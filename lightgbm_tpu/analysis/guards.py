@@ -140,29 +140,13 @@ class CompileCount:
 
 
 @contextlib.contextmanager
-def _monitoring_listener(callback, register, unregister_name: str):
-    """Register a jax.monitoring listener for the duration of the block.
-
-    On exit the listener is deactivated (it stops forwarding to
-    ``callback``) and best-effort unregistered via the private
-    ``jax._src.monitoring`` API — the public unregister landed after
-    0.4.37, and a deactivated listener staying registered is harmless."""
-    state = {"active": True}
-
-    def _listener(*args, **kw) -> None:
-        if state["active"]:
-            callback(*args, **kw)
-
-    register(_listener)
+def _monitoring_listener(callback, register, unregister):
+    """Register a jax.monitoring listener for the duration of the block."""
+    register(callback)
     try:
         yield
     finally:
-        state["active"] = False
-        try:
-            from jax._src import monitoring as _mon
-            getattr(_mon, unregister_name)(_listener)
-        except Exception:
-            pass
+        unregister(callback)
 
 
 @contextlib.contextmanager
@@ -186,7 +170,7 @@ def compile_counter() -> Iterator[CompileCount]:
 
     with _monitoring_listener(
             _on_event, monitoring.register_event_duration_secs_listener,
-            "_unregister_event_duration_listener_by_callback"):
+            monitoring.unregister_event_duration_listener):
         yield counts
 
 
@@ -224,36 +208,52 @@ def cache_counter() -> Iterator[CacheCount]:
             counts.hits += 1
 
     with _monitoring_listener(_on_event, monitoring.register_event_listener,
-                              "_unregister_event_listener_by_callback"):
+                              monitoring.unregister_event_listener):
         yield counts
 
 
-def configure_compile_cache(cache_dir) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+#: jax reads this into ``jax_compilation_cache_dir`` at import; where it
+#: is set, no code in the repository names another directory
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    The ``tpu_compile_cache_dir`` wiring: resumed/checkpointed runs and
-    repeated bench rounds relower but skip every backend compile whose
-    fingerprint is already on disk. The size/compile-time admission
-    thresholds are zeroed so every step program qualifies (the default
-    1 s floor would reject most CPU-backend programs). Changing the
-    directory after a compile already ran re-arms jax's once-per-task
-    cache-enable decision via ``reset_cache``. Returns True when a cache
-    directory is active, False for an empty/unset path (no-op)."""
+
+def checkout_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: the fixed default for the entry points
+    that want a cache without being told where (chip_smoke.py, bench.py,
+    scripts/, the test suite). The path is part of jax's cache key, so it
+    is never a temp name, pid or timestamp; .gitignore lists it."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache(cache_dir, min_compile_secs: float = 0.0) -> bool:
+    """THE place the persistent compilation cache is pointed somewhere.
+
+    One rule: if ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives
+    there and ``cache_dir`` is ignored (jax already read the variable);
+    otherwise it lives in ``cache_dir`` — the user's
+    ``tpu_compile_cache_dir``, or :func:`checkout_cache_dir` for the
+    repo's own entry points. The admission thresholds drop so every step
+    program qualifies (jax's default 1 s floor rejects most CPU-backend
+    programs); the test suite passes a small floor to keep thousands of
+    trivial programs off the disk. Changing the directory after a
+    compile already ran re-arms jax's once-per-task cache-enable
+    decision via ``reset_cache``. Returns True when a cache directory is
+    active, False for an empty/unset ``cache_dir`` (no-op)."""
     path = str(cache_dir or "").strip()
     if not path:
         return False
-    # thresholds zero unconditionally: the dir may already be set (e.g.
-    # via JAX_COMPILATION_CACHE_DIR) with the 1 s admission floor intact,
-    # which would silently reject most CPU-backend step programs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    if os.environ.get(CACHE_DIR_ENV):
+        return True
     if jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc)
         jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()  # drop the cached is-cache-used decision
-        except Exception:
-            pass
+        _cc.reset_cache()  # drop the cached is-cache-used decision
     return True
 
 
@@ -402,6 +402,10 @@ def no_host_transfers() -> Iterator[None]:
 
     See the module docstring for the CPU buffer-protocol caveat.
     """
+    # private reach, kept: the concrete class whose methods materialize
+    # on the host has no public name (jax.Array is only its abstract
+    # base); present in the installed jax 0.9.0, and an import error here
+    # fails the guard loudly rather than disarming it
     from jax._src import array as _array_mod
 
     cls = _array_mod.ArrayImpl

@@ -228,17 +228,20 @@ def run_sweep(sample, num_bins: int,
             try:
                 dt = _time_candidate(build(cand), binned, channels,
                                      reps=reps)
-            except Exception as err:  # noqa: BLE001 - record, move on
-                row["error"] = str(err).splitlines()[0][:200]
-                table.append(row)
-                continue
+            except Exception as err:
+                # a candidate the backend refuses must stop the run, not
+                # lose the race to the XLA einsum with exit code 0
+                raise RuntimeError(
+                    f"tpu_autotune: candidate {cand.key} failed on "
+                    f"bins{tuple(binned.shape)} {binned.dtype} x channels"
+                    f"{tuple(channels.shape)} {channels.dtype}, "
+                    f"num_bins={num_bins}") from err
             row["ms"] = round(dt * 1e3, 4)
             row["rows_per_sec"] = round(n / max(dt, 1e-12))
             table.append(row)
-    timed = [r for r in table if "ms" in r]
-    if not timed:
+    if not table:
         return None, table
-    best = min(timed, key=lambda r: r["ms"])
+    best = min(table, key=lambda r: r["ms"])
     winner = {"entry": best["entry"], "hist_impl": best["hist_impl"],
               "hist_layout": best["hist_layout"],
               "hist_mbatch": best["hist_mbatch"]}
@@ -246,11 +249,8 @@ def run_sweep(sample, num_bins: int,
 
 
 def _multiproc() -> bool:
-    try:
-        import jax
-        return jax.process_count() > 1
-    except Exception:  # pragma: no cover - backend-less host
-        return False
+    import jax
+    return jax.process_count() > 1
 
 
 def _all_swept_knobs_pinned(cfg) -> bool:
@@ -289,8 +289,7 @@ def decision_for(cfg, shape: registry.DatasetShape, platform: str,
     if mode == "off" or shape is None:
         return None, False
     armed = registry._explicit(cfg, "tpu_autotune") or (
-        platform in registry.TPU_PLATFORMS
-        and shape.rows >= MIN_AUTOTUNE_ROWS)
+        registry.on_tpu(platform) and shape.rows >= MIN_AUTOTUNE_ROWS)
     if not armed:
         return None, False
     if _all_swept_knobs_pinned(cfg):
@@ -322,10 +321,6 @@ def decision_for(cfg, shape: registry.DatasetShape, platform: str,
         # pack4 nibble-packs only where every stored column fits a
         # nibble; the common padded width is the available proxy here
         pack4=shape.pack4 and int(shape.num_bins) <= 16)
-    if winner is None:
-        log.warning("tpu_autotune: every sweep candidate failed; "
-                    "keeping the heuristic defaults")
-        return None, True
     block = decision_block(winner, table, platform,
                            registry.shape_class(shape),
                            sample.shape[0], SWEEP_REPS)
@@ -358,7 +353,7 @@ def serving_decision_for(cfg, sclass: str, platform: Optional[str] = None,
         return None, False
     platform = platform or registry.current_platform()
     armed = registry._explicit(cfg, "tpu_autotune") \
-        or platform in registry.TPU_PLATFORMS
+        or registry.on_tpu(platform)
     if not armed:
         return None, False
     path = cache_path(cfg)
@@ -381,19 +376,14 @@ def serving_decision_for(cfg, sclass: str, platform: Optional[str] = None,
                                    "serve_engine": eng}
             try:
                 dt = _time_candidate(fn)
-            except Exception as err:  # noqa: BLE001 - record, move on
-                row["error"] = str(err).splitlines()[0][:200]
-                table.append(row)
-                continue
+            except Exception as err:
+                raise RuntimeError(
+                    f"tpu_autotune: serving candidate serve_{eng} failed "
+                    f"on {sclass} at {rows} rows") from err
             row["ms"] = round(dt * 1e3, 4)
             row["rows_per_sec"] = round(rows / max(dt, 1e-12))
             table.append(row)
-    timed = [r for r in table if "ms" in r]
-    if not timed:
-        log.warning("tpu_autotune: every serving-engine candidate "
-                    "failed; keeping the depth heuristic")
-        return None, True
-    best = min(timed, key=lambda r: r["ms"])
+    best = min(table, key=lambda r: r["ms"])
     winner = {"serve_engine": best["serve_engine"]}
     block = decision_block(winner, table, platform, sclass, rows,
                            SWEEP_REPS)

@@ -76,19 +76,6 @@ def _p99(xs: List[float]) -> float:
     return s[idx]
 
 
-def _coordination_client():
-    """The jax coordination-service KV client, or None (single process /
-    internals moved)."""
-    try:
-        import jax
-        if jax.process_count() <= 1:
-            return None
-        from jax._src import distributed
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 - attribution is best-effort
-        return None
-
-
 class RankStats:
     """Sampled per-rank step/collective-wait attribution (one per run).
 
@@ -115,8 +102,10 @@ class RankStats:
                 rank, world = rank or 0, world or 1
         self.rank = int(rank)
         self.world = int(world)
-        self._kv = kv if kv is not None else (
-            _coordination_client() if self.world > 1 else None)
+        if kv is None and self.world > 1:
+            from ..parallel.multihost import kv_client
+            kv = kv_client()
+        self._kv = kv
         with _run_mu:
             _run_seq += 1
             self._run = _run_seq

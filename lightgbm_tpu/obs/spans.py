@@ -80,10 +80,10 @@ def _mark_seen(name: str) -> None:
 
 
 def _trace_state_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - future-jax fallback: assume host
-        return True
+    # no catch: if jax moves this again, span() must fail loudly — a
+    # swallowed AttributeError here once left every device program
+    # without its phase name (jax.core.trace_state_clean, gone in 0.9)
+    return jax.core.trace_ctx.is_top_level()
 
 
 class _NullSpan:

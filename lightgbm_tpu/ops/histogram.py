@@ -229,8 +229,8 @@ def _resolve_impl(impl: str, num_bins: int, num_features: int = 0) -> str:
     """
     if impl != "auto":
         return impl
-    from .pallas_histogram import pallas_available
-    if (num_bins >= 128 and pallas_available()
+    from ..engines.registry import on_tpu
+    if (num_bins >= 128 and on_tpu()
             and num_features * num_bins <= 50_000):
         return "pallas"
     return "xla"
@@ -349,8 +349,8 @@ def histogram(
     the grouped sums are bit-identical to the ungrouped ones, and the
     per-element psum addends are unchanged — same bytes, same result."""
     if impl == "pallas":
-        from .pallas_histogram import pallas_available
-        if not pallas_available():
+        from ..engines.registry import on_tpu
+        if not on_tpu():
             raise RuntimeError(
                 "tpu_hist_impl=pallas requires a TPU backend; use 'xla'")
     f = binned.shape[1]

@@ -177,6 +177,17 @@ def _path_agreement(binned, rec_b, cat_b, node, went, slot, depth: int,
     return (cnt == 0).astype(jnp.float32)                      # [Tb, N, D+1]
 
 
+def _scatter_to_features(wgt, onehot_f) -> jax.Array:
+    """Per-slot SHAP weights [Tb, N, D+1] summed onto their features
+    [Tb, N, Fd] through a 0/1 matrix. HIGHEST precision is load-bearing:
+    at the TPU's default an f32 contraction rounds its operands to bf16,
+    which is exact for the one-hot but not for the weights — the first run
+    on a v5e had contributions 4.2e-4 off the host twin (1.2e-7 on the CPU
+    backend, where the default is already full f32)."""
+    return jnp.einsum("tnj,tjf->tnf", wgt, onehot_f,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _extend_unwind(one, zfrac, ulen, depth: int) -> jax.Array:
     """The row-dependent EXTEND/UNWIND recurrences: per-slot UNWIND sums
     [Tb, B, D+1] from the agreement fractions ``one`` [Tb, B, D+1].
@@ -294,7 +305,7 @@ def shap_batched(
                                       any_cat, packed)
                 onehot_f = (feat[:, :, None] == farange[None, None, :]
                             ).astype(jnp.float32)              # [Tb,D+1,Fd]
-                return phi + jnp.einsum("tnj,tjf->tnf", wgt, onehot_f), None
+                return phi + _scatter_to_features(wgt, onehot_f), None
 
             # scan the leaf axis (leaf-major transposes of the path
             # arrays) so peak memory stays one leaf's working set
@@ -440,7 +451,7 @@ def shap_batched_tables(
                                           (tb, n, tab.shape[2])), axis=1)
                 onehot_f = (feat[:, :, None] == farange[None, None, :]
                             ).astype(jnp.float32)              # [Tb,D+1,Fd]
-                return phi + jnp.einsum("tnj,tjf->tnf", wgt, onehot_f), None
+                return phi + _scatter_to_features(wgt, onehot_f), None
 
             leaf_xs = (
                 node_b.transpose(1, 0, 2), went_b.transpose(1, 0, 2),

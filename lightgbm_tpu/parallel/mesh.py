@@ -150,7 +150,7 @@ def sync_barrier(tag: str, deadline_s: float = 0.0) -> None:
     barriers in program order, so the ids line up across the pod.
     """
     from ..analysis.faultinject import active_plan
-    from .multihost import run_with_deadline
+    from .multihost import kv_client, run_with_deadline
 
     global _barrier_seq
     _barrier_seq += 1
@@ -160,12 +160,7 @@ def sync_barrier(tag: str, deadline_s: float = 0.0) -> None:
         active_plan().fire("barrier", tag=tag)
         if jax.process_count() <= 1:
             return
-        client = None
-        try:
-            from jax._src import distributed
-            client = distributed.global_state.client
-        except Exception:  # pragma: no cover - jax internals moved
-            pass
+        client = kv_client()
         if client is not None:
             # the KV timeout backstops the watchdog: keep it LARGER than
             # deadline_s so a hang surfaces as TrainingInterrupted first

@@ -8,6 +8,9 @@ faults the TPU worker.
 Usage: REPRO_ROWS=120000 REPRO_LEAVES=255 REPRO_ITERS=3 \
        LGBM_TPU_FORCE_FUSED_EFB=1 python scripts/repro_fused_efb.py
 Prints REPRO_OK as the last line when training survives.
+
+Run it directly (through the chip tool on the TPU): the chip belongs to one
+process, so never start it from a parent that has already touched JAX.
 """
 import os
 import sys
@@ -20,14 +23,12 @@ FEATS = int(os.environ.get("REPRO_FEATS", 4228))
 LEAVES = int(os.environ.get("REPRO_LEAVES", 255))
 ITERS = int(os.environ.get("REPRO_ITERS", 3))
 
-import jax  # noqa: E402
-
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("REPRO_CACHE", "/tmp/.jax_repro_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 from bench import make_allstate_like  # noqa: E402
 import lightgbm_tpu as lgb  # noqa: E402
+from lightgbm_tpu.analysis.guards import (  # noqa: E402
+    checkout_cache_dir, configure_compile_cache)
+
+configure_compile_cache(checkout_cache_dir())
 
 params = {
     "objective": "binary",

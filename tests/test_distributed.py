@@ -128,6 +128,24 @@ def test_data_parallel_model_equality_with_serial():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("learner,grower", [("data", "compact"),
+                                            ("voting", "auto")])
+def test_second_iteration_under_mesh_lowers_nothing(learner, grower):
+    """The step's small carried state starts where the step hands it
+    back (replicated over the mesh). Fed uncommitted, the second
+    iteration re-lowered the whole step program — on the chip a second
+    full compile."""
+    from lightgbm_tpu.analysis import guards
+    X, y = binary_data()
+    bst = lgb.train(_params(objective="binary", tree_learner=learner,
+                            tpu_grower=grower),
+                    lgb.Dataset(X, label=y), 1, keep_training_booster=True)
+    with guards.compile_counter() as cc:
+        bst.update()
+        bst._gbdt._flush_trees()
+    assert cc.lowerings == 0, cc.by_phase
+
+
 def test_feature_parallel_learner():
     """Feature-parallel: data replicated, split finding sharded by feature
     (reference: feature_parallel_tree_learner.cpp)."""

@@ -184,6 +184,42 @@ def test_bench_retries_enumeration_failures(monkeypatch):
     assert EnumFlaky.calls == 3
 
 
+def test_bench_refuses_cpu_backend_without_rehearsal_flag(monkeypatch):
+    """A bench that finds no chip fails: on the CPU backend every stage
+    exits non-zero before measuring or recording anything, unless the
+    command line asks for the rehearsal — and a CPU device reached after a
+    retry is refused just the same."""
+    import jax
+    bench = _load_bench()
+    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
+    monkeypatch.setattr(bench, "_record_shape", lambda *a: pytest.fail(
+        "recorded a row on the CPU backend"))
+    for var in ("BENCH_HIST_MICRO", "BENCH_PREDICT", "BENCH_SERVING",
+                "BENCH_RANKING"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+    with pytest.raises(SystemExit) as exc:
+        bench.main()                       # the default (train) stage
+    assert exc.value.code not in (0, None)
+    assert "--cpu-rehearsal" in str(exc.value.code)
+
+    class CpuAfterRetry:
+        __name__ = "flaky"
+        calls = 0
+
+        def devices(self):
+            CpuAfterRetry.calls += 1
+            if CpuAfterRetry.calls == 1:
+                raise RuntimeError("Unable to initialize backend 'tpu'")
+            return jax.devices()
+
+    with pytest.raises(SystemExit):
+        bench._require_device(CpuAfterRetry())
+    assert CpuAfterRetry.calls == 2
+    monkeypatch.setattr(bench.sys, "argv", ["bench.py", "--cpu-rehearsal"])
+    assert bench._require_device(jax).platform == "cpu"
+
+
 def test_bench_failure_stub_recorded(monkeypatch, tmp_path):
     """An unrecoverable failure emits the structured stub row (value null
     + error inline) AND records it in BENCH_SHAPES.json, so the BENCH_r0x

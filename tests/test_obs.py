@@ -73,6 +73,22 @@ def test_span_under_trace_is_named_scope():
     assert "zz_unit_traced" in spans.seen_spans()
 
 
+def test_compact_step_program_carries_hist_build_scope(monkeypatch):
+    """The device program itself must carry the phase names: the compiled
+    compact step's op metadata names ``hist_build``. When span() quietly
+    stopped detecting a trace (jax 0.9 dropped the function it asked),
+    every per-phase device-seconds number came back empty — this pins the
+    text, so the next jax move cannot empty the traces silently."""
+    monkeypatch.setenv("LGBM_TPU_COMM_ACCOUNTING", "1")
+    X, y = _make_data()
+    bst = lgb.train({"objective": "binary", "num_leaves": 7, "max_bin": 15,
+                     "min_data_in_leaf": 5, "verbosity": -1,
+                     "tpu_grower": "compact"}, lgb.Dataset(X, label=y), 1)
+    text = bst._gbdt._comm_hlo["compact_step_k0"]
+    assert "hist_build" in text
+    assert "split_scan" in text
+
+
 def test_trace_mode_validation():
     assert spans.resolve_trace_mode(None) == "full"
     assert spans.resolve_trace_mode("annotations") == "annotations"

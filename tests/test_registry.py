@@ -243,6 +243,60 @@ def test_unwritable_cache_still_uses_measured_winner(tmp_path,
     assert res.autotuned        # this run still took the measured winner
 
 
+def test_gspmd_partitioned_step_never_gets_a_mosaic_engine():
+    """The masked grower under a mesh is partitioned by GSPMD, and
+    lowering a Mosaic call there is refused ("cannot be automatically
+    partitioned" — met compiling the voting step for a 4-chip v5e mesh):
+    such shapes sweep and resolve the XLA einsum only, under their own
+    cache key, and asking for pallas outright is an error."""
+    shape = SHAPE._replace(mode="voting", gspmd=True)
+    assert registry.shape_class(shape).endswith("-gspmd")
+    assert {c.entry.id for c in registry.sweep_candidates(shape, "tpu")} \
+        == {"xla_lane"}
+    res = registry.resolve({"tpu_autotune": "off"}, shape=shape,
+                           platform="tpu")
+    assert res.hist_impl == "xla" and res.entry_id == "xla_lane"
+    assert res.sources["hist_impl"] == "gspmd"
+    with pytest.raises(ValueError, match="partitioned by GSPMD"):
+        registry.resolve({"tpu_autotune": "off", "tpu_hist_impl": "pallas"},
+                         shape=shape, platform="tpu")
+    # the same mode under shard_map (compact grower) keeps the kernels
+    res = registry.resolve({"tpu_autotune": "off"},
+                           shape=shape._replace(gspmd=False), platform="tpu")
+    assert res.hist_impl == "auto" and res.entry_id == "fused_lane"
+
+
+def test_failed_candidate_stops_the_run(monkeypatch):
+    """A candidate the backend refuses must not lose the race quietly to
+    the XLA einsum (exit code 0, ~80x slower): the sweep raises with the
+    candidate's name and shape."""
+    def refuse(fn, *args, reps=0):
+        raise ValueError("Mosaic failed to compile TPU kernel")
+    monkeypatch.setattr(autotune, "_time_candidate", refuse)
+    cands = registry.sweep_candidates(
+        registry.DatasetShape(512, 4, 16, "serial"), "cpu")
+    with pytest.raises(RuntimeError,
+                       match=r"xla_lane-k8 failed on bins\(512, 4\)"):
+        autotune.run_sweep(np.zeros((512, 4), np.uint8), 16, cands)
+
+
+def test_fused_on_without_tpu_raises():
+    """tpu_fused=on off-TPU used to warn and take the XLA walk; now it is
+    an error (interpret mode, resolved first, stays the CI spelling)."""
+    with pytest.raises(ValueError, match="tpu_fused=on requires a TPU"):
+        registry.resolve({"tpu_fused": "on"})          # live backend: cpu
+    with pytest.raises(ValueError, match="tpu_fused=on requires a TPU"):
+        registry.resolve_fused_block({"tpu_fused": "on"}, platform="cpu")
+    assert registry.resolve_fused_block(
+        {"tpu_fused": "on", "tpu_fused_interpret": True,
+         "tpu_fused_block": 128}, platform="cpu") == 128
+    assert registry.resolve_fused_block({"tpu_fused": "auto"},
+                                        platform="cpu") == 0
+    X, y = binary_data(300, 4, seed=0)
+    with pytest.raises(ValueError, match="tpu_fused=on requires a TPU"):
+        lgb.train(dict(BASE, tpu_fused="on"), lgb.Dataset(X, label=y), 1)
+
+
 def test_implicit_arming_stays_inert_on_cpu(monkeypatch):
     """The first_run DEFAULT must not tax CPU runs or small shapes: with
     tpu_autotune unset, nothing sweeps on cpu even at 1M rows, and on

@@ -53,26 +53,6 @@ def _train(extra, n=800, f=12, seed=0):
     return bst, bst.predict(X)
 
 
-@pytest.fixture
-def cache_config_restored():
-    """Leave the process-global jax compilation-cache config the way the
-    test found it (configure_compile_cache mutates it)."""
-    keys = ("jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
-    prev = {k: getattr(jax.config, k) for k in keys}
-    yield
-    for k, v in prev.items():
-        jax.config.update(k, v)
-    try:
-        # drop the initialized cache object too, or the restored config
-        # is ignored: jax caches its is-cache-used decision per task
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
-
-
 # ------------------------------------------------------------- rung units
 def test_leaf_rung_powers_of_two():
     assert [leaf_rung(v) for v in (2, 3, 4, 5, 8, 9, 31, 32, 33)] == \
@@ -219,6 +199,32 @@ def test_configure_compile_cache_sets_config(tmp_path,
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
     # idempotent re-arm
     assert guards.configure_compile_cache(cache) is True
+
+
+def test_compile_cache_yields_to_environment(tmp_path, monkeypatch,
+                                             cache_config_restored):
+    """The one cache rule: with JAX_COMPILATION_CACHE_DIR set, neither
+    the helper nor the tpu_compile_cache_dir knob names another
+    directory (the thresholds still drop, so step programs qualify)."""
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(guards.CACHE_DIR_ENV, str(tmp_path / "from_env"))
+    assert guards.configure_compile_cache(str(tmp_path / "cc")) is True
+    assert jax.config.jax_compilation_cache_dir == prev
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    _train(dict(tpu_compile_cache_dir=str(tmp_path / "knob")))
+    assert jax.config.jax_compilation_cache_dir == prev
+    assert not (tmp_path / "knob").exists()
+
+
+def test_checkout_cache_dir_is_fixed_and_ignored():
+    """Without the variable the repo's entry points use ONE fixed path
+    inside the checkout (the path is part of jax's cache key), and git
+    ignores it."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert guards.checkout_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert guards.checkout_cache_dir() == guards.checkout_cache_dir()
+    with open(os.path.join(repo, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
 
 
 def test_same_rung_shares_cache_entries(tmp_path, cache_config_restored):

@@ -1,0 +1,307 @@
+"""The Mosaic kernels and the train-step programs compile for a TPU v5e.
+
+No chip is attached here: the TPU's compiler is installed and compiles for a
+chip that is DESCRIBED (``topologies.get_topology_desc``). That shows what
+Pallas interpret mode — all the rest of the CPU suite ever runs — cannot: a
+slice not aligned to the tiling, a kernel over the scoped-VMEM limit, a
+program that does not fit 16 GB, a kernel that cannot be partitioned.
+Nothing runs, so these tests say nothing about results or times.
+
+Rules this file keeps (the TPU library loads in ONE process at a time and is
+held until exit, and pytest-xdist imports every test file in every worker):
+the topology and everything built from it live in module-scoped, non-autouse
+fixtures of THIS file and skip from there; nothing here touches the topology
+at import, in a ``skipif`` or in ``parametrize`` arguments; every compile
+runs in the test's own process; and all such tests stay in this one file. A
+described-device executable cannot be read back from the persistent
+compilation cache, so the cache is switched off around the compiles.
+
+Tier-1 keeps the standalone histogram kernel (split and int8 at higgs
+width, a few seconds each) and ONE fused case (higgs, dual, mbatch 8,
+~80 s); the other fused variants and the whole step programs are ``slow``
+(run them before spending chip time on a change to a kernel, its clamp, or
+the step: ``pytest tests/test_tpu_compile.py -m 'slow or not slow'``).
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.engines import registry
+from lightgbm_tpu.ops.compact import RowLayout
+from lightgbm_tpu.ops.fused_split import fused_block_cap, fused_split
+from lightgbm_tpu.ops.pallas_histogram import pallas_histogram
+
+HBM_BYTES = 16 << 30            # one v5e chip
+HIGGS_ROWS = 10_500_000
+# binary objective, no weights: score + label + row id ride as extras
+HIGGS_EXTRAS = 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chip_mesh(topo):
+    from lightgbm_tpu.parallel.mesh import make_mesh
+    return make_mesh(devices=topo.devices)
+
+
+@pytest.fixture
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compiled_with_kernel(lowered):
+    compiled = lowered.compile()   # raises what the chip's compiler raises
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ------------------------------------------------ standalone histogram kernel
+HIST_CASES = {
+    # name: (rows, features, bins, mode, mbatch, layout)
+    "higgs-split-k1": (1 << 20, 28, 256, "split", 1, "lane"),
+    "higgs-split-k8": (1 << 20, 28, 256, "split", 8, "lane"),
+    "higgs-int8-k8": (1 << 20, 28, 256, "int8", 8, "lane"),
+    "b64-sublane-k8": (1 << 20, 28, 64, "split", 8, "sublane"),
+    # the autotuner's 16k-row sample at its deepest batched-M candidate
+    "sweep-sample-k16": (1 << 14, 28, 256, "split", 16, "lane"),
+}
+HIST_CASES_SLOW = {
+    # the auto dispatch's F*B <= 50,000 boundary (ops/histogram._resolve_impl)
+    "boundary-f195": (1 << 20, 195, 256, "split", 8, "lane"),
+    "msltr-f137": (1 << 20, 137, 256, "split", 8, "lane"),
+    "pack4-width-sublane-int8": (1 << 20, 28, 16, "int8", 8, "sublane"),
+}
+
+
+def _hist_compile(case, one_chip):
+    rows, f, b, mode, mbatch, layout = case
+    ch_t = jnp.int8 if mode == "int8" else jnp.float32
+    _compiled_with_kernel(pallas_histogram.lower(
+        jax.ShapeDtypeStruct((rows, f), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, 4), ch_t, sharding=one_chip),
+        b, mode=mode, mbatch=mbatch, hist_layout=layout))
+
+
+@pytest.mark.parametrize("name", sorted(HIST_CASES))
+def test_histogram_kernel_compiles_for_v5e(name, one_chip,
+                                           no_persistent_cache):
+    _hist_compile(HIST_CASES[name], one_chip)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", sorted(HIST_CASES_SLOW))
+def test_histogram_kernel_compiles_for_v5e_slow(name, one_chip,
+                                                no_persistent_cache):
+    _hist_compile(HIST_CASES_SLOW[name], one_chip)
+
+
+# -------------------------------------------------------- fused split kernel
+FUSED_CASES_SLOW = {
+    # name: dict(features, bins, + fused_split keyword overrides)
+    "higgs-dual-k1": dict(f=28, b=256, mbatch=1),
+    "higgs-dual-k16": dict(f=28, b=256, mbatch=16),
+    "higgs-copyback-k8": dict(f=28, b=256, dual=False),
+    "higgs-quant-k8": dict(f=28, b=256, quant=True),
+    "b64-sublane-k8": dict(f=28, b=64, hist_layout="sublane"),
+    # narrow bins: refused before PR 24 (23.3 / 16.2 MB of scoped VMEM
+    # against 16 MB) until _hist_packing bounded the group's compare tiles
+    "b16-lane-k8": dict(f=28, b=16),
+    "b32-lane-k8": dict(f=28, b=32),
+    "pack4-lane-k8": dict(f=28, b=16, packed4=True),
+    "pack4-sublane-k8": dict(f=28, b=16, packed4=True,
+                             hist_layout="sublane"),
+    "msltr-f137-dual-k8": dict(f=137, b=256),
+    # the on-chip shape of the deleted tests/test_tpu_shapes.py: Allstate's
+    # 4228 one-hot features bundle to 529 columns, and bundled data runs
+    # the copy-back variant (boosting/gbdt._setup_compact_state)
+    "efb-529-copyback-k8": dict(f=529, b=256, dual=False),
+}
+
+
+def _fused_compile(one_chip, f, b, rows=1 << 20, packed4=False, **kw):
+    layout = RowLayout(num_features=f, num_extra=HIGGS_EXTRAS,
+                       packed4=packed4)
+    c = layout.num_cols
+    bs = min(512, fused_block_cap(c, kw.get("mbatch", 8),
+                                  kw.get("quant", False),
+                                  kw.get("hist_layout", "lane")))
+    n = rows + bs + 32
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = arr((), jnp.int32)
+    _compiled_with_kernel(fused_split.lower(
+        arr((n, c), jnp.uint8), arr((n, c), jnp.uint8), i32, i32, i32, i32,
+        i32, i32, i32, i32, i32, arr((8,), jnp.uint32), layout=layout,
+        num_bins=b, block_size=bs, smaller_left=i32, side=i32,
+        num_rows=rows, **kw))
+
+
+def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The headline kernel as higgs trains it: 128-byte records, 256 bins,
+    dual residency, batched-M 8, block clamped to 384."""
+    _fused_compile(one_chip, f=28, b=256)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", sorted(FUSED_CASES_SLOW))
+def test_fused_kernel_compiles_for_v5e_slow(name, one_chip,
+                                            no_persistent_cache):
+    _fused_compile(one_chip, **FUSED_CASES_SLOW[name])
+
+
+# --------------------------------------------------- whole train-step programs
+class _Captured(Exception):
+    pass
+
+
+def _abstract_step(monkeypatch, params, rows, features=28):
+    """(booster, step args, step kwargs) of the FIRST train-step call a
+    booster would make on a TPU, captured before anything runs.
+
+    The engine registry asks the live backend which platform it is on; here
+    that is the CPU, so the test steers ``current_platform`` (in the test,
+    not through an option of the program) to get the engines a TPU run
+    resolves: compact grower, fused kernel, Mosaic histograms. The step
+    callable is then swapped for a recorder that keeps its arguments and
+    aborts, because the kernel cannot execute on this backend."""
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    rng = np.random.RandomState(0)
+    x = rng.randn(rows, features).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float64)
+    bst = lgb.Booster(params, lgb.Dataset(x, label=y, params=params))
+    g = bst._gbdt
+    got = {}
+
+    def recorder(*args, **kwargs):
+        got["args"], got["kwargs"] = args, kwargs
+        raise _Captured
+
+    if g._use_compact:
+        g._setup_compact_state()
+        g._compact["step"] = recorder
+    else:
+        g._step_fn = recorder
+    with pytest.raises(_Captured):
+        g.train_one_iter()
+    return bst, got["args"], got["kwargs"]
+
+
+def _retarget(args, dim_map, sharding_of):
+    """Abstract the captured arguments at the real row count, placed on the
+    described devices (``jax.device_put`` to them is impossible)."""
+    def leaf(v):
+        if not isinstance(v, jax.Array):
+            return v
+        shape = tuple(dim_map.get(d, d) for d in v.shape)
+        return jax.ShapeDtypeStruct(shape, v.dtype, sharding=sharding_of(v))
+    return jax.tree_util.tree_map(leaf, args)
+
+
+STEP_PARAMS = {
+    "objective": "binary", "num_leaves": 255, "max_bin": 255,
+    "min_data_in_leaf": 100, "verbosity": -1,
+    # the sweep would EXECUTE Mosaic candidates; nothing runs here
+    "tpu_autotune": "off",
+}
+
+
+@pytest.mark.slow
+def test_compact_step_compiles_for_v5e_at_higgs_shape(
+        monkeypatch, one_chip, no_persistent_cache):
+    """One whole serial train step at 10.5M x 28, 255 leaves, 255 bins."""
+    bst, args, kwargs = _abstract_step(monkeypatch, STEP_PARAMS, 1 << 16)
+    g = bst._gbdt
+    assert g._use_compact and g.grower_params.fused_block > 0
+    assert not g.grower_params.fused_interpret
+    abstract = _retarget(args, g.flight_row_dims(HIGGS_ROWS),
+                         lambda v: one_chip)
+    compiled = _compiled_with_kernel(
+        g._build_compact_step_fn().lower(*abstract, **kwargs))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, mem
+
+
+@pytest.mark.slow
+def test_data_parallel_step_compiles_for_four_v5e_chips(
+        monkeypatch, four_chip_mesh, no_persistent_cache):
+    """The same step under ``shard_map`` over a 4-chip mesh at 4M rows:
+    the kernel partitions with the shards and the histogram collective is
+    in the program."""
+    params = dict(STEP_PARAMS, tree_learner="data", tpu_mesh_shape="4")
+    bst, args, kwargs = _abstract_step(monkeypatch, params, 1 << 18)
+    g = bst._gbdt
+    assert g._use_compact and g.mesh is not None
+    dims = g.flight_row_dims(1 << 22)
+    g.mesh = four_chip_mesh       # the step's shard_map closes over it
+    abstract = _retarget(
+        args, dims, lambda v: NamedSharding(four_chip_mesh, v.sharding.spec)
+        if isinstance(v.sharding, NamedSharding) else None)
+    k = kwargs.pop("k")
+    step = g._build_compact_step_fn()
+    lowered = jax.jit(lambda *a: step(*a, k=k)).lower(*abstract)
+    # what the program asks for (tpu_hist_scatter=auto: reduce-scatter) ...
+    assert "reduce_scatter" in lowered.as_text()
+    compiled = _compiled_with_kernel(lowered)
+    # ... and what the v5e compiler makes of it: at this payload it
+    # decomposes the reduce-scatter into all-reduce + slice
+    text = compiled.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    # per-chip bytes; the outer jit here drops the step's buffer donation,
+    # so this over-counts what a real run holds
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES, mem
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("top_k", [20, 8], ids=["full-election", "live-vote"])
+def test_voting_step_compiles_for_four_v5e_chips(
+        top_k, monkeypatch, four_chip_mesh, no_persistent_cache):
+    """The voting learner's masked step at chip_smoke's four-chip size.
+    GSPMD partitions it, and nothing partitions a Mosaic call (lowering
+    one there is refused outright), so the registry hands this step the
+    XLA einsum. At 28 features the default ``top_k=20`` elects every
+    feature (the exact data-parallel histogram); ``top_k=8`` really votes."""
+    params = dict(STEP_PARAMS, tree_learner="voting", tpu_mesh_shape="4",
+                  num_leaves=31, top_k=top_k)
+    bst, args, kwargs = _abstract_step(monkeypatch, params, 1 << 18)
+    g = bst._gbdt
+    assert not g._use_compact and g.mesh is not None
+    assert g.grower_params.hist_impl == "xla"
+    assert g._engine_resolution.sources["hist_impl"] == "gspmd"
+    abstract = _retarget(
+        args, {}, lambda v: NamedSharding(four_chip_mesh, v.sharding.spec)
+        if isinstance(v.sharding, NamedSharding) else None)
+    text = g._build_step_fn().lower(*abstract, **kwargs).compile().as_text()
+    assert "all-reduce" in text or "all-gather" in text

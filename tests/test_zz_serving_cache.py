@@ -15,46 +15,32 @@ import lightgbm_tpu as lgb
 from utils import FAST_PARAMS, binary_data
 
 
-def test_second_boot_rearms_ladder_with_zero_cache_misses(tmp_path):
+def test_second_boot_rearms_ladder_with_zero_cache_misses(
+        tmp_path, cache_config_restored):
     """With tpu_compile_cache_dir set, a restarted server re-warms its
     FULL predict ladder from the persistent cache — backend compiles
     consult the cache and miss zero times."""
     import jax
     X, _ = binary_data()
-    saved = (jax.config.jax_compilation_cache_dir,
-             jax.config.jax_persistent_cache_min_compile_time_secs,
-             jax.config.jax_persistent_cache_min_entry_size_bytes)
-    try:
-        params = dict(FAST_PARAMS, objective="binary",
-                      tpu_predict_buckets="32,256",
-                      tpu_compile_cache_dir=str(tmp_path / "cc"))
-        y = (X[:, 0] > 0).astype(float)
-        bst = lgb.train(params, lgb.Dataset(X, label=y), 3)
-        # boot 1 must BACKEND-compile the whole ladder (earlier tests may
-        # have left shape-compatible programs in the in-memory jit cache,
-        # which would skip the backend and write nothing to disk)
-        jax.clear_caches()
-        boot1 = bst.warm_predict_ladder()
-        assert boot1["cache"]["requests"] > 0          # cache consulted
-        # "process restart": drop every in-memory jit/backend cache, so
-        # the second warmup must re-lower and re-ask the backend
-        jax.clear_caches()
-        boot2 = bst.warm_predict_ladder()
-        assert boot2["lowerings"] > 0                  # really re-lowered
-        assert boot2["cache"]["requests"] > 0
-        assert boot2["cache"]["misses"] == 0, boot2    # zero backend work
-        assert boot2["cache"]["hits"] == boot2["cache"]["requests"]
-        # warmed-from-cache programs really serve
-        out, n = bst.predict_serving(X[:5])
-        np.testing.assert_array_equal(out[:n], bst.predict(X[:5]))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", saved[0])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          saved[1])
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          saved[2])
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
+    params = dict(FAST_PARAMS, objective="binary",
+                  tpu_predict_buckets="32,256",
+                  tpu_compile_cache_dir=str(tmp_path / "cc"))
+    y = (X[:, 0] > 0).astype(float)
+    bst = lgb.train(params, lgb.Dataset(X, label=y), 3)
+    # boot 1 must BACKEND-compile the whole ladder (earlier tests may
+    # have left shape-compatible programs in the in-memory jit cache,
+    # which would skip the backend and write nothing to disk)
+    jax.clear_caches()
+    boot1 = bst.warm_predict_ladder()
+    assert boot1["cache"]["requests"] > 0          # cache consulted
+    # "process restart": drop every in-memory jit/backend cache, so
+    # the second warmup must re-lower and re-ask the backend
+    jax.clear_caches()
+    boot2 = bst.warm_predict_ladder()
+    assert boot2["lowerings"] > 0                  # really re-lowered
+    assert boot2["cache"]["requests"] > 0
+    assert boot2["cache"]["misses"] == 0, boot2    # zero backend work
+    assert boot2["cache"]["hits"] == boot2["cache"]["requests"]
+    # warmed-from-cache programs really serve
+    out, n = bst.predict_serving(X[:5])
+    np.testing.assert_array_equal(out[:n], bst.predict(X[:5]))
