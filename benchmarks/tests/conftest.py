@@ -1,0 +1,10 @@
+"""The benchmark's own tests: ``pytest benchmarks/tests`` from the root of
+the repository, on the CPU. Not part of tier-1."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
