@@ -1055,18 +1055,15 @@ def run_ranking_bench():
 
 def _record_scaling_ledger(jax, trace_dir, shape, iters_per_sec,
                            timed_iters):
-    """BENCH_LEDGER=1: parse the round's profiler trace and record the
-    scaling-efficiency block (obs/ledger.py) into COMM_ACCOUNTING.json
-    (+ BENCH_MULTICHIP_PATH when set). Best-effort — the ledger must
+    """BENCH_LEDGER=1: record the scaling-efficiency block
+    (obs/ledger.py) into COMM_ACCOUNTING.json (+ BENCH_MULTICHIP_PATH
+    when set). The round's profiler trace under ``trace_dir`` is no
+    longer reduced here (the hand-written reader is gone), so the block
+    carries no ``measured_vs_model``. Best-effort — the ledger must
     never sink a bench round that already measured its throughput."""
     try:
         from lightgbm_tpu.obs import ledger as obs_ledger
-        from lightgbm_tpu.obs import tracing as obs_tracing
-        analysis = obs_tracing.analyze_trace_dir(trace_dir)
-        if analysis is None:
-            sys.stderr.write(f"[bench] ledger: no trace artifact under "
-                             f"{trace_dir}\n")
-            return
+        analysis = None
         n_chips = len(jax.devices())
         contract_mode = os.environ.get(
             "BENCH_LEDGER_CONTRACT",

@@ -10,16 +10,25 @@ Public surface mirrors the reference's Python package
 (python-package/lightgbm/__init__.py): ``Dataset``, ``Booster``, ``train``,
 ``cv``, callbacks, and sklearn-style estimators.
 """
-from .basic import Booster, Dataset, Sequence
-from .callback import (
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()    # the `import` span's start (below)
+
+from .basic import Booster, Dataset, Sequence  # noqa: E402
+from .callback import (  # noqa: E402
     EarlyStopException,
     early_stopping,
     log_evaluation,
     record_evaluation,
     reset_parameter,
 )
-from .config import Config
-from .engine import CVBooster, cv, train
+from .config import Config  # noqa: E402
+from .engine import CVBooster, cv, train  # noqa: E402
+from .obs import spans as _spans  # noqa: E402
+
+# the `import` span, stamped and not entered: what it would be entered
+# with is what it times (jax's import is most of it)
+_spans.record("import", _IMPORT_T0, _time.perf_counter())
 
 __version__ = "0.1.0"
 

@@ -61,10 +61,21 @@ from jax import monitoring
 
 from ..utils import rwlock as _rwlock
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: duration event -> the ``kind`` of the flight ring's ``compile`` record.
+#: The first two are also counted (CompileCount); a backend compile's
+#: interval encloses its cache retrieval's, and a jit traced inside
+#: another's trace would nest likewise (only outermost traces are
+#: recorded), so a reader takes unions and never sums across kinds.
+_COMPILE_KINDS = {_TRACE_EVENT: "traces", _LOWER_EVENT: "lowerings",
+                  _BACKEND_EVENT: "backend_compiles",
+                  _CACHE_RETRIEVAL_EVENT: "cache_retrievals"}
 
 #: jax.Array methods/properties through which host materialization funnels
 _FUNNELS = ("_value", "__array__", "item", "tolist", "__float__",
@@ -275,6 +286,10 @@ def install_global_compile_listener() -> None:
     records each compile into the flight recorder, phase-keyed — so a
     post-mortem dump shows WHAT compiled right before a death, and the
     metrics plane reports attribution without any guard being armed.
+    Besides lowerings and backend compiles the ring gets jaxpr traces
+    (outermost only) and persistent-cache retrievals, each with the
+    ``compile_phase()`` it fell in, the function's name where jax gives
+    one, and ``t0``/``t1`` on ``time.perf_counter()``.
     Cost: one python callback per compile event (compiles are rare by
     contract — the whole repo is built around zero steady-state
     compiles)."""
@@ -285,19 +300,26 @@ def install_global_compile_listener() -> None:
         _global_listener_installed = True
 
     def _on_duration(event: str, duration_secs: float = 0.0, **kw) -> None:
-        kind = None
-        if event == _LOWER_EVENT:
-            kind = "lowerings"
-        elif event == _BACKEND_EVENT:
-            kind = "backend_compiles"
+        kind = _COMPILE_KINDS.get(event)
         if kind is None:
             return
+        # jax reports a duration as it ends: t1 is now, on the clock of
+        # the host spans (obs/spans.py), so a reader places both on one
+        # timeline; the ring's wall-clock t stays for the dumps
+        t1 = time.perf_counter()
+        if event == _TRACE_EVENT and not jax.core.trace_ctx.is_top_level():
+            # a jit traced inside another jit's trace (every jnp function
+            # is one): hundreds a step, all inside the outermost's interval
+            return
         phase = current_compile_phase()
-        with _global_mu:
-            _global_compiles.bump(kind, phase)
+        if event in (_LOWER_EVENT, _BACKEND_EVENT):
+            with _global_mu:
+                _global_compiles.bump(kind, phase)
         from ..obs import flight
         flight.note("compile", kind=kind, phase=phase,
-                    seconds=round(float(duration_secs), 4))
+                    seconds=round(float(duration_secs), 4),
+                    t0=t1 - float(duration_secs), t1=t1,
+                    fun=kw.get("fun_name"))
 
     def _on_event(event: str, **kw) -> None:
         if event == _CACHE_REQUEST_EVENT:

@@ -27,12 +27,6 @@ Two checks:
   collective-wait timer (``obs/ranks.py``), all of which knowingly
   measure the host loop — carry allowlist anchors; a new unreviewed
   timing site fails tier-1 until justified.
-* **(c) trace analytics off the hot path**: ``obs/tracing.py`` parses
-  profiler artifacts — a pure post-run analysis. Importing it (module-
-  or function-level) anywhere a jit-reachable function lives puts a
-  protobuf walk within reach of the training hot path; the analytics
-  must stay in post-run code (engine's post-session emit, scripts/obs,
-  bench's ledger step).
 """
 from __future__ import annotations
 
@@ -55,20 +49,6 @@ _SPAN_CLOSERS = {"stop", "end", "close", "__exit__"}
 
 #: blocking materializers that make host timing honest in the same body
 _BLOCKERS = {"block_until_ready"}
-
-#: trace-parse analytics modules that must stay off the hot path (c)
-_TRACE_MODULES = ("lightgbm_tpu.obs.tracing",)
-
-
-def _is_trace_import(mod: str, sym: Optional[str]) -> bool:
-    """Does an import entry resolve to the trace-analytics module?
-    Covers ``import lightgbm_tpu.obs.tracing``, ``from
-    lightgbm_tpu.obs import tracing``, relative ``from ..obs import
-    tracing`` (resolved), and ``from ..obs.tracing import X``."""
-    if mod in _TRACE_MODULES or mod.endswith(".obs.tracing"):
-        return True
-    return sym == "tracing" and (mod == "obs" or mod.endswith(".obs"))
-
 
 def _is_clock_call(module: ModuleInfo, node: ast.Call) -> Optional[str]:
     """The canonical clock name for a Call node, or None."""
@@ -132,30 +112,6 @@ class TimingRule(Rule):
               ) -> List[Finding]:
         out: List[Finding] = []
         reachable = {id(f) for f in package.reachable_functions(module)}
-        # (c) trace-parse analytics imported into a module that contains
-        # jit-reachable code: the xplane walk must stay post-run
-        if reachable and not (module.dotted or "").endswith("obs.tracing"):
-            for node in ast.walk(module.tree):
-                names = ()
-                if isinstance(node, ast.Import):
-                    names = [(a.name, None) for a in node.names]
-                elif isinstance(node, ast.ImportFrom):
-                    mod = module._resolve_relative(node.module, node.level)
-                    names = [(mod, a.name) for a in node.names]
-                for mod, sym in names:
-                    if sym is not None and sym.isupper():
-                        # an ALL-CAPS constant (SPAN_TAXONOMY) is shared
-                        # vocabulary, not parse machinery
-                        continue
-                    if _is_trace_import(mod, sym):
-                        out.append(self.finding(
-                            module, node, module.func_of(node),
-                            "trace-parse analytics (obs.tracing) "
-                            "imported into a module with jit-reachable "
-                            "code: artifact parsing is post-run only — "
-                            "move the import to the post-session emit "
-                            "path (engine), scripts/obs, or the bench "
-                            "ledger step"))
         for fn in module.functions.values():
             jit_reachable = id(fn) in reachable
             spans = _span_locals(fn)

@@ -147,6 +147,11 @@ class Dataset:
         """(reference: Dataset.construct, basic.py:2517)"""
         if self._inner is not None:
             return self
+        from .obs.spans import span
+        with span("construct"):
+            return self._construct()
+
+    def _construct(self) -> "Dataset":
         cfg = Config(self.params)
         if isinstance(self.data, str) and (self.data.endswith(".npz")
                                            or self.data.endswith(".bin")):
@@ -460,10 +465,6 @@ class Booster:
         self._train_data_name = "training"
         self._custom_objective: Optional[Callable] = None
         self._pending_finish = False
-        # device-time trace analytics (obs/tracing.py): set by
-        # engine.train after a full trace session closes; None means no
-        # artifact was recorded/parseable for this booster's run
-        self._device_time_analysis = None
 
         if train_set is not None:
             if not isinstance(train_set, Dataset):
@@ -476,19 +477,22 @@ class Booster:
             from .parallel.multihost import maybe_init_distributed
             maybe_init_distributed(params)
             train_set.construct()
-            self.config = Config(params)
-            objective = self.config.objective
-            if callable(objective):
-                self._custom_objective = objective
-                objective = None
-                obj = None
-            else:
-                obj = create_objective(objective, self.config)
-            from .boosting import create_boosting
-            self._gbdt = create_boosting(self.config, train_set._inner, obj)
-            self.train_set = train_set
-            self._gbdt.set_train_metrics(
-                create_metrics(self.config.metric, self.config))
+            from .obs.spans import span
+            with span("booster_init"):
+                self.config = Config(params)
+                objective = self.config.objective
+                if callable(objective):
+                    self._custom_objective = objective
+                    objective = None
+                    obj = None
+                else:
+                    obj = create_objective(objective, self.config)
+                from .boosting import create_boosting
+                self._gbdt = create_boosting(self.config, train_set._inner,
+                                             obj)
+                self.train_set = train_set
+                self._gbdt.set_train_metrics(
+                    create_metrics(self.config.metric, self.config))
             self._valid_names: List[str] = []
         elif model_file is not None or model_str is not None:
             from .model_io import load_booster
@@ -607,35 +611,41 @@ class Booster:
             raise NotImplementedError(
                 "changing train_set on update is not supported")
         from .analysis.guards import compile_phase
+        from .obs.spans import bump, span
         fobj = fobj or self._custom_objective
         t0 = time.perf_counter()
-        # every compile inside an update is attributed to the train_step
-        # phase (guards.compile_counter by_phase, the metrics plane, and
-        # the flight recorder all key on it)
-        with compile_phase("train_step"):
-            if fobj is not None:
-                grad, hess = _call_custom_objective(fobj, self)
-                finished = self._gbdt.train_one_iter(grad, hess)
+        # the update's span: the spans inside it carry the booster's
+        # iter_, and it holds the counters the closing tick reports
+        with span("iteration", iteration=self._gbdt.iter_):
+            # every compile inside an update is attributed to the
+            # train_step phase (guards.compile_counter by_phase, the
+            # metrics plane, and the flight recorder all key on it)
+            with compile_phase("train_step"):
+                if fobj is not None:
+                    grad, hess = _call_custom_objective(fobj, self)
+                    finished = self._gbdt.train_one_iter(grad, hess)
+                else:
+                    finished = self._gbdt.train_one_iter()
+            # sampled per-rank attribution (obs/ranks.py): at the
+            # tpu_rank_stats_every cadence ONLY, block on the step's
+            # device work so step_s is a real measurement (not
+            # dispatch), then let the rank-stats plane probe the
+            # collective and publish; off-sample iterations take neither
+            # the block nor the probe, so the steady-state 0-d2h guard
+            # holds between samples. The tick's seconds are captured
+            # BEFORE sample_step: the sampling overhead (barrier wait
+            # for a slow peer, the rank-0 KV gather) must not inflate
+            # the metrics stream's iteration wall
+            rank_stats = getattr(self._gbdt, "_rank_stats", None)
+            if rank_stats is not None and rank_stats.due(self._gbdt.iter_):
+                import jax
+                bump("host_syncs")
+                jax.block_until_ready(self._gbdt.train_score)
+                elapsed = time.perf_counter() - t0
+                rank_stats.sample_step(self._gbdt.iter_, elapsed)
             else:
-                finished = self._gbdt.train_one_iter()
-        # sampled per-rank attribution (obs/ranks.py): at the
-        # tpu_rank_stats_every cadence ONLY, block on the step's device
-        # work so step_s is a real measurement (not dispatch), then let
-        # the rank-stats plane probe the collective and publish;
-        # off-sample iterations take neither the block nor the probe, so
-        # the steady-state 0-d2h guard holds between samples. The tick's
-        # seconds are captured BEFORE sample_step: the sampling overhead
-        # (barrier wait for a slow peer, the rank-0 KV gather) must not
-        # inflate the metrics stream's iteration wall
-        rank_stats = getattr(self._gbdt, "_rank_stats", None)
-        if rank_stats is not None and rank_stats.due(self._gbdt.iter_):
-            import jax
-            jax.block_until_ready(self._gbdt.train_score)
-            elapsed = time.perf_counter() - t0
-            rank_stats.sample_step(self._gbdt.iter_, elapsed)
-        else:
-            elapsed = time.perf_counter() - t0
-        self._gbdt._obs_iteration_tick(elapsed)
+                elapsed = time.perf_counter() - t0
+            self._gbdt._obs_iteration_tick(elapsed)
         # a stop detected by a mid-training flush (e.g. in reset_parameter)
         pending, self._pending_finish = self._pending_finish, False
         return finished or pending

@@ -21,6 +21,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 import weakref
 
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -32,6 +33,7 @@ import numpy as np
 from ..io.dataset import BinnedDataset
 from ..metrics import Metric
 from ..objectives import Objective
+from ..obs.spans import bump, span, update_counters
 from ..ops.compact import RowLayout, pack_rows, segments_to_leaf_vectors
 from ..ops.grower import (GrowerParams, TreeArrays, depth_rung, grow_tree,
                           leaf_rung)
@@ -78,7 +80,6 @@ def _bound_gradients(obj, k_total: int, scores, label, weight):
     """Objective gradients with label/weight rebound to the compact grower's
     current row order (the objective's stored arrays are in the original
     order; see Objective.row_elementwise)."""
-    from ..obs.spans import span
     old_l, old_w = obj.label, obj.weight
     obj.label, obj.weight = label, weight
     try:
@@ -637,6 +638,9 @@ class GBDT:
         # below sees a plain dense matrix (bundling is lossless)
         self._efb_precheck(train_set, cfg, tree_learner)
 
+        # span `to_device` below: the binned matrix's first move to the
+        # device. Host seconds to hand it over; the copy may still be in
+        # flight when the span closes, nothing here waits for it
         binned_np = train_set.binned
         if pad:
             binned_np = np.pad(binned_np, ((0, pad), (0, 0)))
@@ -654,8 +658,9 @@ class GBDT:
             # over features and all-gathers the tiny best-split argmax, the
             # analogue of SyncUpGlobalBestSplit)
             from ..parallel.mesh import feature_sharding_2d
-            self.binned = jax.device_put(binned_np,
-                                         feature_sharding_2d(self.mesh))
+            with span("to_device"):
+                self.binned = jax.device_put(
+                    binned_np, feature_sharding_2d(self.mesh))
             ones = np.ones(self.num_data, np.float32)
             if pad:
                 ones[self._n_real:] = 0.0
@@ -666,8 +671,9 @@ class GBDT:
             # across machines (data_parallel_tree_learner.cpp BeforeTrain)
             if self._multiproc:
                 # assemble the global array from per-process local shards
-                self.binned = jax.make_array_from_process_local_data(
-                    row_sharding_2d(self.mesh), binned_np)
+                with span("to_device"):
+                    self.binned = jax.make_array_from_process_local_data(
+                        row_sharding_2d(self.mesh), binned_np)
                 self._valid_row_mask = None
             else:
                 s_feat = mesh_axis_sizes(self.mesh)[1]
@@ -679,15 +685,17 @@ class GBDT:
                     if self._f_pad:
                         binned_np = np.pad(binned_np,
                                            ((0, 0), (0, self._f_pad)))
-                self.binned = jax.device_put(
-                    binned_np, row_feature_sharding(self.mesh))
+                with span("to_device"):
+                    self.binned = jax.device_put(
+                        binned_np, row_feature_sharding(self.mesh))
                 ones = np.ones(self.num_data, np.float32)
                 if pad:
                     ones[self._n_real:] = 0.0
                 self._valid_row_mask = jax.device_put(
                     ones, row_sharding(self.mesh))
         else:
-            self.binned = jnp.asarray(binned_np)
+            with span("to_device"):
+                self.binned = jnp.asarray(binned_np)
             self._valid_row_mask = None
         def fpad(arr, fill):
             if self._f_pad:
@@ -1943,15 +1951,17 @@ class GBDT:
         self._boost_from_average()
         c = self._compact
         if c["step"] is None:
-            c["step"] = self._build_compact_step_fn()
+            with span("build_step"):
+                c["step"] = self._build_compact_step_fn()
         strat = self.sample_strategy
         n = self.num_data      # bag vectors align with work rows (incl. pad)
 
         # GOSS ranks rows by gradient magnitude; compute in current order
         g = h = None
         if strat.is_hessian_change:
-            g, h = self._compact_gradients()
-        mask = strat.bag_mask(self.iter_, g, h)
+            g, h = self._dispatch("gradient", self._compact_gradients)
+        with span("bag"):
+            mask = strat.bag_mask(self.iter_, g, h)
         # fresh == the strategy actually drew a new bag this iteration; a
         # reused (cached) bag must come from the stored sample-weight column,
         # which rode the partitions and is in the current row order — the
@@ -1970,14 +1980,16 @@ class GBDT:
         if getattr(self, "_ext_grads", False):
             # lambdarank-style coupled gradients: computed once per
             # iteration in original query order, permuted to current order
-            ext_args = tuple(self._rank_grads_fn()(
-                c["work"], self.train_score))
+            ext_args = tuple(self._dispatch(
+                "gradient", self._rank_grads_fn(), c["work"],
+                self.train_score))
         for k in range(k_total):
             # trees after the first in an iteration reuse the stored bag
             # (same bag for all trees of one iteration, like the reference)
             use_stored = not (fresh and k == 0)
             (tree, work, scratch, scores,
-             self._cegb_used) = c["step"](
+             self._cegb_used) = self._dispatch(
+                "step_dispatch", c["step"],
                 c["work"], c["scratch"], self.train_score, mask,
                 jnp.asarray(use_stored), feat_mask,
                 jnp.float32(self.shrinkage_rate),
@@ -2064,7 +2076,6 @@ class GBDT:
 
     def _gradients(self) -> Tuple[jax.Array, jax.Array]:
         """(reference: GBDT::Boosting, gbdt.cpp:220)"""
-        from ..obs.spans import span
         if self._grad_fn is None:
             base = self.objective.get_gradients
 
@@ -2285,11 +2296,12 @@ class GBDT:
                 self._use_compact = False
             else:
                 if self._compact is None:
-                    self._setup_compact_state()
+                    with span("compact_setup"):
+                        self._setup_compact_state()
                 return self._train_one_iter_compact()
         if gradients is None or hessians is None:
             self._boost_from_average()
-            grad, hess = self._gradients()
+            grad, hess = self._dispatch("gradient", self._gradients)
         else:
             g_np = np.asarray(gradients, np.float32).reshape(k, self._n_real)
             h_np = np.asarray(hessians, np.float32).reshape(k, self._n_real)
@@ -2302,8 +2314,10 @@ class GBDT:
             # zero padding-row gradients before GOSS ranks them
             grad = grad * self._valid_row_mask[None, :]
             hess = hess * self._valid_row_mask[None, :]
-        mask = self.sample_strategy.bag_mask(self.iter_, grad, hess)
-        grad, hess = self.sample_strategy.scale_grad_hess(mask, grad, hess)
+        with span("bag"):
+            mask = self.sample_strategy.bag_mask(self.iter_, grad, hess)
+            grad, hess = self.sample_strategy.scale_grad_hess(
+                mask, grad, hess)
         if mask is None:
             mask = jnp.ones((n,), jnp.float32)
         if self._valid_row_mask is not None:
@@ -2312,7 +2326,8 @@ class GBDT:
         feat_mask = self._feature_mask()
         first_iter = self.num_total_trees < self.num_tree_per_iteration
         if self._step_fn is None:
-            self._step_fn = self._build_step_fn()
+            with span("build_step"):
+                self._step_fn = self._build_step_fn()
         true_grad, true_hess = grad, hess
         if self._use_quant:
             # one global-scale quantization per iteration over all classes
@@ -2325,8 +2340,8 @@ class GBDT:
 
         for cur_tree_id in range(k):
             (tree, row_leaf, new_score, self._cegb_used,
-             self._cegb_charged) = self._step_fn(
-                self.binned,
+             self._cegb_charged) = self._dispatch(
+                "step_dispatch", self._step_fn, self.binned,
                 self.train_score[cur_tree_id], grad[cur_tree_id],
                 hess[cur_tree_id], mask, feat_mask,
                 jnp.float32(self.shrinkage_rate),
@@ -2378,22 +2393,43 @@ class GBDT:
             return self._flush_trees()
         return False
 
+    @staticmethod
+    def _dispatch(site, program, *args, **kwargs):
+        """One call of one of the booster's jitted programs from the
+        update loop, under the host span ``site`` (``step_dispatch``,
+        ``gradient``) and counted into the update's ``dispatches``. The
+        span times the dispatch (and, on a first call, the trace and
+        compile inside it), not the device's work: nothing here waits."""
+        with span(site):
+            bump("dispatches")
+            return program(*args, **kwargs)
+
     def _obs_iteration_tick(self, seconds: float) -> None:
-        """Per-update telemetry tick (called from Booster.update): one
-        flight-ring event and, when ``tpu_metrics_path`` is armed, one
-        JSONL record carrying CUMULATIVE phase-keyed compile counts and
-        persistent-cache counters — host-only reads (python ints and the
-        wall clock), so the steady-state 0-d2h guard holds with telemetry
-        fully enabled. ``iteration`` is the count of completed updates
-        (absolute, so resumed runs line up)."""
+        """Per-update telemetry tick (called from Booster.update, inside
+        its ``iteration`` span): one flight-ring event and, when
+        ``tpu_metrics_path`` is armed, one JSONL record carrying
+        CUMULATIVE phase-keyed compile counts and persistent-cache
+        counters — host-only reads (python ints and the wall clock), so
+        the steady-state 0-d2h guard holds with telemetry fully enabled.
+
+        ``iteration`` is the count of completed updates (absolute, so
+        resumed runs line up; the update's spans carry the ``iter_`` it
+        started from, one less). ``seconds`` is the update's wall on the
+        host: dispatch time, unless something in the update blocked on
+        the device — ``flush_trees`` does every ``stop_check_freq``-th
+        update, and then ``seconds`` holds the step's device time too.
+        ``dispatches``, ``host_syncs`` and ``d2h_bytes`` are the update's
+        counters (obs/spans.py); ``t1`` is now on the spans' clock."""
         from ..analysis import guards
         from ..obs import flight
+        counters = update_counters()
         flight.note("iteration", iteration=self.iter_,
-                    seconds=round(seconds, 6))
+                    seconds=round(seconds, 6), t1=time.perf_counter(),
+                    **counters)
         stream = getattr(self, "_metrics_stream", None)
         if stream is not None:
             stream.emit("iteration", iteration=self.iter_,
-                        seconds=round(seconds, 6),
+                        seconds=round(seconds, 6), **counters,
                         compiles=guards.phase_compile_counts(),
                         cache=guards.global_cache_counts())
 
@@ -2476,12 +2512,18 @@ class GBDT:
         # one batched device_get of all pending trees; deliberately NOT a
         # jnp.stack program — its shape would depend on the pending count and
         # recompile for every distinct flush size
-        if getattr(self, "_multiproc", False):
-            # replicated device trees are not fully addressable across
-            # processes; pull the local replica of each array
-            host_trees = jax.tree.map(_to_host, trees)
-        else:
-            host_trees = jax.device_get(trees)
+        # span `flush_trees`: the one site where the update loop blocks on
+        # the device (the fetch waits for the step that grew the trees)
+        with span("flush_trees"):
+            bump("host_syncs")
+            if getattr(self, "_multiproc", False):
+                # replicated device trees are not fully addressable across
+                # processes; pull the local replica of each array
+                host_trees = jax.tree.map(_to_host, trees)
+            else:
+                host_trees = jax.device_get(trees)
+            bump("d2h_bytes", sum(
+                leaf.nbytes for leaf in jax.tree.leaves(host_trees)))
         # copy-on-write: mutate a private list and rebind once, so code
         # reading self.models WITHOUT the trees mutex (model text dumps,
         # leaf-value bounds) always sees a self-consistent list — either
@@ -2536,13 +2578,16 @@ class GBDT:
         self._update_valid_scores(tree, cur_tree_id)
 
     def _update_valid_scores(self, tree: TreeArrays, cur_tree_id: int) -> None:
-        for vs in self.valid_sets:
-            leaf = route_one_tree(
-                vs.binned, tree.split_feature, tree.split_bin,
-                tree.cat_bitset, tree.default_left, tree.left_child,
-                tree.right_child, tree.num_nodes, *self._route_args())
-            vs.score = vs.score.at[cur_tree_id].set(
-                _add_leaf_outputs(vs.score[cur_tree_id], tree.leaf_value, leaf))
+        if not self.valid_sets:
+            return
+        with span("valid_scores"):
+            for vs in self.valid_sets:
+                leaf = route_one_tree(
+                    vs.binned, tree.split_feature, tree.split_bin,
+                    tree.cat_bitset, tree.default_left, tree.left_child,
+                    tree.right_child, tree.num_nodes, *self._route_args())
+                vs.score = vs.score.at[cur_tree_id].set(_add_leaf_outputs(
+                    vs.score[cur_tree_id], tree.leaf_value, leaf))
 
     def apply_tree_to_scores(self, host: HostTree, cur_tree_id: int,
                              factor: float, train: bool = True,

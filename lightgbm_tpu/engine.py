@@ -109,47 +109,7 @@ def train(
             if path and not interrupted:
                 log.warning(f"flight recorder dumped to {path}")
             raise
-    # device-time trace analytics (obs/tracing.py): the profiler only
-    # writes its artifact when the session CLOSES, so the parse runs
-    # here — after the with-block, strictly off the training path — and
-    # emits the per-phase DEVICE-time table next to the host phase table
-    # the summary already carries (device_seconds vs host_seconds; a
-    # reader diffing the two sees host-dispatch skew instead of
-    # mistaking it for compute)
-    if trace_dir and trace_mode == "full":
-        _emit_device_time(booster, trace_dir, obs_baseline)
     return booster
-
-
-def _emit_device_time(booster: Booster, trace_dir: str,
-                      obs_baseline: Dict[str, Any]) -> None:
-    """Parse the just-closed profiler artifact and emit the
-    ``device_time`` metrics record. Best-effort: analytics must never
-    fail a run that already trained."""
-    from . import obs
-    from .obs import flight, tracing
-    try:
-        analysis = tracing.analyze_trace_dir(trace_dir)
-    except Exception as err:  # noqa: BLE001 - telemetry is best-effort
-        log.warning(f"trace analytics failed for {trace_dir}: {err}")
-        return
-    if analysis is None:
-        log.warning(f"tpu_trace_dir={trace_dir} left no xplane artifact "
-                    "to analyze")
-        return
-    host_phases = obs.spans.phase_times_since(obs_baseline["phase"])
-    stream = booster._gbdt._metrics_stream
-    if stream is not None:
-        stream.emit("device_time", host_phase_times=host_phases,
-                    **analysis)
-    decomp = analysis.get("decomposition", {})
-    flight.note("device_time", source=analysis.get("source"),
-                phases={k: v.get("device_seconds")
-                        for k, v in analysis.get("phases", {}).items()},
-                **{k: decomp.get(k) for k in ("busy_seconds",
-                                              "comm_seconds",
-                                              "idle_seconds")})
-    booster._device_time_analysis = analysis
 
 
 def _train_impl(

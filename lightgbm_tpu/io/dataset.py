@@ -308,23 +308,26 @@ class BinnedDataset:
         else:
             cat_idx = _resolve_categorical(categorical_feature, ds.feature_names)
             ds.categorical_features = sorted(cat_idx)
-            # sample rows for bin construction (reference: bin_construct_sample_cnt)
-            if n > bin_construct_sample_cnt:
-                rng = np.random.RandomState(data_random_seed)
-                sample_idx = rng.choice(n, size=bin_construct_sample_cnt, replace=False)
-                sample = arr[np.sort(sample_idx)]
-            else:
-                sample = arr
-            # multi-host: every process contributes its sample and all build
-            # identical mappers from the pooled global distribution
-            # (reference: ConstructBinMappersFromTextData,
-            # src/io/dataset_loader.cpp:1070)
-            from ..parallel.multihost import pool_bin_sample
-            sample = pool_bin_sample(sample)
-            total_sample_cnt = len(sample)
-            _fit_mappers(ds, sample, f, cat_idx, max_bin, min_data_in_bin,
-                         use_missing, zero_as_missing, forcedbins_filename,
-                         max_bin_by_feature)
+            from ..obs.spans import span
+            with span("find_bins"):
+                # sample rows for bin construction (reference:
+                # bin_construct_sample_cnt)
+                if n > bin_construct_sample_cnt:
+                    rng = np.random.RandomState(data_random_seed)
+                    sample_idx = rng.choice(
+                        n, size=bin_construct_sample_cnt, replace=False)
+                    sample = arr[np.sort(sample_idx)]
+                else:
+                    sample = arr
+                # multi-host: every process contributes its sample and all
+                # build identical mappers from the pooled global
+                # distribution (reference: ConstructBinMappersFromTextData,
+                # src/io/dataset_loader.cpp:1070)
+                from ..parallel.multihost import pool_bin_sample
+                sample = pool_bin_sample(sample)
+                _fit_mappers(ds, sample, f, cat_idx, max_bin,
+                             min_data_in_bin, use_missing, zero_as_missing,
+                             forcedbins_filename, max_bin_by_feature)
 
         # bin all columns — batched over row chunks and column groups
         # (io/binning.py bin_columns, the construct hot path)

@@ -4,30 +4,29 @@ One observability subsystem spanning training, collectives, and serving
 (the reproduction's answer to the reference's ``USE_TIMETAG``
 ``Common::Timer`` registry plus the ops tooling it never had):
 
-* :mod:`.spans` — phase-named spans (``span("hist_build")``): zero-cost
-  when disabled, ``jax.named_scope`` under trace so DEVICE programs carry
-  the phase names into the ``tpu_trace_dir`` Perfetto/TensorBoard trace,
-  host timing + ``TraceAnnotation`` at the declared tick sites;
-  ``trace_session`` owns the ``tpu_trace_dir``/``tpu_trace_mode`` knobs.
-* :mod:`.flight` — bounded ring of structured events (iteration ticks,
-  phase-keyed compile events, collective byte accounting, fault fires,
+* :mod:`.spans` — phase-named spans (``span("hist_build")``):
+  ``jax.named_scope`` under trace, so DEVICE programs carry the phase
+  names in their op metadata; on the host one ``span`` record in the
+  flight ring (name, t0, t1, parent, iteration) + the phase table +
+  a ``TraceAnnotation``. Set-up and the update loop record always, the
+  serving ticks inside a ``trace_session``, which owns the
+  ``tpu_trace_dir``/``tpu_trace_mode`` knobs.
+* :mod:`.flight` — bounded ring of structured events (host spans,
+  iteration ticks with the update's counters, phase-keyed compile
+  events on the spans' clock, collective byte accounting, fault fires,
   deadline/retry outcomes), dumped as JSONL on ``TrainingInterrupted``,
   on a blown hot-swap, and at checkpoint ticks (``tpu_flight_buffer``).
+  Read in-process by the benchmark's reducers.
 * :mod:`.metrics` — per-iteration JSONL stream (``tpu_metrics_path``;
   bench.py derives its BENCH-row counters from it) and a pull-based
   Prometheus-text endpoint served from PredictionServer
   (``--metrics-port`` on ``scripts/serve``). stdlib HTTP, no new deps.
-* :mod:`.summarize` — ``scripts/obs``: per-phase time share + compile /
-  collective totals from any of the above artifacts (the
-  ``Common::Timer::Print`` analogue), jax-free; subcommands ``trace``
-  (device-time table from a profiler artifact) and ``merge``
-  (cross-rank flight-dump timeline).
-* :mod:`.tracing` — device-time trace analytics: parses the
-  ``tpu_trace_dir`` xplane artifact (jax-free protobuf wire reader) and
-  maps timed device events back to the span taxonomy — the per-phase
-  DEVICE-seconds table, per-collective durations, MXU/comm/idle
-  decomposition. Post-run only; tpulint R009c keeps it out of
-  jit-reachable modules.
+* :mod:`.summarize` — ``scripts/obs``: per-phase host time share, the
+  per-iteration table, compile / collective totals from any of the
+  above artifacts (the ``Common::Timer::Print`` analogue), jax-free;
+  subcommands ``merge`` (cross-rank flight-dump timeline) and ``drift``.
+* :mod:`.tracing` — the span taxonomy (the names), jax-free. Device
+  time is the benchmark's to reduce (``benchmarks/trace.py``).
 * :mod:`.ranks` — per-rank runtime attribution: sampled step /
   collective-wait timers published over the coordination-service KV,
   rank-0 median/p99/max aggregation + straggler flags

@@ -13,8 +13,11 @@ device access), and the ring is dumped as JSONL
 * at every checkpoint tick (so even a SIGKILL leaves the ring as of the
   last durable snapshot).
 
-Events recorded by the shipped hooks: iteration ticks, compile events
-(phase-keyed, via analysis/guards), persistent-cache hits/misses,
+Events recorded by the shipped hooks: host spans (``span`` records:
+``name``, ``t0``/``t1`` on ``time.perf_counter()``, ``parent``,
+``iteration`` — obs/spans.py), iteration ticks with the update's
+counters, compile events (phase-keyed and on the same ``t0``/``t1``
+clock, via analysis/guards), persistent-cache hits/misses,
 collective-program byte accounting (analysis/hlo.py, when
 LGBM_TPU_COMM_ACCOUNTING=1), fault-injection fires, collective deadline /
 transient-retry outcomes, checkpoint writes, serving swaps and worker
@@ -108,6 +111,13 @@ class FlightRecorder:
         with self._mu:
             return list(self._ring)
 
+    def dropped(self) -> int:
+        """Records the ring has let go since process start (or the last
+        ``clear``). A reader that sums over the ring checks this first:
+        with records dropped, a sum over what is left is a short sum."""
+        with self._mu:
+            return max(0, self._seq - len(self._ring))
+
     def clear(self) -> None:
         with self._mu:
             self._ring.clear()
@@ -157,7 +167,7 @@ class FlightRecorder:
         try:
             with self._mu:
                 events = list(self._ring)
-                seq = self._seq
+                dropped = max(0, self._seq - len(events))
             out = self._resolve_path(path)
             d = os.path.dirname(out)
             if d:
@@ -168,7 +178,7 @@ class FlightRecorder:
                           "rank": _process_rank(),
                           "capacity": self._capacity,
                           "events": len(events),
-                          "dropped": max(0, seq - len(events))}
+                          "dropped": dropped}
                 if extra:
                     header.update(extra)
                 fh.write(json.dumps(header, default=str) + "\n")

@@ -1,4 +1,4 @@
-"""Phase-named spans: one API, two faces (device trace names + host timing).
+"""Phase-named spans: one API, two faces (device trace names + host records).
 
 The reference attributes wall time to every training phase through its
 ``USE_TIMETAG`` ``Common::Timer`` registry (include/LightGBM/utils/log.h:
@@ -7,27 +7,45 @@ two, because the two interesting clocks live in different places:
 
 * **Device time** belongs to the profiler. A span entered while jax is
   TRACING wraps the region in ``jax.named_scope``, so the lowered HLO ops
-  carry the phase name and the Perfetto/TensorBoard trace that
-  ``tpu_trace_dir`` emits shows ``hist_build`` / ``split_scan`` /
-  ``collective_reduce`` lanes instead of a wall of fused ops. This costs
-  nothing at runtime — the scope only exists at trace time.
+  carry the phase name in their op metadata. The chip's profile does NOT
+  name its events by that scope: an ``XLA Ops`` event is named by its HLO
+  line (``%fusion.3 = ...``, ``%fused_split_step.17 = ...``), and the
+  scope path is metadata one click down in XProf. Only the fused kernel's
+  two call sites are told apart by event name (``ops/fused_split.py``
+  names each); device seconds by scope are reduced nowhere yet (PERF.md,
+  section 7).
 * **Host time** belongs to the orchestration loop. A span entered outside
-  tracing (checkpoint writes, serve ticks, warmup rungs) wraps the region
-  in ``jax.profiler.TraceAnnotation`` and accumulates wall time into the
-  per-phase table that :mod:`..obs.summarize` prints — the
-  ``Common::Timer::Print`` analogue. Host timing around ASYNC dispatch
-  measures dispatch, not device work (tpulint R009 exists to keep naive
-  timing out of jit-reachable code); host spans are therefore placed only
-  at the declared tick sites, where the host genuinely blocks.
+  tracing appends one ``span`` record to the flight ring (obs/flight.py)
+  — ``name``, ``t0``/``t1`` on ``time.perf_counter()``, ``parent`` (the
+  enclosing host span, from a thread-local stack), ``iteration`` (the
+  booster's ``iter_`` inside ``Booster.update()``, else None) — feeds the
+  per-phase table that :func:`phase_times` returns, and enters a
+  ``jax.profiler.TraceAnnotation`` of the same name: free with no
+  profiler listening, and with one it puts the span on the trace's host
+  timeline beside the device's, for whoever opens the xplane in XProf or
+  Perfetto. Host timing around ASYNC dispatch measures dispatch, not
+  device work (tpulint R009 keeps naive timing out of jit-reachable
+  code): of the update loop's spans only ``flush_trees`` blocks on the
+  device, and its seconds are the wait for the step, not host work.
 
-Zero-cost-when-disabled contract: with no trace session active,
-``span(name)`` outside tracing returns one shared no-op context manager —
-two attribute reads, no allocation. Enablement comes from
-:func:`trace_session` (the ``tpu_trace_dir``/``tpu_trace_mode`` context
-engine.train holds for the whole run): ``mode="full"`` starts a real
-``jax.profiler.trace`` AND enables host spans; ``mode="annotations"``
-enables the spans (device names + host phase table) without the profiler
-— the cheap always-on-able flavor.
+Which host spans record. The spans of set-up and of the update loop
+(:data:`ALWAYS_ON`) record whenever they are entered: the ring is on by
+default, ``Booster.update()`` writes its ``iteration`` event there anyway,
+and an update enters about five of them (a dict and a locked append each:
+microseconds against an iteration). Every other host span (the serving
+ticks, ``checkpoint_write``, ``autotune``) records only inside a
+:func:`trace_session` and is otherwise one shared no-op — a coalescer
+ticking thousands of times a second would turn the ring over and push
+out the compile and iteration events a post-mortem wants.
+``trace_session`` is the ``tpu_trace_dir``/``tpu_trace_mode`` context
+engine.train holds for the whole run: ``mode="full"`` starts a real
+``jax.profiler.trace`` too, ``mode="annotations"`` does not.
+
+Inside ``Booster.update()`` the ``iteration`` span also holds the
+update's counters (:func:`bump`): ``dispatches`` (calls of the booster's
+own jitted programs: the step, the gradients), ``host_syncs`` (entries
+into sites that block on the device) and ``d2h_bytes``; the closing
+``iteration`` event reports them (``GBDT._obs_iteration_tick``).
 
 Span taxonomy (every name a device program or tick site carries):
 
@@ -35,6 +53,8 @@ Span taxonomy (every name a device program or tick site carries):
 ``binning``               io/binning.bin_columns — raw values -> bin codes
                           (dataset construct AND the serve-time bin_matrix)
 ``gradient``              objective gradients/hessians for the iteration
+                          (named scope in the program; host face where the
+                          gradient program is called)
 ``hist_build``            per-leaf histogram accumulation (all engines)
 ``collective_reduce``     psum/psum_scatter of histograms over the mesh
 ``split_scan``            best-split scan over the histogram bins
@@ -44,6 +64,21 @@ Span taxonomy (every name a device program or tick site carries):
 ``serve_tick``            one coalescer micro-batch device dispatch
 ``autotune``              the startup engine microbench sweep
                           (engines/autotune.py — strictly pre-steady-state)
+``import``                ``import lightgbm_tpu`` (stamped, not entered)
+``construct``             all of ``Dataset.construct()``; children
+                          ``find_bins`` (the sample and the boundaries)
+                          and ``binning``
+``booster_init``          ``Booster.__init__`` with a train set, past its
+                          ``construct``; child ``to_device`` (the binned
+                          matrix's first move to the device, in
+                          ``GBDT._setup_train``)
+``compact_setup``         ``_setup_compact_state`` (first update)
+``build_step``            ``_build_compact_step_fn`` / ``_build_step_fn``
+``iteration``             all of ``Booster.update()``; children ``bag``,
+                          ``gradient``, ``step_dispatch`` (one per call of
+                          the jitted step), ``valid_scores``,
+                          ``flush_trees`` (the ``device_get`` of the
+                          pending trees: where the loop blocks)
 ========================  ==================================================
 """
 from __future__ import annotations
@@ -55,13 +90,30 @@ from typing import Dict, Iterator, Optional, Set
 
 import jax
 
+from . import flight
 #: the complete phase-name taxonomy (tests assert a traced+served run
 #: touches every one of these). Canonical copy lives in obs/tracing.py
-#: (jax-free, so scripts/obs can attribute trace phases with no backend);
+#: (jax-free, so scripts/obs can name phases with no backend);
 #: re-exported here because spans is the producer side of the same names.
 from .tracing import SPAN_TAXONOMY  # noqa: E402,F401
 
 _TRACE_MODES = ("full", "annotations")
+
+#: host spans that record with no session active: set-up and the update
+#: loop (module docstring: which host spans record)
+ALWAYS_ON = frozenset((
+    "import", "construct", "find_bins", "binning", "to_device",
+    "booster_init", "compact_setup", "build_step",
+    "iteration", "bag", "gradient", "step_dispatch", "valid_scores",
+    "flush_trees"))
+
+#: the update's counters (:func:`bump`), as its ``iteration`` event and
+#: the metrics stream's record carry them
+COUNTERS = ("dispatches", "host_syncs", "d2h_bytes")
+
+#: per-thread: the stack of open host spans and, inside Booster.update(),
+#: the booster's iter_ and the update's counters
+_local = threading.local()
 
 _mu = threading.Lock()
 _enabled = 0                      # nesting count of enabling sessions
@@ -128,42 +180,86 @@ class _TracedSpan:
 
 
 class _HostSpan:
-    """Span entered on the host: profiler annotation + phase-time entry."""
+    """Span entered on the host: ring record + phase-table entry +
+    profiler annotation. ``iteration`` (given by ``Booster.update``
+    alone) makes this the update's span: the spans inside it carry that
+    number, and :func:`bump` counts into it."""
 
-    __slots__ = ("_name", "_ann", "_t0")
+    __slots__ = ("_name", "_ann", "_t0", "_parent", "_iteration", "_outer")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, iteration: Optional[int] = None):
         self._name = name
         self._ann = jax.profiler.TraceAnnotation(name)
+        self._iteration = iteration
 
     def __enter__(self) -> "_HostSpan":
+        if self._iteration is not None:
+            self._outer = (getattr(_local, "iteration", None),
+                           getattr(_local, "counters", None))
+            _local.iteration, _local.counters = int(self._iteration), {}
+        stack = getattr(_local, "stack", None)
+        if stack is None:
+            stack = _local.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self._name)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dt = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
         self._ann.__exit__(*exc)
-        with _mu:
-            _mark_seen(self._name)
-            _phase_s[self._name] = _phase_s.get(self._name, 0.0) + dt
-            _phase_n[self._name] = _phase_n.get(self._name, 0) + 1
+        _local.stack.pop()
+        record(self._name, self._t0, t1, self._parent,
+               getattr(_local, "iteration", None))
+        if self._iteration is not None:
+            _local.iteration, _local.counters = self._outer
         return False
 
 
-def span(name: str):
+def record(name: str, t0: float, t1: float, parent: Optional[str] = None,
+           iteration: Optional[int] = None) -> None:
+    """One finished host span: a ``span`` record in the flight ring and
+    an entry in the phase table. What a host span's exit calls; called
+    directly where a span is stamped and not entered (``import``)."""
+    flight.note("span", name=name, t0=t0, t1=t1, parent=parent,
+                iteration=iteration)
+    with _mu:
+        _mark_seen(name)
+        _phase_s[name] = _phase_s.get(name, 0.0) + (t1 - t0)
+        _phase_n[name] = _phase_n.get(name, 0) + 1
+
+
+def span(name: str, iteration: Optional[int] = None):
     """The phase span for ``name`` — see the module docstring.
 
     Under tracing: a ``named_scope`` (always, enablement aside — trace
     time is the only chance to name the device ops, and it is free at
-    runtime). On the host: a timing+annotation span when a trace session
-    is active, else the shared no-op.
+    runtime). On the host: a recording span when the name is one of
+    :data:`ALWAYS_ON` or a trace session is active, else the shared
+    no-op.
     """
     if not _trace_state_clean():
         return _TracedSpan(name)
-    if _enabled:
-        return _HostSpan(name)
+    if _enabled or name in ALWAYS_ON:
+        return _HostSpan(name, iteration)
     return _NULL
+
+
+def bump(counter: str, n: int = 1) -> None:
+    """Add ``n`` to one of the current update's counters. Outside
+    ``Booster.update()`` there is no update to count for and nothing is
+    counted: a flush from ``predict`` is not an update's sync."""
+    counters = getattr(_local, "counters", None)
+    if counters is not None:
+        counters[counter] = counters.get(counter, 0) + n
+
+
+def update_counters() -> Dict[str, int]:
+    """The counters of the update this thread is inside (zeros outside
+    one): what ``GBDT._obs_iteration_tick`` writes into its event."""
+    counted = getattr(_local, "counters", None) or {}
+    return {name: counted.get(name, 0) for name in COUNTERS}
 
 
 def annotations_enabled() -> bool:
@@ -191,8 +287,8 @@ def disable_annotations() -> None:
 
 
 def seen_spans() -> Set[str]:
-    """Span names observed so far (traced into a program, or entered on
-    the host inside a session)."""
+    """Span names observed so far (traced into a program, or recorded
+    on the host)."""
     with _mu:
         return set(_seen)
 

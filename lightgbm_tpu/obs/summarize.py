@@ -12,11 +12,14 @@ offline from the observability artifacts a run leaves behind — a
                                               # latest PSI flush + SLO
                                               # burn tail (obs/drift.py)
 
-prints per-phase host time share, phase-keyed compile totals, persistent-
-cache hit/miss, collective-program byte totals (when the run captured
-them via LGBM_TPU_COMM_ACCOUNTING), iteration throughput, and the tail
-of notable events (faults, deadlines, restarts, swaps) — the post-mortem
-read of a dead run, or the profile read of a healthy one.
+prints per-phase host time share (from a summary record, else summed
+from a dump's ``span`` records), the per-iteration table (seconds and the
+update's counters: dispatches, host syncs, bytes fetched), phase-keyed
+compile totals and a dump's compile seconds by kind, persistent-cache
+hit/miss, collective-program byte totals (when the run captured them via
+LGBM_TPU_COMM_ACCOUNTING), and the tail of notable events (faults,
+deadlines, restarts, swaps) — the post-mortem read of a dead run, or the
+profile read of a healthy one.
 
 This module is intentionally jax-free (plain json/os), so ``scripts/obs``
 runs anywhere in milliseconds, including hosts without a backend.
@@ -28,6 +31,9 @@ import json
 import os
 import sys
 from typing import Any, Dict, List, Optional, Sequence
+
+#: the update's counters an ``iteration`` record carries (obs/spans.py)
+ITERATION_COUNTERS = ("dispatches", "host_syncs", "d2h_bytes")
 
 #: event kinds surfaced in the "notable events" tail
 NOTABLE = ("fault_fire", "deadline", "retry", "crash",
@@ -62,10 +68,12 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
     collectives: Dict[str, Dict[str, Any]] = {}
     iters = 0
     iter_seconds = 0.0
+    per_iteration: List[Dict[str, Any]] = []
+    span_times: Dict[str, Dict[str, float]] = {}
+    compile_seconds: Dict[str, float] = {}
     notable: List[Dict[str, Any]] = []
     spans_seen: List[str] = []
     dump_header: Optional[Dict[str, Any]] = None
-    device_time: Optional[Dict[str, Any]] = None
     rank_stats: Optional[Dict[str, Any]] = None
     stragglers: List[Dict[str, Any]] = []
 
@@ -74,6 +82,9 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         if k == "iteration":
             iters += 1
             iter_seconds += float(rec.get("seconds", 0.0) or 0.0)
+            per_iteration.append(
+                {key: rec.get(key) for key in
+                 ("iteration", "seconds") + ITERATION_COUNTERS})
             if isinstance(rec.get("compiles"), dict):
                 compiles = rec["compiles"]     # cumulative: keep the last
             if isinstance(rec.get("cache"), dict):
@@ -88,14 +99,18 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
             if isinstance(rec.get("spans_seen"), list):
                 spans_seen = sorted(set(spans_seen)
                                     | set(rec["spans_seen"]))
+        elif k == "span":
+            slot = span_times.setdefault(
+                str(rec.get("name")), {"seconds": 0.0, "count": 0})
+            slot["seconds"] += float(rec["t1"]) - float(rec["t0"])
+            slot["count"] += 1
+        elif k == "compile":
+            kind = str(rec.get("kind"))
+            compile_seconds[kind] = compile_seconds.get(kind, 0.0) \
+                + float(rec.get("seconds", 0.0) or 0.0)
         elif k == "collective_program":
             collectives[str(rec.get("key"))] = {
                 "bytes": rec.get("bytes"), "total": rec.get("total")}
-        elif k == "device_time":
-            device_time = rec                  # one per run: keep the last
-            if isinstance(rec.get("host_phase_times"), dict) \
-                    and not phase_times:
-                phase_times = rec["host_phase_times"]
         elif k == "rank_stats":
             rank_stats = rec                   # cumulative-ish: keep last
         elif k == "straggler":
@@ -105,15 +120,19 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         if k in NOTABLE:
             notable.append(rec)
 
+    # a run's summary record carries the whole run's table; a dump has
+    # only what its ring still holds, and is the fallback
+    phase_times = phase_times or span_times
     total_phase_s = sum(float(v.get("seconds", 0.0) or 0.0)
                         for v in phase_times.values()) or None
     return {
         "records": len(records),
         "iterations": iters,
         "iter_seconds_mean": (iter_seconds / iters) if iters else None,
+        "per_iteration": per_iteration[-20:],
+        "compile_seconds": compile_seconds,
         "phase_times": phase_times,
         "phase_total_seconds": total_phase_s,
-        "device_time": device_time,
         "rank_stats": rank_stats,
         "stragglers": stragglers[-20:],
         "compiles": compiles,
@@ -203,46 +222,35 @@ def _fmt_table(summary: Dict[str, Any]) -> str:
     lines: List[str] = []
     pt = summary["phase_times"]
     total = summary["phase_total_seconds"]
-    dt = summary.get("device_time") or {}
-    dev_phases = dt.get("phases") or {}
     lines.append(f"records: {summary['records']}  "
                  f"iterations: {summary['iterations']}"
                  + (f"  mean iter: {summary['iter_seconds_mean']:.4f}s"
                     if summary["iter_seconds_mean"] else ""))
-    if pt or dev_phases:
-        # host and device seconds SIDE BY SIDE: the host column is wall
-        # clock at the tick sites (dispatch included), the device column
-        # is profiler-measured op time — a large host/device gap on the
-        # same phase is dispatch skew, not compute
+    if pt:
+        # wall clock at the host tick sites: dispatch time, except where
+        # the site blocks on the device (flush_trees); spans nest, so the
+        # shares do not add up to one
         lines.append("")
         lines.append(f"{'phase':<20} {'host_s':>10} {'share':>7} "
-                     f"{'count':>8} {'device_s':>10}")
-        names = set(pt) | set(dev_phases)
-        for name in sorted(names, key=lambda n: -max(
-                float((pt.get(n) or {}).get("seconds", 0) or 0),
-                float((dev_phases.get(n) or {}).get(
-                    "device_seconds", 0) or 0))):
-            v = pt.get(name) or {}
-            s = float(v.get("seconds", 0.0) or 0.0)
-            share = (s / total) if total else 0.0
-            host = f"{s:>10.3f}" if name in pt else f"{'-':>10}"
-            d = dev_phases.get(name) or {}
-            dev = (f"{float(d.get('device_seconds', 0.0)):>10.4f}"
-                   if name in dev_phases else f"{'-':>10}")
-            lines.append(f"{name:<20} {host} {share:>6.1%} "
-                         f"{int(v.get('count', 0) or 0):>8} {dev}")
-    if dt:
-        d = dt.get("decomposition") or {}
+                     f"{'count':>8}")
+        for name, v in sorted(pt.items(), key=lambda kv: -float(
+                kv[1].get("seconds", 0) or 0)):
+            sec = float(v.get("seconds", 0.0) or 0.0)
+            lines.append(f"{name:<20} {sec:>10.3f} "
+                         f"{(sec / total) if total else 0.0:>6.1%} "
+                         f"{int(v.get('count', 0) or 0):>8}")
+    rows = [r for r in summary.get("per_iteration") or []
+            if any(r.get(c) is not None for c in ITERATION_COUNTERS)]
+    if rows:
         lines.append("")
-        lines.append(
-            f"device timeline ({dt.get('source')}): "
-            f"busy {d.get('busy_seconds', 0):.4f}s = "
-            f"mxu {d.get('mxu_seconds', 0):.4f}s + "
-            f"comm {d.get('comm_seconds', 0):.4f}s + other; "
-            f"idle {d.get('idle_seconds', 0):.4f}s")
-        for key, v in sorted((dt.get("collectives") or {}).items()):
-            lines.append(f"  collective {key:<22} "
-                         f"{v.get('seconds', 0):.6f}s x{v.get('count')}")
+        lines.append(f"{'iteration':>9} {'seconds':>10} {'dispatches':>10} "
+                     f"{'host_syncs':>10} {'d2h_bytes':>10}")
+        for r in rows:
+            lines.append(
+                f"{r.get('iteration')!s:>9} "
+                f"{float(r.get('seconds') or 0.0):>10.4f} "
+                + " ".join(f"{int(r.get(c) or 0):>10}"
+                           for c in ITERATION_COUNTERS))
     rs = summary.get("rank_stats")
     if rs:
         lines.append("")
@@ -266,6 +274,10 @@ def _fmt_table(summary: Dict[str, Any]) -> str:
         for p, d in sorted((comp.get("by_phase") or {}).items()):
             lines.append(f"  {p:<18} {d.get('lowerings', 0):>4} lowerings "
                          f"{d.get('backend_compiles', 0):>4} backend")
+    if summary.get("compile_seconds"):
+        lines.append("compile seconds by kind (nested kinds overlap): "
+                     + ", ".join(f"{k} {v:.3f}" for k, v in sorted(
+                         summary["compile_seconds"].items())))
     cache = summary["cache"]
     if cache:
         lines.append(f"compile cache: {cache.get('hits', 0)}/"
@@ -371,42 +383,6 @@ def merge_main(argv: Sequence[str]) -> int:
     else:
         print(_fmt_merge(merged))
     return 0
-
-
-def _fmt_trace(analysis: Dict[str, Any]) -> str:
-    """The per-phase device-time table of one profiler artifact."""
-    lines = [f"trace: {analysis.get('trace_dir', '')} "
-             f"({', '.join(analysis.get('files', []))}) "
-             f"source={analysis.get('source')} "
-             f"lanes={analysis.get('lanes')}"]
-    phases = analysis.get("phases") or {}
-    if phases:
-        lines.append("")
-        lines.append(f"{'phase':<20} {'device_s':>12} {'events':>8}")
-        for name, v in sorted(phases.items(),
-                              key=lambda kv: -float(
-                                  kv[1].get("device_seconds", 0) or 0)):
-            lines.append(f"{name:<20} "
-                         f"{float(v.get('device_seconds', 0)):>12.6f} "
-                         f"{int(v.get('events', 0)):>8}")
-    un = float(analysis.get("unattributed_seconds", 0.0) or 0.0)
-    if un:
-        lines.append(f"{'(unattributed)':<20} {un:>12.6f}")
-    d = analysis.get("decomposition") or {}
-    lines.append("")
-    lines.append(f"timeline: total {d.get('total_seconds', 0):.6f}s  "
-                 f"busy {d.get('busy_seconds', 0):.6f}s  "
-                 f"mxu {d.get('mxu_seconds', 0):.6f}s  "
-                 f"comm {d.get('comm_seconds', 0):.6f}s  "
-                 f"idle {d.get('idle_seconds', 0):.6f}s")
-    for key, v in sorted((analysis.get("collectives") or {}).items()):
-        lines.append(f"  collective {key:<22} "
-                     f"{v.get('seconds', 0):.6f}s x{v.get('count')}")
-    if analysis.get("spans_lowered"):
-        lines.append("")
-        lines.append("spans lowered: "
-                     + ", ".join(analysis["spans_lowered"]))
-    return "\n".join(lines)
 
 
 def drift_summary(paths: Sequence[str], top: int = 10) -> Dict[str, Any]:
@@ -535,34 +511,10 @@ def drift_main(argv: Sequence[str]) -> int:
     return 0
 
 
-def trace_main(argv: Sequence[str]) -> int:
-    ap = argparse.ArgumentParser(
-        prog="obs trace",
-        description="per-phase DEVICE-time table from a tpu_trace_dir "
-                    "profiler artifact (jax-free xplane parse)")
-    ap.add_argument("trace_dir", help="the tpu_trace_dir a run wrote")
-    ap.add_argument("--json", action="store_true", dest="as_json",
-                    help="emit the analysis as JSON instead of a table")
-    args = ap.parse_args(argv)
-    from .tracing import analyze_trace_dir
-    analysis = analyze_trace_dir(args.trace_dir)
-    if analysis is None:
-        print(f"obs trace: no xplane artifact under {args.trace_dir}",
-              file=sys.stderr)
-        return 2
-    if args.as_json:
-        print(json.dumps(analysis, indent=1, default=str))
-    else:
-        print(_fmt_trace(analysis))
-    return 0
-
-
 def main(argv: Optional[Sequence[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     # subcommands ride in front of the legacy positional form
     # (`scripts/obs <files>` keeps summarizing, unchanged)
-    if argv and argv[0] == "trace":
-        return trace_main(argv[1:])
     if argv and argv[0] == "merge":
         return merge_main(argv[1:])
     if argv and argv[0] == "drift":

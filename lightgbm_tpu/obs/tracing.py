@@ -1,278 +1,30 @@
-"""Device-time trace analytics: the profiler artifact, parsed honestly.
+"""The span taxonomy: the phase names the program's spans may carry.
 
-Every number the telemetry plane reported for the device side before
-this module was a *host* wall clock wrapped around async dispatch —
-exactly what tpulint R009 exists to distrust. The profiler is the one
-component that measures real device time, and ``trace_session``
-(obs/spans.py) already makes it write its artifact under
-``tpu_trace_dir``:
-
-    <tpu_trace_dir>/plugins/profile/<run>/<host>.xplane.pb
-
-This module parses that artifact OFFLINE (after the session closed,
-never on the hot path — tpulint R009c pins any import of it from
-jit-reachable code) and maps the timed events back to the PR 10 span
-taxonomy through the ``named_scope`` phase names the lowered programs
-carry, producing:
-
-* a per-phase **device**-time table (``hist_build``,
-  ``collective_reduce``, ``split_scan``, ...) — emitted side by side
-  with the host phase table (``device_seconds`` vs ``host_seconds``) in
-  the metrics-stream summary, so host-dispatch skew is visible instead
-  of silently reported as compute;
-* per-collective op durations (the measured counterpart of the byte
-  model in ``analysis/contracts/*.json`` — obs/ledger.py divides them);
-* an MXU / comm / idle decomposition of the device timeline.
-
-Artifact mechanics, all jax-free (scripts/obs runs this without a
-backend):
-
-* ``xplane.pb`` is a ``tensorflow.profiler.XSpace`` protobuf. A ~60-line
-  generic wire-format reader walks it with the field numbers below — no
-  protobuf dependency. Planes hold lines (one per device stream / host
-  thread), lines hold events (``offset_ps``/``duration_ps``), and event
-  metadata carries names.
-* The full ``jit(step)/.../hist_build/...`` scope path lives in the HLO
-  proto each module's metadata entry embeds (``OpMetadata.op_name`` per
-  instruction), NOT in the timed event names — those are bare HLO
-  instruction names (``fusion.3``, ``all-reduce.1``). The parser builds
-  the instruction -> scoped-op-name map from the embedded HLO protos and
-  resolves every timed event through it.
-* On TPU/GPU the timed events live on ``/device:...`` planes. On CPU
-  there is no device plane; XLA's compute-pool threads still record the
-  per-instruction executions on the host plane, so the analyzer falls
-  back to host-plane events that resolve through the HLO instruction map
-  (``source: "host-xla"`` marks the fallback — dispatch skew included,
-  but per-phase attribution is real).
+Jax-free, so ``scripts/obs`` and offline readers can name phases without
+a backend; ``obs/spans.py`` (the producer of the names) re-exports it.
+The device-time table that used to be reduced here from a profiler
+artifact is the benchmark's now (``benchmarks/trace.py`` on
+``jax.profiler.ProfileData``); the program keeps only the names.
 """
 from __future__ import annotations
 
-import glob
-import os
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Optional, Tuple
 
-#: the complete phase-name taxonomy. Canonical HERE (jax-free) so both
-#: the trace analytics and scripts/obs can name phases without a
-#: backend; obs/spans.py re-exports it (tests and engine key on
-#: ``spans.SPAN_TAXONOMY``).
+#: every name a device program or a host tick site carries. Device
+#: phases (``named_scope`` at trace time) and serving ticks first, then
+#: the host spans of set-up and of the update loop (obs/spans.py says
+#: which of them record always). None may be ``update``: the benchmark
+#: takes host events of that name as its window.
 SPAN_TAXONOMY = (
     "binning", "gradient", "hist_build", "collective_reduce", "split_scan",
     "partition", "checkpoint_write", "predict_warmup", "serve_tick",
     "autotune", "featurize", "contrib",
+    "import", "construct", "find_bins", "to_device", "booster_init",
+    "compact_setup", "build_step",
+    "iteration", "bag", "step_dispatch", "valid_scores", "flush_trees",
 )
 
-#: HLO opcode/name fragments that mean "communication"
-_COLLECTIVE_TOKENS = (
-    "all-reduce", "reduce-scatter", "all-gather", "all-to-all",
-    "collective-permute", "collective-broadcast", "send", "recv",
-)
-#: opcodes whose time is MXU (systolic-array) work
-_MXU_OPCODES = {"dot", "convolution"}
-_MXU_TOKENS = ("dot", "conv", "matmul")
 
-_PS = 1e-12   # picoseconds -> seconds
-
-
-# -- protobuf wire-format reader ---------------------------------------------
-def _varint(buf: bytes, i: int) -> Tuple[int, int]:
-    r = 0
-    s = 0
-    while True:
-        b = buf[i]
-        i += 1
-        r |= (b & 0x7F) << s
-        if not b & 0x80:
-            return r, i
-        s += 7
-
-
-def _iter_fields(buf: bytes) -> Iterator[Tuple[int, int, Any]]:
-    """Yield ``(field_number, wire_type, value)`` over one message.
-
-    Wire types: 0 varint (int), 2 length-delimited (bytes), 5/1 fixed
-    32/64 (raw bytes). Anything else is a parse error — the caller
-    treats the blob as not-a-message.
-    """
-    i, n = 0, len(buf)
-    while i < n:
-        key, i = _varint(buf, i)
-        fn, wt = key >> 3, key & 7
-        if wt == 0:
-            v, i = _varint(buf, i)
-        elif wt == 2:
-            ln, i = _varint(buf, i)
-            if i + ln > n:
-                raise ValueError("truncated length-delimited field")
-            v = buf[i:i + ln]
-            i += ln
-        elif wt == 5:
-            v = buf[i:i + 4]
-            i += 4
-        elif wt == 1:
-            v = buf[i:i + 8]
-            i += 8
-        else:
-            raise ValueError(f"unsupported wire type {wt}")
-        yield fn, wt, v
-
-
-def _utf8(b: bytes) -> str:
-    return b.decode("utf-8", errors="replace")
-
-
-# -- HLO proto: instruction name -> (scoped op_name, opcode) -----------------
-def _parse_op_metadata(buf: bytes) -> Tuple[str, str]:
-    """OpMetadata: op_type=1, op_name=2 (the full scope path)."""
-    op_type = op_name = ""
-    for fn, wt, v in _iter_fields(buf):
-        if wt != 2:
-            continue
-        if fn == 1:
-            op_type = _utf8(v)
-        elif fn == 2:
-            op_name = _utf8(v)
-    return op_type, op_name
-
-
-def _parse_hlo_instructions(buf: bytes, out: Dict[str, Tuple[str, str]]
-                            ) -> int:
-    """Walk an ``xla.HloProto`` blob: hlo_module=1 -> computations=3 ->
-    instructions=2 -> {name=1, opcode=2, metadata=7}. Adds
-    ``instr_name -> (scoped_op_name, opcode)`` entries; returns how many
-    instructions were seen (0 = the blob was not an HLO proto)."""
-    seen = 0
-    try:
-        for fn, wt, v in _iter_fields(buf):
-            if fn != 1 or wt != 2:       # hlo_module
-                continue
-            for f2, w2, v2 in _iter_fields(v):
-                if f2 != 3 or w2 != 2:   # computations
-                    continue
-                for f3, w3, v3 in _iter_fields(v2):
-                    if f3 != 2 or w3 != 2:   # instructions
-                        continue
-                    name = opcode = ""
-                    op_name = ""
-                    for f4, w4, v4 in _iter_fields(v3):
-                        if w4 != 2:
-                            continue
-                        if f4 == 1:
-                            name = _utf8(v4)
-                        elif f4 == 2:
-                            opcode = _utf8(v4)
-                        elif f4 == 7:
-                            _, op_name = _parse_op_metadata(v4)
-                    if name:
-                        seen += 1
-                        # scope path falls back to the bare name
-                        out[name] = (op_name or name, opcode)
-    except (ValueError, IndexError):
-        return 0
-    return seen
-
-
-# -- XSpace parsing ----------------------------------------------------------
-class XLine:
-    __slots__ = ("name", "timestamp_ns", "events")
-
-    def __init__(self) -> None:
-        self.name = ""
-        self.timestamp_ns = 0
-        # (metadata_id, offset_ps, duration_ps)
-        self.events: List[Tuple[int, int, int]] = []
-
-
-class XPlane:
-    __slots__ = ("name", "lines", "event_names", "hlo_map")
-
-    def __init__(self) -> None:
-        self.name = ""
-        self.lines: List[XLine] = []
-        self.event_names: Dict[int, str] = {}
-        # instruction name -> (scoped op_name, opcode), from embedded
-        # HLO protos in this plane's event metadata
-        self.hlo_map: Dict[str, Tuple[str, str]] = {}
-
-
-def _parse_event(buf: bytes) -> Tuple[int, int, int]:
-    """XEvent: metadata_id=1, offset_ps=2, duration_ps=3."""
-    mid = off = dur = 0
-    for fn, wt, v in _iter_fields(buf):
-        if wt != 0:
-            continue
-        if fn == 1:
-            mid = v
-        elif fn == 2:
-            off = v
-        elif fn == 3:
-            dur = v
-    return mid, off, dur
-
-
-def _parse_line(buf: bytes) -> XLine:
-    """XLine: name=2, timestamp_ns=3, events=4, display_name=11."""
-    line = XLine()
-    display = ""
-    for fn, wt, v in _iter_fields(buf):
-        if fn == 2 and wt == 2:
-            line.name = _utf8(v)
-        elif fn == 11 and wt == 2:
-            display = _utf8(v)
-        elif fn == 3 and wt == 0:
-            line.timestamp_ns = v
-        elif fn == 4 and wt == 2:
-            line.events.append(_parse_event(v))
-    line.name = line.name or display
-    return line
-
-
-def _parse_event_metadata(buf: bytes, plane: XPlane) -> None:
-    """map<int64, XEventMetadata> entry: key=1, value=2. XEventMetadata:
-    id=1, name=2, stats=5; any bytes stat that parses as an HLO proto
-    feeds the plane's instruction map."""
-    key = None
-    meta = None
-    for fn, wt, v in _iter_fields(buf):
-        if fn == 1 and wt == 0:
-            key = v
-        elif fn == 2 and wt == 2:
-            meta = v
-    if meta is None:
-        return
-    name = ""
-    for fn, wt, v in _iter_fields(meta):
-        if fn == 1 and wt == 0 and key is None:
-            key = v
-        elif fn == 2 and wt == 2:
-            name = _utf8(v)
-        elif fn == 5 and wt == 2:
-            # XStat: value oneof; bytes_value=6 may embed an HloProto
-            for f2, w2, v2 in _iter_fields(v):
-                if f2 == 6 and w2 == 2 and len(v2) > 16:
-                    _parse_hlo_instructions(v2, plane.hlo_map)
-    if key is not None and name:
-        plane.event_names[key] = name
-
-
-def parse_xspace(data: bytes) -> List[XPlane]:
-    """Parse serialized XSpace bytes into planes (lines + name tables)."""
-    planes: List[XPlane] = []
-    for fn, wt, v in _iter_fields(data):
-        if fn != 1 or wt != 2:           # XSpace.planes
-            continue
-        plane = XPlane()
-        for f2, w2, v2 in _iter_fields(v):
-            if f2 == 2 and w2 == 2:
-                plane.name = _utf8(v2)
-            elif f2 == 3 and w2 == 2:
-                plane.lines.append(_parse_line(v2))
-            elif f2 == 4 and w2 == 2:
-                _parse_event_metadata(v2, plane)
-        planes.append(plane)
-    return planes
-
-
-# -- analytics ---------------------------------------------------------------
 def phase_of(scoped_name: str) -> Optional[str]:
     """First taxonomy token appearing in a scoped op name, scanned in
     path order so the OUTERMOST phase scope wins (``.../hist_build/
@@ -284,166 +36,3 @@ def phase_of(scoped_name: str) -> Optional[str]:
         if i >= 0 and i < best[0]:
             best = (i, token)
     return best[1]
-
-
-def _is_collective(name: str, opcode: str) -> bool:
-    base = (opcode or name).lower()
-    return any(t in base for t in _COLLECTIVE_TOKENS)
-
-
-def _is_mxu(name: str, opcode: str) -> bool:
-    if opcode in _MXU_OPCODES:
-        return True
-    base = name.lower()
-    return any(t in base for t in _MXU_TOKENS)
-
-
-def analyze_planes(planes: List[XPlane]) -> Dict[str, Any]:
-    """Aggregate parsed planes into the device-time analysis dict.
-
-    Device planes (``/device:...``) are authoritative when present;
-    otherwise host-plane events that resolve through the HLO instruction
-    map stand in (CPU backend — source ``host-xla``).
-    """
-    # one shared instruction map: the metadata plane holds the HLO protos
-    # even when the timed events live on another plane
-    hlo_map: Dict[str, Tuple[str, str]] = {}
-    for plane in planes:
-        hlo_map.update(plane.hlo_map)
-
-    device_planes = [p for p in planes if p.name.startswith("/device:")]
-    source = "device" if device_planes else "host-xla"
-    use = device_planes or planes
-
-    phases: Dict[str, Dict[str, float]] = {}
-    collectives: Dict[str, Dict[str, float]] = {}
-    busy = mxu = comm = 0.0
-    unattributed = 0.0
-    lanes = 0
-    span_min: Optional[float] = None
-    span_max: Optional[float] = None
-
-    def _instr_base(event_name: str) -> str:
-        # profiler event names may suffix the instruction (".clone") or
-        # wrap it; resolve exact first, then the dotted stem
-        if event_name in hlo_map:
-            return event_name
-        stem = event_name.split("/")[-1]
-        if stem in hlo_map:
-            return stem
-        if stem.endswith(".clone") and stem[:-6] in hlo_map:
-            return stem[:-6]
-        return ""
-
-    for plane in use:
-        # device planes carry DERIVED lines next to the op stream ("XLA
-        # Modules" module-level rollups, "Steps", "Framework Name
-        # Scope") whose events re-describe the same time — summing every
-        # line would double-count. When an "XLA Ops" line exists, it is
-        # the one authoritative op timeline per stream.
-        lines = plane.lines
-        if source == "device":
-            op_lines = [ln for ln in lines if "XLA Ops" in (ln.name or "")]
-            lines = op_lines or lines
-        for line in lines:
-            lane_used = False
-            for mid, off, dur in line.events:
-                name = plane.event_names.get(mid, "")
-                if not name:
-                    continue
-                instr = _instr_base(name)
-                if source == "host-xla" and not instr:
-                    # host fallback: only REAL XLA op executions count —
-                    # python frames and pool bookkeeping are not device
-                    # time
-                    continue
-                scoped, opcode = hlo_map.get(instr, ("", ""))
-                scoped = scoped or name
-                secs = dur * _PS
-                t0 = line.timestamp_ns * 1e-9 + off * _PS
-                span_min = t0 if span_min is None else min(span_min, t0)
-                span_max = (t0 + secs if span_max is None
-                            else max(span_max, t0 + secs))
-                lane_used = True
-                busy += secs
-                phase = phase_of(scoped)
-                if phase is None:
-                    unattributed += secs
-                else:
-                    d = phases.setdefault(
-                        phase, {"device_seconds": 0.0, "events": 0})
-                    d["device_seconds"] += secs
-                    d["events"] += 1
-                if _is_collective(scoped if not instr else instr, opcode):
-                    key = (instr or name).split(".")[0] or name
-                    c = collectives.setdefault(
-                        key, {"seconds": 0.0, "count": 0})
-                    c["seconds"] += secs
-                    c["count"] += 1
-                    comm += secs
-                elif _is_mxu(scoped if not instr else instr, opcode):
-                    mxu += secs
-            if lane_used:
-                lanes += 1
-
-    total = (span_max - span_min) if span_min is not None else 0.0
-    # spans that LOWERED: taxonomy tokens present anywhere in the scoped
-    # op names of the compiled modules (whether or not their ops were
-    # sampled into timed events)
-    lowered = sorted({p for scoped, _ in hlo_map.values()
-                     for p in (phase_of(scoped),) if p})
-    for d in phases.values():
-        d["device_seconds"] = round(d["device_seconds"], 9)
-    return {
-        "source": source,
-        "lanes": lanes,
-        "phases": phases,
-        "unattributed_seconds": round(unattributed, 9),
-        "collectives": {k: {"seconds": round(v["seconds"], 9),
-                            "count": int(v["count"])}
-                        for k, v in collectives.items()},
-        "decomposition": {
-            "total_seconds": round(total, 9),
-            "busy_seconds": round(busy, 9),
-            "mxu_seconds": round(mxu, 9),
-            "comm_seconds": round(comm, 9),
-            "idle_seconds": round(max(0.0, total - busy), 9),
-        },
-        "spans_lowered": lowered,
-    }
-
-
-def find_xplane_files(trace_dir: str) -> List[str]:
-    """``*.xplane.pb`` files of the NEWEST run under ``trace_dir``
-    (``plugins/profile/<run>/``; a bare directory of .pb files also
-    works)."""
-    runs = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*")))
-    candidates = ([runs[-1]] if runs else []) + [trace_dir]
-    for d in candidates:
-        files = sorted(glob.glob(os.path.join(d, "*.xplane.pb")))
-        if files:
-            return files
-    return []
-
-
-def analyze_trace_dir(trace_dir: str) -> Optional[Dict[str, Any]]:
-    """Parse + analyze the newest trace run under ``trace_dir``; None
-    when no artifact exists. Never raises on a torn artifact — the
-    analytics run on post-mortem paths too."""
-    files = find_xplane_files(trace_dir)
-    if not files:
-        return None
-    planes: List[XPlane] = []
-    for path in files:
-        try:
-            with open(path, "rb") as fh:
-                planes.extend(parse_xspace(fh.read()))
-        except (OSError, ValueError, IndexError):
-            continue
-    if not planes:
-        return None
-    out = analyze_planes(planes)
-    out["trace_dir"] = trace_dir
-    out["files"] = [os.path.basename(f) for f in files]
-    return out

@@ -435,7 +435,8 @@ def grow_tree_compact(
                 jnp.zeros((W,), jnp.uint32), layout, B, params.fused_block,
                 W, interpret=params.fused_interpret, dual=params.fused_dual,
                 hist_debug=params.fused_hist_debug, num_rows=n, quant=quant,
-                mbatch=params.hist_mbatch, hist_layout=params.hist_layout)
+                mbatch=params.hist_mbatch, hist_layout=params.hist_layout,
+                name="fused_split_root")
         root_hist = reduce_any(root_loc)
     else:
         # data-parallel: histograms reduce over the mesh axis (reference:
@@ -725,7 +726,7 @@ def grow_tree_compact(
                     dual=params.fused_dual,
                     hist_debug=params.fused_hist_debug,
                     num_rows=n, quant=quant, mbatch=params.hist_mbatch,
-                    hist_layout=params.hist_layout)
+                    hist_layout=params.hist_layout, name="fused_split_step")
         else:
             with span("partition"):
                 work, scratch = partition_segment(

@@ -1505,68 +1505,6 @@ def test_r009_with_span_under_jit_clean(tmp_path):
     assert "R009" not in codes(findings)
 
 
-def test_r009c_trace_import_in_jit_reachable_module_flagged(tmp_path):
-    """Sub-check (c): obs.tracing (the xplane parse) imported into a
-    module that contains jit-reachable code is a finding — artifact
-    analytics must stay off the hot path (post-run only)."""
-    findings = lint_snippet(tmp_path, """
-        import jax
-        from lightgbm_tpu.obs import tracing
-
-        @jax.jit
-        def step(x):
-            return x + 1
-    """)
-    r9 = [f for f in findings if f.rule == "R009"]
-    assert r9 and "post-run" in r9[0].message
-
-
-def test_r009c_function_level_trace_import_flagged(tmp_path):
-    """The lazy-import spelling does not launder it: a function-level
-    import inside a module with jit-reachable code is flagged too."""
-    findings = lint_snippet(tmp_path, """
-        import jax
-
-        @jax.jit
-        def step(x):
-            return x * 2
-
-        def emit_summary(path):
-            import lightgbm_tpu.obs.tracing as tracing
-            return tracing.analyze_trace_dir(path)
-    """)
-    assert any(f.rule == "R009" and "tracing" in f.message
-               for f in findings)
-
-
-def test_r009c_trace_import_without_jit_code_clean(tmp_path):
-    """Post-run consumers (engine's post-session emit, scripts/obs,
-    bench's ledger step) have no jit-reachable code — importing the
-    analytics there is the DESIGN, not a finding."""
-    findings = lint_snippet(tmp_path, """
-        from lightgbm_tpu.obs import tracing
-
-        def summarize_run(trace_dir):
-            return tracing.analyze_trace_dir(trace_dir)
-    """)
-    assert "R009" not in codes(findings)
-
-
-def test_r009c_taxonomy_constant_import_clean(tmp_path):
-    """The ALL-CAPS taxonomy tuple is shared vocabulary, not parse
-    machinery — importing it next to jitted code is fine (obs/spans.py
-    does exactly this)."""
-    findings = lint_snippet(tmp_path, """
-        import jax
-        from lightgbm_tpu.obs.tracing import SPAN_TAXONOMY
-
-        @jax.jit
-        def step(x):
-            return x + len(SPAN_TAXONOMY)
-    """)
-    assert "R009" not in codes(findings)
-
-
 # ---------------------------------------------------------------- R010
 def test_r010_rank_guarded_collective_flagged(tmp_path):
     """The canonical pod deadlock: rank 0 joins a rendezvous its peers

@@ -1,162 +1,23 @@
 """Distributed performance observability (ISSUE 11): fast-lane units.
 
-Covers the three legs without a profiler session (the first jax
-profiler session costs a one-time ~10s init — tier-1's budget lives in
-the slow lane for that; these tests synthesize the xplane artifact with
-a tiny protobuf wire encoder instead):
-
-* obs/tracing.py — xplane parse, HLO scope resolution, per-phase device
-  time, collective durations, MXU/comm/idle decomposition;
+* obs/tracing.py — the span taxonomy (the profiler-artifact reader that
+  used to live there is gone; benchmarks/trace.py reduces device time);
 * obs/ranks.py — sampled publish/aggregate over an injected KV,
   straggler flags, heartbeat-miss reporting;
 * obs/ledger.py — per-chip efficiency, measured-vs-model, atomic record;
-* scripts/obs — trace table, cross-rank merge ordered by (time, rank).
+* scripts/obs — cross-rank merge ordered by (time, rank).
 """
 import json
-import os
 
 import pytest
 
 from lightgbm_tpu.obs import flight, ledger, summarize, tracing
-
-# ---------------------------------------------------------------- encoder
-# minimal protobuf wire encoder: enough XSpace/HloProto to synthesize a
-# device trace (field numbers mirror obs/tracing.py's reader)
-
-
-def _v(n):
-    out = b""
-    while True:
-        b = n & 0x7F
-        n >>= 7
-        out += bytes([b | (0x80 if n else 0)])
-        if not n:
-            return out
-
-
-def _vi(fn, val):
-    return _v((fn << 3) | 0) + _v(val)
-
-
-def _ld(fn, payload):
-    return _v((fn << 3) | 2) + _v(len(payload)) + payload
-
-
-def _s(fn, text):
-    return _ld(fn, text.encode())
-
-
-def _hlo_proto(instrs):
-    """instrs: [(name, opcode, scoped_op_name)] -> serialized HloProto."""
-    comp = b""
-    for name, opcode, scoped in instrs:
-        meta = _s(2, scoped)                      # OpMetadata.op_name
-        comp += _ld(2, _s(1, name) + _s(2, opcode) + _ld(7, meta))
-    module = _s(1, "m") + _ld(3, comp)            # HloModuleProto
-    return _ld(1, module)                         # HloProto.hlo_module
-
-
-def _event_meta(mid, name, hlo=None):
-    body = _vi(1, mid) + _s(2, name)
-    if hlo is not None:
-        stat = _vi(1, 1) + _ld(6, hlo)            # XStat.bytes_value
-        body += _ld(5, stat)                      # XEventMetadata.stats
-    return _ld(4, _vi(1, mid) + _ld(2, body))     # map entry in XPlane
-
-
-def _line(name, ts_ns, events):
-    body = _s(2, name) + _vi(3, ts_ns)
-    for mid, off_ps, dur_ps in events:
-        body += _ld(4, _vi(1, mid) + _vi(2, off_ps) + _vi(3, dur_ps))
-    return _ld(3, body)                           # XPlane.lines
-
-
-def _plane(name, parts):
-    return _ld(1, _s(2, name) + b"".join(parts))  # XSpace.planes
-
-
-_US = 1_000_000  # 1 microsecond in picoseconds
-
-
-def _device_space():
-    """One device plane: four scoped ops + one unscoped, one collective."""
-    instrs = [
-        ("fusion.1", "fusion", "jit(step)/jit(main)/hist_build/add"),
-        ("dot.2", "dot", "jit(step)/jit(main)/hist_build/dot_general"),
-        ("all-reduce.3", "all-reduce",
-         "jit(step)/jit(main)/collective_reduce/psum"),
-        ("reduce.4", "reduce", "jit(step)/jit(main)/split_scan/reduce"),
-        ("copy.5", "copy", "copy.5"),             # no scope: unattributed
-    ]
-    parts = [_event_meta(i + 1, n, _hlo_proto(instrs) if i == 0 else None)
-             for i, (n, _, _) in enumerate(instrs)]
-    # timeline (ts base 1000ns): events at 0..50us, durations in us
-    parts.append(_line("XLA Ops", 1000, [
-        (1, 0 * _US, 10 * _US),       # hist_build fusion: 10us
-        (2, 10 * _US, 5 * _US),       # hist_build dot:     5us (MXU)
-        (3, 15 * _US, 20 * _US),      # collective_reduce: 20us (comm)
-        (4, 35 * _US, 8 * _US),       # split_scan:         8us
-        (5, 43 * _US, 2 * _US),       # unattributed:       2us
-    ]))
-    return _plane("/device:TPU:0", parts)
-
-
-def test_xplane_parse_and_phase_table(tmp_path):
-    run = tmp_path / "plugins" / "profile" / "2026_08_04"
-    run.mkdir(parents=True)
-    (run / "host.xplane.pb").write_bytes(_device_space())
-    out = tracing.analyze_trace_dir(str(tmp_path))
-    assert out is not None and out["source"] == "device"
-    ph = out["phases"]
-    assert ph["hist_build"]["device_seconds"] == pytest.approx(15e-6)
-    assert ph["hist_build"]["events"] == 2
-    assert ph["collective_reduce"]["device_seconds"] == pytest.approx(
-        20e-6)
-    assert ph["split_scan"]["device_seconds"] == pytest.approx(8e-6)
-    assert out["unattributed_seconds"] == pytest.approx(2e-6)
-    # collective durations by op stem
-    assert out["collectives"]["all-reduce"]["count"] == 1
-    assert out["collectives"]["all-reduce"]["seconds"] == pytest.approx(
-        20e-6)
-    # decomposition: total spans first start to last end = 45us
-    d = out["decomposition"]
-    assert d["total_seconds"] == pytest.approx(45e-6)
-    assert d["busy_seconds"] == pytest.approx(45e-6)
-    assert d["mxu_seconds"] == pytest.approx(5e-6)
-    assert d["comm_seconds"] == pytest.approx(20e-6)
-    assert d["idle_seconds"] == pytest.approx(0.0)
-    assert out["spans_lowered"] == ["collective_reduce", "hist_build",
-                                    "split_scan"]
-
-
-def test_host_fallback_counts_only_resolved_ops():
-    """No device plane: host events count ONLY when they resolve through
-    the HLO instruction map — python frames are not device time."""
-    instrs = [("fusion.9", "fusion",
-               "jit(f)/jit(main)/partition/scatter")]
-    parts = [
-        _event_meta(1, "fusion.9", _hlo_proto(instrs)),
-        _event_meta(2, "$builtins isinstance"),
-        _line("tf_XLAEigen/1", 0, [(1, 0, 7 * _US), (2, 0, 500 * _US)]),
-    ]
-    out = tracing.analyze_planes(tracing.parse_xspace(
-        _plane("/host:CPU", parts)))
-    assert out["source"] == "host-xla"
-    assert out["phases"] == {"partition": {"device_seconds": 7e-6,
-                                           "events": 1}}
-    assert out["decomposition"]["busy_seconds"] == pytest.approx(7e-6)
 
 
 def test_phase_of_outermost_scope_wins():
     assert tracing.phase_of(
         "jit(s)/split_scan/jit(x)/partition/op") == "split_scan"
     assert tracing.phase_of("no taxonomy here") is None
-
-
-def test_analyze_trace_dir_tolerates_torn_artifacts(tmp_path):
-    assert tracing.analyze_trace_dir(str(tmp_path)) is None
-    (tmp_path / "torn.xplane.pb").write_bytes(b"\x0a\xff\xff")  # truncated
-    assert tracing.analyze_trace_dir(str(tmp_path)) is None
 
 
 # ----------------------------------------------------------------- ledger
@@ -325,46 +186,6 @@ def test_obs_merge_orders_by_time_then_rank(tmp_path, capsys):
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == len(merged)
     assert all(isinstance(json.loads(line), dict) for line in out)
-
-
-def test_obs_trace_cli_renders_table(tmp_path, capsys):
-    run = tmp_path / "plugins" / "profile" / "r1"
-    run.mkdir(parents=True)
-    (run / "host.xplane.pb").write_bytes(_device_space())
-    assert summarize.trace_main([str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    assert "hist_build" in out and "collective_reduce" in out
-    assert "all-reduce" in out
-    assert "spans lowered:" in out
-    assert summarize.trace_main([str(tmp_path / "nope")]) == 2
-
-
-def test_summary_table_shows_device_next_to_host(tmp_path, capsys):
-    """The side-by-side contract: a stream with a summary (host
-    seconds) AND a device_time record renders one table with both
-    columns."""
-    from lightgbm_tpu.obs import metrics
-    p = tmp_path / "s.jsonl"
-    s = metrics.MetricsStream(str(p))
-    s.emit("summary", phase_times={"hist_build": {"seconds": 1.0,
-                                                  "count": 5}})
-    s.emit("device_time", source="device",
-           phases={"hist_build": {"device_seconds": 0.25, "events": 9},
-                   "split_scan": {"device_seconds": 0.1, "events": 3}},
-           decomposition={"total_seconds": 0.5, "busy_seconds": 0.4,
-                          "mxu_seconds": 0.2, "comm_seconds": 0.05,
-                          "idle_seconds": 0.1},
-           collectives={"all-reduce": {"seconds": 0.05, "count": 4}})
-    s.close()
-    summary = summarize.summarize([str(p)])
-    assert summary["device_time"]["phases"]["hist_build"][
-        "device_seconds"] == 0.25
-    assert summarize.main([str(p)]) == 0
-    out = capsys.readouterr().out
-    assert "host_s" in out and "device_s" in out
-    assert "0.2500" in out            # device seconds rendered
-    assert "device timeline" in out
-    assert "collective all-reduce" in out
 
 
 def test_flight_dump_carries_rank_field(tmp_path):
