@@ -696,13 +696,18 @@ class Booster:
                 resolved.hist_layout, num_bins=clamp_ctx["num_bins"],
                 num_features=clamp_ctx["num_features"],
                 env_override=os.environ.get("LGBM_TPU_FUSED_BS", ""))
+        resolved_mbatch = resolved.hist_mbatch
+        if resolved.fused_block and not resolved_fb:
+            # the clamp took the fused kernel off: the standalone depth
+            resolved_mbatch = engine_registry.standalone_mbatch(
+                self.config, resolved)
         gbdt.grower_params = gbdt.grower_params._replace(
             num_leaves=key_leaves,
             max_depth=key_depth,
             step_buckets=gbdt._step_buckets,
             hist_overlap=resolved.hist_overlap,
             hist_impl=resolved.hist_impl,
-            hist_mbatch=resolved.hist_mbatch,
+            hist_mbatch=resolved_mbatch,
             hist_layout=resolved.hist_layout,
             fused_block=resolved_fb,
             lambda_l1=float(self.config.lambda_l1),

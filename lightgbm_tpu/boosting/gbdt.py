@@ -226,8 +226,10 @@ def _pick_fused_block(cfg) -> int:
 
 
 def _pick_hist_mbatch(cfg) -> int:
-    """Thin delegate: ``tpu_hist_mbatch`` (user > LGBM_TPU_HIST_MBATCH
-    env > autotune > default 8) resolves in the engine registry."""
+    """Thin delegate: the standalone engines' ``tpu_hist_mbatch`` (user >
+    LGBM_TPU_HIST_MBATCH env > autotune > default 8) resolves in the
+    engine registry; the fused kernel's own depth does too
+    (registry.resolve_mbatch, ``fused=True``)."""
     from ..engines import registry as engine_registry
     return engine_registry.resolve_mbatch(cfg)
 
@@ -941,7 +943,8 @@ class GBDT:
             pack4=bool(cfg.get("tpu_bin_pack4", False)),
             # the masked grower under a mesh is partitioned by GSPMD,
             # which cannot partition a Mosaic call (registry.DatasetShape)
-            gspmd=self.mesh is not None and not self._use_compact)
+            gspmd=self.mesh is not None and not self._use_compact,
+            compact=self._use_compact)
 
         def _autotune_sample(n, _b=binned_host):
             if len(_b) <= n:
@@ -1471,6 +1474,12 @@ class GBDT:
                 env_override=os.environ.get("LGBM_TPU_FUSED_BS", ""))
             if resolved_bs != gp.fused_block:
                 gp = gp._replace(fused_block=resolved_bs)
+                if not resolved_bs:
+                    # the XLA walk's segment_histogram is a standalone
+                    # engine: it keeps their depth, not the fused kernel's
+                    gp = gp._replace(
+                        hist_mbatch=engine_registry.standalone_mbatch(
+                            self.config, self._engine_resolution))
                 self.grower_params = gp
         # the fused kernel's aligned block writes may overrun a segment end
         # by up to one block + one alignment tile
@@ -2294,6 +2303,14 @@ class GBDT:
                         "compact training started; set tpu_grower=masked")
                 # caller-supplied gradients arrive in the original row order
                 self._use_compact = False
+                # the masked grower does not fuse: the standalone depth
+                from ..engines import registry as engine_registry
+                self.grower_params = self.grower_params._replace(
+                    hist_mbatch=engine_registry.standalone_mbatch(
+                        self.config, self._engine_resolution))
+                if self._engine_shape is not None:
+                    self._engine_shape = self._engine_shape._replace(
+                        compact=False)
             else:
                 if self._compact is None:
                     with span("compact_setup"):

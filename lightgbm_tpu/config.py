@@ -207,9 +207,11 @@ PARAMS: Dict[str, Tuple[Any, type, Tuple[str, ...]]] = {
     "tpu_part_block": (2048, int, ()),      # compact partition stream block
     "tpu_hist_block": (16384, int, ()),     # compact histogram stream block
     # batched-M histogram depth: K row blocks per one-hot contraction fill
-    # M = 8K of the 128 MXU rows (ops/fused_split.py hist_flush; 1 = the
-    # sync reference path). The pending ring multiplies histogram-side
-    # VMEM residency by K, so tpu_fused_block is re-clamped against it
+    # M = 8K of the 128 MXU rows. 8 is the STANDALONE engines' default;
+    # unset, a fused entry runs 2 (engines/registry.py FUSED_MBATCH: the
+    # fused kernel's pending ring is ten times slower at 8 on the chip).
+    # Set, it reaches both. The ring multiplies histogram-side VMEM
+    # residency by K, so tpu_fused_block is re-clamped against it
     "tpu_hist_mbatch": (8, int, ("hist_mbatch",)),
     # Mosaic one-hot register layout for the histogram engines: "lane"
     # keeps bins along lanes (channel-major output, the batched-M

@@ -47,10 +47,29 @@ from ..utils import log
 #: TPU" question in the package goes through :func:`on_tpu`
 TPU_PLATFORMS = ("tpu",)
 
-#: batched-M depths the autotuner sweeps (ops/fused_split.py hist_flush:
+#: batched-M depths the autotuner sweeps for the STANDALONE engines (the
+#: Mosaic kernel's window partition and the XLA einsum's chunk widening:
 #: M = 8K MXU rows, K <= 16). The default (8) leads so a tie resolves to
 #: today's behavior, not to an arbitrary cell.
 MBATCH_CANDIDATES = (8, 16, 1)
+
+#: the fused kernel's own depth (ops/fused_split.py hist_flush), which it
+#: no longer inherits from the standalone engines. On the chip (higgs,
+#: 10.5M x 28, block 384; PERF.md section 6, PR 29) K = 1 / 2 / 4 train
+#: at 0.932 / 0.939 / 0.949 s an iteration — the ring buys no speed — and
+#: K = 6 / 7 / 8 / 16 at 6.6 / 8.3 / 9.7 / 25.3 s: once a flush spans
+#: K x block >= 2048 rows every streamed row costs 60-96 ns more. The
+#: standalone kernel's race goes the other way (k8 0.340 ms against k1
+#: 0.445 ms). 2 and not 1, for 0.7% of an iteration: each flush adds
+#: two blocks' partial sums to the f32 accumulator at once, half as many
+#: roundings, and at 63 bins (164k rows a bin at the root) that halves
+#: the worst leaf's hessian error (3.4e-4 at K = 1 against the
+#: benchmark's 4e-4 limit; 1.8e-4 at K = 2, 0.9e-4 at K = 8). One value
+#: for every shape the chip has measured (255 and 63 bins, int8
+#: channels, F = 137); a shape that wants another gets it in
+#: :func:`resolve_mbatch` from what the shape shows (``quant``, layout,
+#: width), not from a new knob.
+FUSED_MBATCH = 2
 
 
 class DatasetShape(NamedTuple):
@@ -70,6 +89,10 @@ class DatasetShape(NamedTuple):
     #: so these shapes take the XLA einsum, which GSPMD splits over rows.
     #: The compact grower's step runs under shard_map and keeps the kernels
     gspmd: bool = False
+    #: the compact grower takes this run (False: the masked grower on one
+    #: chip — small data, a stochastic or caller-supplied objective).
+    #: Only the compact grower fuses; not part of the shape class
+    compact: bool = True
 
 
 class EngineEntry(NamedTuple):
@@ -82,8 +105,11 @@ class EngineEntry(NamedTuple):
     Mosaic kernels). ``sweepable`` entries are timed standalone by the
     autotuner; the fused kernel is selected structurally (it replaces
     the partition+histogram streams and its binding constraint is the
-    scoped-VMEM validator, :func:`clamp_fused_block`) but INHERITS the
-    winning layout/mbatch — those knobs thread into its ``hist_flush``.
+    scoped-VMEM validator, :func:`clamp_fused_block`). It inherits the
+    winning LAYOUT, which threads into its ``hist_flush``, and not the
+    winning depth: what wins a standalone 16k-row race (8) loses a factor
+    of ten inside the fused walk, so a fused entry runs
+    :data:`FUSED_MBATCH` unless the user or the environment names a depth.
     """
     id: str
     impl: str                     # hist_impl fed to ops/histogram dispatch
@@ -193,7 +219,10 @@ class Resolution(NamedTuple):
 
     ``sources`` maps knob -> one of ``user`` / ``env`` / ``autotune`` /
     ``default`` so logs and tests can see WHICH rung of the resolve
-    order produced each value.
+    order produced each value (``hist_impl`` may also read ``gspmd`` and
+    ``hist_mbatch`` ``fused``: the structural answers). ``hist_mbatch``
+    is the depth the run's histograms are built at: the fused kernel's
+    under a fused entry, the standalone engines' otherwise.
     """
     entry_id: str
     fused_block: int
@@ -334,10 +363,20 @@ def validated_fused_block_env(value: str, num_cols: int,
 
 
 def resolve_mbatch(cfg, decision: Optional[Dict[str, Any]] = None,
-                   sources: Optional[Dict[str, str]] = None) -> int:
+                   sources: Optional[Dict[str, str]] = None,
+                   fused: bool = False) -> int:
     """``tpu_hist_mbatch``: K row blocks per one-hot contraction,
-    M = 8K MXU rows. user > env (LGBM_TPU_HIST_MBATCH) > autotune >
-    default 8; always clamped to [1, 16]."""
+    M = 8K MXU rows; always clamped to [1, 16].
+
+    The standalone engines (``fused=False``): user > env
+    (LGBM_TPU_HIST_MBATCH) > autotune > default 8 — the depth their
+    sweep measures, and where the chip has it ahead (k8 0.340 ms against
+    k1 0.445 ms on 16k rows). The fused kernel (``fused=True``): user >
+    env > :data:`FUSED_MBATCH` (source ``fused``). Neither the sweep's
+    winner nor the standalone default reaches it: inside the fused walk
+    no depth is faster than 1, 2 costs 0.7% and 8 a factor of ten
+    (PERF.md section 6, PR 29), and the sweep never times the fused
+    kernel."""
     src = "default"
     k = int(_get(cfg, "tpu_hist_mbatch", 8) or 8)
     if _explicit(cfg, "tpu_hist_mbatch"):
@@ -345,12 +384,24 @@ def resolve_mbatch(cfg, decision: Optional[Dict[str, Any]] = None,
     elif os.environ.get("LGBM_TPU_HIST_MBATCH", ""):
         k = validated_mbatch_env(os.environ["LGBM_TPU_HIST_MBATCH"])
         src = "env"
+    elif fused:
+        k, src = FUSED_MBATCH, "fused"
     elif decision and decision.get("hist_mbatch"):
         k = int(decision["hist_mbatch"])
         src = "autotune"
     if sources is not None:
         sources["hist_mbatch"] = src
     return max(1, min(k, 16))
+
+
+def standalone_mbatch(cfg, res: Optional[Resolution]) -> int:
+    """The depth of a run whose fused kernel was taken off AFTER
+    :func:`resolve` answered for it (:func:`clamp_fused_block` found no
+    block that fits, or caller-supplied gradients sent the run to the
+    masked grower): its histograms come from a standalone engine after
+    all, so it runs what :func:`resolve_mbatch` gives those, from the
+    run's in-memory decision."""
+    return resolve_mbatch(cfg, res.decision if res is not None else None)
 
 
 def resolve_layout(cfg, num_bins: int,
@@ -444,9 +495,10 @@ def resolve_fused_block(cfg, platform: Optional[str] = None,
     """``tpu_fused``: the fused per-split Mosaic kernel block size
     (0 = off). auto = on whenever a real TPU backend is present; the
     fused kernel is selected structurally, not by the microbench (see
-    EngineEntry.sweepable), but its hist_flush inherits the autotuned
-    layout/mbatch. The record-width scoped-VMEM clamp re-runs at
-    :func:`clamp_fused_block` once the row layout is known."""
+    EngineEntry.sweepable); its hist_flush inherits the autotuned
+    layout and runs its own depth (:func:`resolve_mbatch`). The
+    record-width scoped-VMEM clamp re-runs at :func:`clamp_fused_block`
+    once the row layout is known."""
     mode = str(_get(cfg, "tpu_fused", "auto") or "auto").lower()
     src = "user" if _explicit(cfg, "tpu_fused") else "default"
     if sources is not None:
@@ -584,7 +636,6 @@ def resolve(cfg, shape: Optional[DatasetShape] = None,
     # 0 = bin width unknown (no train-set context): the sublane bound
     # cannot be checked, so it is not enforced against a made-up width
     num_bins = int(shape.num_bins) if shape is not None else 0
-    mbatch = resolve_mbatch(cfg, decision, sources)
     layout = resolve_layout(cfg, num_bins, decision, platform, sources)
     gspmd = shape is not None and shape.gspmd
     impl = resolve_impl(cfg, decision, sources, gspmd=gspmd)
@@ -594,9 +645,15 @@ def resolve(cfg, shape: Optional[DatasetShape] = None,
                  "einsum — a Mosaic kernel cannot be partitioned "
                  "automatically")
     fused_block = resolve_fused_block(cfg, platform, sources)
+    # only the compact grower fuses: the masked grower (a GSPMD step, or
+    # one chip's small or caller-gradient runs) builds every histogram
+    # with a standalone engine, at the standalone engines' depth
+    fused = bool(fused_block) and not gspmd and (
+        shape is None or shape.compact)
+    mbatch = resolve_mbatch(cfg, decision, sources, fused=fused)
     step_buckets = resolve_step_buckets(cfg, sources)
     overlap = resolve_overlap(cfg, sources)
-    if fused_block and not gspmd:     # only the compact grower fuses
+    if fused:
         entry_id = "fused_sublane" if layout == "sublane" else "fused_lane"
     elif decision and decision.get("entry"):
         entry_id = str(decision["entry"])

@@ -54,20 +54,33 @@ undone exactly at flush). With no spare lane the kernel falls back to bf16
 (0..255 exact, f32 accumulate). Histogram channels use the same hi/lo-bf16
 split as ops/pallas_histogram.py: counts exact, grad/hess ~2^-17 relative.
 
-Batched-M histogram pipeline (round 6): the histogram contraction's output
-has only 8 rows (the channel count), so a per-block issue runs at M=8 of the
-MXU's 128 rows — the round-5 decomposition's dominant waste. The kernel now
-stages K = ``mbatch`` row blocks (bins + TRANSPOSED [8, bs] channel
-operands) in a pending ring and issues ONE contraction per feature group
-with a block-diagonal [8K, K*bs] channel LHS against the K blocks'
-row-concatenated one-hots — M = 8K = 64-128 MXU rows per issue, the TPU
-analogue of the reference CUDA constructor accumulating many row-blocks per
-launch (cuda_histogram_constructor.cu:17-68). The drain flushes the
-``pushes % K`` remainder exactly (stale slots zero out on the channel side).
-If Mosaic relayouts dominate at B <= 64 despite the batching, the next
-fallback is the bins-on-sublanes layout (VERDICT r5 attack (c)): transpose
-the ONE-HOT operand instead so bins provide the M rows — not implemented
-while the block-diagonal path holds.
+Batched-M histogram pipeline (round 6; measured on the chip in PR 29: no
+speed at any depth, a tenfold loss at the old default of 8):
+the histogram contraction's output has only 8 rows (the channel count), so
+a per-block issue runs at M=8 of the MXU's 128 rows. With ``mbatch`` = K > 1
+the kernel stages K row blocks (bins + TRANSPOSED [8, bs] channel operands)
+in a pending ring and issues ONE contraction per feature group with a
+block-diagonal [8K, K*bs] channel LHS against the K blocks' row-concatenated
+one-hots — M = 8K MXU rows per issue, the TPU analogue of the reference CUDA
+constructor accumulating many row-blocks per launch
+(cuda_histogram_constructor.cu:17-68). The drain flushes the ``pushes % K``
+remainder exactly (stale slots zero out on the channel side). That was the
+design's argument. On a v5e it does not hold inside this kernel (PERF.md
+section 6, PR 29: higgs, 10.5M x 28, 255 leaves, block 384): K = 1 / 2 / 4
+train at 0.932 / 0.939 / 0.949 s an iteration, each deeper ring a little
+slower, and K = 6 / 7 / 8 / 16 at 6.6 / 8.3 / 9.7 / 25.3 s. Once one flush
+spans K x bs >= 2048 rows (K = 8 at bs = 192 does not, and runs like K = 1)
+the walk pays 60-96 ns for every row a split STREAMS, histogrammed or not;
+``hist_debug="sync"`` at K = 8's VMEM sizing runs like K = 1, so it is the
+flush's code in the loop body and not the ring's residency. What the ring
+does buy is fewer roundings: a flush folds K blocks' partial sums into the
+f32 accumulator in one addition, and at 63 bins K = 2 halves the worst
+leaf's hessian error against K = 1 (1.8e-4 against 3.4e-4 relative). So a
+fused entry runs K = 2, 0.7% slower than K = 1, unless the user or
+LGBM_TPU_HIST_MBATCH names a depth (engines/registry.py FUSED_MBATCH). The
+deeper arms stay for that bisect and for the parity tests
+(tests/test_hist_mbatch.py); ROADMAP (Design) says what a simplicity pass
+can put in the ring's place.
 """
 from __future__ import annotations
 
@@ -920,11 +933,15 @@ def fused_split(
     ``mbatch`` (env/param ``tpu_hist_mbatch``) is the depth of the
     histogram pending ring: K staged row blocks issue ONE one-hot
     contraction per feature group with M = 8K MXU rows (hist_flush)
-    instead of K matmuls at M = 8. K = 1 is the sync reference path
-    (counts and int32 histograms bit-identical at any K; bf16 grad/hess
-    within ~2^-17 relative — the f32 accumulation regroups). The ring
-    multiplies histogram-side VMEM residency by K, so callers must size
-    ``block_size`` through :func:`fused_block_cap`.
+    instead of K matmuls at M = 8. K = 1 is the reference path; the
+    engine registry hands a fused entry K = 2 by default: on the chip
+    every deeper ring measured slower, K = 8 by a factor of ten, and
+    K = 2 buys half the roundings for 0.7% (module docstring). Counts and int32 histograms are bit-identical at any K;
+    bf16 grad/hess within ~2^-17 relative — the f32 accumulation
+    regroups. The ring multiplies histogram-side VMEM residency by K, so
+    callers must size ``block_size`` through :func:`fused_block_cap`.
+    The signature's default of 8 is the standalone engines' and is what
+    a direct caller gets; the grower always passes the resolved depth.
 
     CONTRACT — pad >= block_size: the row arrays must be padded past the
     real row count by at least ``block_size`` rows (internal callers pad by

@@ -172,13 +172,17 @@ class GrowerParams(NamedTuple):
     # (ops/pallas_histogram.py _hist_kernel_sublane,
     # ops/fused_split.py hist_flush)
     hist_layout: str = "lane"
-    # batched-M histogram depth (env/param tpu_hist_mbatch): K staged row
-    # blocks per one-hot contraction fill M = 8K of the 128 MXU rows —
-    # the fused kernel's pending ring, the Mosaic kernel's window
-    # partition, and the XLA engine's chunk widening all key off this
-    # (ops/fused_split.py hist_flush is the reference design). K = 1 is
-    # the sync reference path; the ring multiplies histogram-side VMEM
-    # residency by K (ops/fused_split.py fused_block_cap)
+    # batched-M histogram depth (env/param tpu_hist_mbatch): K row blocks
+    # per one-hot contraction fill M = 8K of the 128 MXU rows. The depth
+    # the run's histogram engine runs, as the engine registry resolved
+    # it: the Mosaic kernel's window partition and the XLA engine's
+    # chunk widening want 8 (their default and their sweep's usual
+    # winner); the fused kernel's pending ring wants 1 or 2 — at 8 it
+    # costs a fused split ten times its time on the chip
+    # (ops/fused_split.py docstring), so under a fused entry this field
+    # reads engines/registry.py FUSED_MBATCH (2) unless the user or the
+    # environment named a depth. The ring multiplies histogram-side VMEM residency by K
+    # (ops/fused_split.py fused_block_cap)
     hist_mbatch: int = 8
     # data-parallel histogram reduction: 0 = all-reduce (lax.psum) of the
     # full [F, B, 4] histogram; S > 0 = reduce-scatter over the feature

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.analysis import guards
+from lightgbm_tpu.engines import registry
 from lightgbm_tpu.ops.compact import RowLayout, pack_rows
 from lightgbm_tpu.ops.fused_split import (fused_block_cap, fused_ring_bytes,
                                           fused_split)
@@ -134,6 +135,52 @@ def test_fused_split_mode_parity_with_mbatch():
         outs[mb] = (np.asarray(w), np.asarray(s), np.asarray(hist))
     np.testing.assert_array_equal(outs[1][0], outs[8][0])   # partition
     np.testing.assert_array_equal(outs[1][2][:, :, 2:], outs[8][2][:, :, 2:])
+
+
+def test_compact_grower_tree_identical_at_fused_default_and_depth_8():
+    """Grower level: a compact-grower forest grown at the depth a fused
+    entry now resolves by default and at an explicit depth 8 (the
+    standalone engines' default, which fused entries used to inherit)
+    has identical splits and leaf counts — the depth regroups an f32
+    accumulation and nothing else."""
+    rng = np.random.RandomState(23)
+    n, f = 1500, 8
+    X = rng.randn(n, f).astype(np.float32)
+    y = (X[:, 0] - 0.5 * X[:, 3] + 0.3 * X[:, 5] * X[:, 1]
+         + 0.4 * rng.randn(n) > 0).astype(np.float64)
+    base = {
+        "objective": "binary", "num_leaves": 15, "max_bin": 63,
+        "learning_rate": 0.1, "min_data_in_leaf": 20, "verbosity": -1,
+        "tpu_grower": "compact", "tpu_autotune": "off",
+        "tpu_fused_interpret": True, "tpu_fused_block": 128,
+    }
+    trees = {}
+    for name, extra in (("default", {}), ("k8", {"tpu_hist_mbatch": 8})):
+        params = dict(base, **extra)
+        bst = lgb.train(params, lgb.Dataset(X, label=y, params=params), 3,
+                        keep_training_booster=True)
+        gp = bst._gbdt.grower_params
+        assert gp.fused_block == 128
+        assert gp.hist_mbatch == (8 if extra else registry.FUSED_MBATCH)
+        trees[name] = bst.dump_model()["tree_info"]
+    assert registry.FUSED_MBATCH != 8       # else this compares nothing
+
+    def walk(node, out):
+        if "leaf_index" in node:
+            out.append(("leaf", node["leaf_index"], node["leaf_count"]))
+            return
+        out.append((node["split_feature"], node["threshold"],
+                    node["decision_type"], node["internal_count"]))
+        walk(node["left_child"], out)
+        walk(node["right_child"], out)
+
+    assert len(trees["default"]) == len(trees["k8"]) == 3
+    for a, b in zip(trees["default"], trees["k8"]):
+        assert a["num_leaves"] == b["num_leaves"] > 1
+        wa, wb = [], []
+        walk(a["tree_structure"], wa)
+        walk(b["tree_structure"], wb)
+        assert wa == wb
 
 
 # --------------------------------------------------- standalone Mosaic

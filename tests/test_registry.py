@@ -316,6 +316,145 @@ def test_implicit_arming_stays_inert_on_cpu(monkeypatch):
     assert not res.autotuned
 
 
+# ------------------------------------------ the fused kernel's own depth
+BIG = registry.DatasetShape(rows=1 << 20, features=28, num_bins=255,
+                            mode="serial")
+OFF = {"tpu_autotune": "off"}
+
+
+def _cached(tmp_path, shape, platform="tpu", layout="lane"):
+    """cfg whose autotune cache holds a standalone Mosaic winner at depth
+    16 in ``layout``."""
+    cache = tmp_path / "at.json"
+    autotune.store_decision(
+        str(cache), autotune.cache_key(platform,
+                                       registry.shape_class(shape)),
+        _decision_block({"entry": f"pallas_{layout}", "hist_impl": "pallas",
+                         "hist_layout": layout, "hist_mbatch": 16},
+                        platform=platform,
+                        sclass=registry.shape_class(shape)))
+    return {"tpu_autotune": "first_run", "tpu_autotune_cache": str(cache)}
+
+
+@pytest.mark.parametrize("cfg,platform,entry", [
+    (OFF, "tpu", "fused_lane"),
+    (dict(OFF, tpu_fused="on"), "tpu", "fused_lane"),
+    # interpret mode on a CPU host is a fused entry too
+    (dict(OFF, tpu_fused_interpret=True, tpu_fused_block=128), "cpu",
+     "fused_lane"),
+], ids=["tpu-auto", "tpu-on", "cpu-interpret"])
+def test_fused_entry_resolves_its_own_depth(monkeypatch, cfg, platform,
+                                            entry):
+    """With a fused entry and nothing set, the resolution carries the
+    fused kernel's depth (not the standalone engines' default of 8) and
+    says where it came from."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    res = registry.resolve(cfg, shape=BIG, platform=platform)
+    assert res.entry_id == entry and res.fused_block > 0
+    assert res.hist_mbatch == registry.FUSED_MBATCH
+    assert res.sources["hist_mbatch"] == "fused"
+    # no shape context (a booster without a train set) fuses alike
+    res = registry.resolve(cfg, shape=None, platform=platform)
+    assert res.hist_mbatch == registry.FUSED_MBATCH
+    assert res.sources["hist_mbatch"] == "fused"
+
+
+def test_fused_sublane_entry_resolves_its_own_depth(monkeypatch):
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    res = registry.resolve(dict(OFF, tpu_hist_layout="sublane"),
+                           shape=BIG._replace(num_bins=63), platform="tpu")
+    assert res.entry_id == "fused_sublane"
+    assert res.hist_mbatch == registry.FUSED_MBATCH
+    assert res.sources["hist_mbatch"] == "fused"
+
+
+def test_user_and_env_depth_still_reach_the_fused_kernel(monkeypatch):
+    """An explicit tpu_hist_mbatch and LGBM_TPU_HIST_MBATCH win for the
+    fused kernel as they do for the standalone engines, user first: the
+    chip bisect (depth 1 against 8) stays reproducible."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    res = registry.resolve(dict(OFF, tpu_hist_mbatch=8), shape=BIG,
+                           platform="tpu")
+    assert res.entry_id == "fused_lane"
+    assert res.hist_mbatch == 8 and res.sources["hist_mbatch"] == "user"
+    monkeypatch.setenv("LGBM_TPU_HIST_MBATCH", "4")
+    res = registry.resolve(OFF, shape=BIG, platform="tpu")
+    assert res.hist_mbatch == 4 and res.sources["hist_mbatch"] == "env"
+    res = registry.resolve(dict(OFF, tpu_hist_mbatch=16), shape=BIG,
+                           platform="tpu")
+    assert res.hist_mbatch == 16 and res.sources["hist_mbatch"] == "user"
+    monkeypatch.setenv("LGBM_TPU_HIST_MBATCH", "99")     # clamped, not lost
+    res = registry.resolve(OFF, shape=BIG, platform="tpu")
+    assert res.hist_mbatch == 16 and res.sources["hist_mbatch"] == "env"
+
+
+def test_fused_entry_takes_the_sweeps_layout_not_its_depth(tmp_path,
+                                                           monkeypatch):
+    """The autotuner times the STANDALONE engines: its winning depth does
+    not reach the fused kernel (its winning layout still does), and the
+    same cache entry applies in full once the run does not fuse."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    shape = BIG._replace(num_bins=16)
+    cfg = _cached(tmp_path, shape, layout="sublane")
+    res = registry.resolve(cfg, shape=shape, platform="tpu",
+                           allow_sweep=False)
+    assert res.autotuned and res.entry_id == "fused_sublane"
+    assert res.sources["hist_layout"] == "autotune"
+    assert res.hist_mbatch == registry.FUSED_MBATCH
+    assert res.sources["hist_mbatch"] == "fused"
+    res = registry.resolve(dict(cfg, tpu_fused="off"), shape=shape,
+                           platform="tpu", allow_sweep=False)
+    assert res.entry_id == "pallas_sublane" and res.fused_block == 0
+    assert res.hist_mbatch == 16
+    assert res.sources["hist_mbatch"] == "autotune"
+
+
+@pytest.mark.parametrize("cfg,shape,platform", [
+    (OFF, BIG, "cpu"),
+    (dict(OFF, tpu_fused="off"), BIG, "tpu"),
+    (OFF, BIG._replace(mode="voting", gspmd=True), "tpu"),
+    (OFF, BIG._replace(mode="data", gspmd=True), "tpu"),
+    # one chip's masked grower (small data, caller's gradients)
+    (OFF, BIG._replace(compact=False), "tpu"),
+    (dict(OFF, tpu_fused_interpret=True), BIG._replace(compact=False),
+     "cpu"),
+], ids=["cpu", "tpu-fused-off", "gspmd-voting", "gspmd-data",
+        "tpu-masked", "cpu-interpret-masked"])
+def test_standalone_engines_resolve_as_before(tmp_path, monkeypatch, cfg,
+                                              shape, platform):
+    """A resolution that does not fuse keeps the standalone depth: the
+    default of 8, the sweep's winner above it, user and env above that."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    res = registry.resolve(cfg, shape=shape, platform=platform)
+    assert not res.entry_id.startswith("fused")
+    assert res.hist_mbatch == 8 and res.sources["hist_mbatch"] == "default"
+    cached = dict(_cached(tmp_path, shape, platform), **{
+        k: v for k, v in cfg.items() if k != "tpu_autotune"})
+    res = registry.resolve(cached, shape=shape, platform=platform,
+                           allow_sweep=False)
+    assert res.autotuned
+    assert res.hist_mbatch == 16
+    assert res.sources["hist_mbatch"] == "autotune"
+    res = registry.resolve(dict(cfg, tpu_hist_mbatch=2), shape=shape,
+                           platform=platform)
+    assert res.hist_mbatch == 2 and res.sources["hist_mbatch"] == "user"
+
+
+def test_autotune_candidates_unchanged_by_the_fused_depth():
+    """The sweep grid is the standalone engines' and keeps its depths,
+    default first; no fused entry is ever timed."""
+    assert registry.MBATCH_CANDIDATES == (8, 16, 1)
+    keys = [c.key for c in registry.sweep_candidates(BIG, "tpu")]
+    assert keys == ["xla_lane-k8", "xla_lane-k16", "xla_lane-k1",
+                    "pallas_lane-k8", "pallas_lane-k16", "pallas_lane-k1"]
+    assert [c.key for c in registry.sweep_candidates(BIG, "cpu")] \
+        == keys[:3]
+    assert registry.resolve_mbatch({}) == 8
+    assert registry.resolve_mbatch({}, {"hist_mbatch": 16}) == 16
+    assert registry.resolve_mbatch({}, {"hist_mbatch": 16},
+                                   fused=True) == registry.FUSED_MBATCH
+
+
 # ----------------------------------------------- booster-level integration
 def test_first_run_once_then_zero_microbenches(tmp_path, monkeypatch):
     """The acceptance loop: a fresh cache sweeps exactly once at
@@ -398,6 +537,61 @@ def test_reset_uses_in_memory_decision_not_cache(tmp_path, monkeypatch):
     assert res.autotuned and res.decision == decision0
     assert res.hist_mbatch == decision0["hist_mbatch"]
     bst.update()
+
+
+FUSED_CPU = dict(BASE, tpu_grower="compact", tpu_autotune="off",
+                 tpu_fused_interpret=True, tpu_fused_block=128,
+                 num_leaves=7)
+
+
+def test_reset_parameter_reresolves_to_the_fused_depth(monkeypatch):
+    """A fused run's depth survives reset_parameter (the learning-rate
+    callback resets every iteration: no rebuild of the step), and a
+    mid-run explicit depth still reaches the kernel."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    X, y = binary_data(600, 6, seed=3)
+    bst = lgb.Booster(FUSED_CPU, lgb.Dataset(X, label=y, params=FUSED_CPU))
+    g = bst._gbdt
+    assert g._use_compact and g.grower_params.fused_block == 128
+    assert g._engine_resolution.entry_id == "fused_lane"
+    assert g.grower_params.hist_mbatch == registry.FUSED_MBATCH
+    assert g._engine_resolution.sources["hist_mbatch"] == "fused"
+    bst.update()
+    step = g._compact["step"]
+    bst.reset_parameter({"learning_rate": 0.05})
+    assert g.grower_params.hist_mbatch == registry.FUSED_MBATCH
+    assert g._engine_resolution.sources["hist_mbatch"] == "fused"
+    assert g._compact["step"] is step           # nothing to rebuild
+    bst.reset_parameter({"tpu_hist_mbatch": 8})
+    assert g.grower_params.hist_mbatch == 8
+    assert g._engine_resolution.sources["hist_mbatch"] == "user"
+    bst.update()
+
+
+def test_unfused_fallbacks_keep_the_standalone_depth(monkeypatch):
+    """Where the fused kernel leaves a run AFTER the resolve, its
+    histograms come from a standalone engine at the standalone depth:
+    caller-supplied gradients (masked grower), and the record-width
+    clamp finding no block (the XLA walk's segment_histogram)."""
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    X, y = binary_data(600, 6, seed=4)
+    bst = lgb.Booster(FUSED_CPU, lgb.Dataset(X, label=y, params=FUSED_CPU))
+    g = bst._gbdt
+    assert g.grower_params.hist_mbatch == registry.FUSED_MBATCH
+    n = len(y)
+    g.train_one_iter(np.zeros(n, np.float32) + 0.1,
+                     np.ones(n, np.float32))
+    assert not g._use_compact and not g._engine_shape.compact
+    assert g.grower_params.hist_mbatch == 8
+    # the clamp takes the kernel off
+    monkeypatch.setattr(registry, "clamp_fused_block", lambda *a, **k: 0)
+    bst = lgb.Booster(FUSED_CPU, lgb.Dataset(X, label=y, params=FUSED_CPU))
+    bst.update()
+    gp = bst._gbdt.grower_params
+    assert gp.fused_block == 0 and gp.hist_mbatch == 8
+    bst.reset_parameter({"lambda_l2": 1.0})
+    gp = bst._gbdt.grower_params
+    assert gp.fused_block == 0 and gp.hist_mbatch == 8
 
 
 def test_sweep_skipped_when_all_knobs_pinned(monkeypatch):

@@ -17,8 +17,9 @@ described-device executable cannot be read back from the persistent
 compilation cache, so the cache is switched off around the compiles.
 
 Tier-1 keeps the standalone histogram kernel (split and int8 at higgs
-width, a few seconds each) and ONE fused case (higgs, dual, mbatch 8,
-~80 s); the other fused variants and the whole step programs are ``slow``
+width, a few seconds each) and ONE fused case (higgs, dual, at the depth
+the registry resolves for a fused entry, ~13 s); the other fused variants
+and the whole step programs are ``slow``
 (run them before spending chip time on a change to a kernel, its clamp, or
 the step: ``pytest tests/test_tpu_compile.py -m 'slow or not slow'``).
 """
@@ -125,7 +126,10 @@ def test_histogram_kernel_compiles_for_v5e_slow(name, one_chip,
 # -------------------------------------------------------- fused split kernel
 FUSED_CASES_SLOW = {
     # name: dict(features, bins, + fused_split keyword overrides)
+    # the depths a user or LGBM_TPU_HIST_MBATCH can still hand the kernel
+    # (8 is what fused entries ran before PR 29, ~80 s to compile)
     "higgs-dual-k1": dict(f=28, b=256, mbatch=1),
+    "higgs-dual-k8": dict(f=28, b=256, mbatch=8),
     "higgs-dual-k16": dict(f=28, b=256, mbatch=16),
     "higgs-copyback-k8": dict(f=28, b=256, dual=False),
     "higgs-quant-k8": dict(f=28, b=256, quant=True),
@@ -167,8 +171,14 @@ def _fused_compile(one_chip, f, b, rows=1 << 20, packed4=False, **kw):
 
 def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
     """The headline kernel as higgs trains it: 128-byte records, 256 bins,
-    dual residency, batched-M 8, block clamped to 384."""
-    _fused_compile(one_chip, f=28, b=256)
+    dual residency, block clamped to 384, at the depth the registry
+    resolves for a fused entry on a TPU with nothing set."""
+    res = registry.resolve(
+        {"tpu_autotune": "off"}, platform="tpu",
+        shape=registry.DatasetShape(HIGGS_ROWS, 28, 255, "serial"))
+    assert res.entry_id == "fused_lane"
+    assert res.sources["hist_mbatch"] == "fused"
+    _fused_compile(one_chip, f=28, b=256, mbatch=res.hist_mbatch)
 
 
 @pytest.mark.slow
