@@ -686,17 +686,16 @@ class Booster:
             gbdt._step_buckets,
             int(self.config.num_leaves), int(self.config.max_depth))
         gbdt._max_depth_cfg = int(self.config.max_depth)
-        resolved_fb = resolved.fused_block
+        resolved_fb, resolved_mbatch = (resolved.fused_block,
+                                        resolved.hist_mbatch)
         clamp_ctx = getattr(gbdt, "_fused_clamp_ctx", None)
         if resolved_fb and clamp_ctx:
             # the compact row layout is already built: re-run the SAME
-            # record-width scoped-VMEM clamp _setup_compact_state applied
-            resolved_fb = engine_registry.clamp_fused_block(
-                resolved_fb, clamp_ctx["num_cols"], resolved.hist_mbatch,
-                resolved.hist_layout, num_bins=clamp_ctx["num_bins"],
-                num_features=clamp_ctx["num_features"],
+            # record-width clamp _setup_compact_state applied
+            resolved_fb, resolved_mbatch = engine_registry.fit_fused_flush(
+                resolved, clamp_ctx["num_cols"], clamp_ctx["num_bins"],
+                clamp_ctx["num_features"],
                 env_override=os.environ.get("LGBM_TPU_FUSED_BS", ""))
-        resolved_mbatch = resolved.hist_mbatch
         if resolved.fused_block and not resolved_fb:
             # the clamp took the fused kernel off: the standalone depth
             resolved_mbatch = engine_registry.standalone_mbatch(

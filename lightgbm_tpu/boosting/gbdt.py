@@ -18,6 +18,7 @@ Layout decisions (vs the reference):
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -1465,21 +1466,23 @@ class GBDT:
             # the batched-M pending ring with hist_mbatch * block_size,
             # and the histogram accumulator with num_cols * num_bins; the
             # registry-owned clamp scales the block down for wide records
-            # / deep rings and falls back to the XLA walk when the
-            # histogram alone would blow the ~16MB scoped limit
-            resolved_bs = engine_registry.clamp_fused_block(
-                gp.fused_block, layout.num_cols, gp.hist_mbatch,
-                gp.hist_layout, num_bins=int(self.grower_params.num_bins),
-                num_features=layout.num_features,
+            # / deep rings / many feature groups (trading the depth
+            # for the block where nobody named a depth) and falls back to
+            # the XLA walk when the histogram alone would blow the ~16MB
+            # scoped limit
+            resolved_bs, resolved_depth = engine_registry.fit_fused_flush(
+                self._engine_resolution, layout.num_cols,
+                int(self.grower_params.num_bins), layout.num_features,
                 env_override=os.environ.get("LGBM_TPU_FUSED_BS", ""))
-            if resolved_bs != gp.fused_block:
-                gp = gp._replace(fused_block=resolved_bs)
-                if not resolved_bs:
-                    # the XLA walk's segment_histogram is a standalone
-                    # engine: it keeps their depth, not the fused kernel's
-                    gp = gp._replace(
-                        hist_mbatch=engine_registry.standalone_mbatch(
-                            self.config, self._engine_resolution))
+            if not resolved_bs:
+                # the XLA walk's segment_histogram is a standalone
+                # engine: it keeps their depth, not the fused kernel's
+                resolved_depth = engine_registry.standalone_mbatch(
+                    self.config, self._engine_resolution)
+            if (resolved_bs, resolved_depth) != (gp.fused_block,
+                                                gp.hist_mbatch):
+                gp = gp._replace(fused_block=resolved_bs,
+                                 hist_mbatch=resolved_depth)
                 self.grower_params = gp
         # the fused kernel's aligned block writes may overrun a segment end
         # by up to one block + one alignment tile
@@ -1543,13 +1546,15 @@ class GBDT:
             nm = self.num_data
             off = layout.extra_off + 4 * self._cx_rowid
 
-            def fn(work, scores_cur):
+            def fn(work, scores_cur, by_length=None):
                 from ..ops.compact import _u8_to_f32
                 rows = (work.reshape(S, nl + pr, -1)[:, :nl]
                         .reshape(S * nl, -1) if S > 1 else work[:nm])
                 rid = _u8_to_f32(rows[:, off:off + 4]).astype(jnp.int32)
                 s_orig = jnp.zeros_like(scores_cur).at[:, rid].set(scores_cur)
-                g, h = obj.get_gradients(s_orig[0])
+                with (obj.bound_layout(by_length) if by_length is not None
+                      else contextlib.nullcontext()):
+                    g, h = obj.get_gradients(s_orig[0])
                 return g[rid], h[rid]
 
             # position-bias objectives update host state (pos_biases) inside
@@ -1557,6 +1562,11 @@ class GBDT:
             eager = (getattr(obj, "is_stochastic", False)
                      or getattr(obj, "positions", None) is not None)
             c["rank_grad_fn"] = fn if eager else jax.jit(fn)
+            # the jitted program takes the objective's layout (the queries
+            # by length class) as an argument, not as its constants
+            c["rank_grad_layout"] = (
+                obj.layout_arrays()
+                if not eager and hasattr(obj, "layout_arrays") else None)
         return c["rank_grad_fn"]
 
     def _compact_rows(self, work):
@@ -1989,9 +1999,10 @@ class GBDT:
         if getattr(self, "_ext_grads", False):
             # lambdarank-style coupled gradients: computed once per
             # iteration in original query order, permuted to current order
+            rank_grads = self._rank_grads_fn()
             ext_args = tuple(self._dispatch(
-                "gradient", self._rank_grads_fn(), c["work"],
-                self.train_score))
+                "rank_grads", rank_grads, c["work"], self.train_score,
+                c["rank_grad_layout"]))
         for k in range(k_total):
             # trees after the first in an iteration reuse the stored bag
             # (same bag for all trees of one iteration, like the reference)
@@ -2414,7 +2425,8 @@ class GBDT:
     def _dispatch(site, program, *args, **kwargs):
         """One call of one of the booster's jitted programs from the
         update loop, under the host span ``site`` (``step_dispatch``,
-        ``gradient``) and counted into the update's ``dispatches``. The
+        ``gradient``, ``rank_grads``) and counted into the update's
+        ``dispatches``. The
         span times the dispatch (and, on a first call, the trace and
         compile inside it), not the device's work: nothing here waits."""
         with span(site):
@@ -2440,6 +2452,9 @@ class GBDT:
         from ..analysis import guards
         from ..obs import flight
         counters = update_counters()
+        # what a ranking objective's layout by query length makes the
+        # gradient program compute (objectives.py): fixed at init
+        counters.update(getattr(self.objective, "rank_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),
                     **counters)

@@ -578,7 +578,10 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
     and the batched-M pending ring with ``mbatch * block_size`` (bins +
     transposed channels + the flush's one-hot and block-diagonal
     transients — both register layouts charged, ops/fused_split.py
-    fused_ring_bytes); the histogram accumulator needs
+    fused_ring_bytes), and the flush's unrolled text and stack with
+    feature groups x ``mbatch`` x ``block_size`` (``_FLUSH_ONEHOT_ROWS``:
+    220 features of 256 bins run block 96 at depth 2 where their
+    256-byte rows alone would allow 192); the histogram accumulator needs
     ``f_pad * stride * 32`` bytes regardless of block size, so a shape
     whose accumulator alone blows the ~16MB scoped limit falls back to
     the XLA walk (returns 0). ``env_override`` (LGBM_TPU_FUSED_BS) is
@@ -587,7 +590,9 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
         return 0
     from ..ops.fused_split import _hist_packing, fused_block_cap
     vmem_cap_bs = fused_block_cap(num_cols, mbatch,
-                                  hist_layout=hist_layout)
+                                  hist_layout=hist_layout,
+                                  num_features=num_features,
+                                  num_bins=num_bins)
     bs = min(block, vmem_cap_bs)
     if env_override:
         # perf experiments; rounded + re-guarded, never trusted raw
@@ -600,6 +605,34 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
                     "XLA compact walk")
         return 0
     return bs
+
+
+def fit_fused_flush(res: "Resolution", num_cols: int, num_bins: int,
+                    num_features: int, env_override: str = ""
+                    ) -> Tuple[int, int]:
+    """(fused_block, hist_mbatch) the fused kernel runs on this row
+    record: :func:`clamp_fused_block` at the resolved depth and, where
+    the depth is the fused default (source ``fused``: nobody named one)
+    and the clamp's bound on the flush has cut the block, depth 1 at the
+    block that holds as many rows a flush. One flush sums depth x block
+    rows into the f32 accumulator, so the sums are the same bit for bit;
+    the larger block streams faster and the shallower kernel compiles in
+    half the time (220 features of 256 bins: depth 1 at block 192 ran 2.85
+    s an iteration against 3.09 s at depth 2 and block 96, the
+    comparison's gaps identical to the last digit; PERF.md section 6,
+    PR 30). A block of 0 (the clamp took the kernel off) leaves the
+    depth to :func:`standalone_mbatch`."""
+    block = clamp_fused_block(res.fused_block, num_cols, res.hist_mbatch,
+                              res.hist_layout, num_bins, num_features,
+                              env_override)
+    depth = res.hist_mbatch
+    if (block and depth > 1 and not env_override
+            and res.sources.get("hist_mbatch") == "fused"):
+        shallow = clamp_fused_block(res.fused_block, num_cols, 1,
+                                    res.hist_layout, num_bins, num_features)
+        if shallow >= depth * block:
+            return shallow, 1
+    return block, depth
 
 
 # ---------------------------------------------------------------------------

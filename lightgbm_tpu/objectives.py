@@ -21,11 +21,14 @@ Interface mirrors the reference's (objective_function.h):
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import contextlib
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .obs.spans import span
 
 _EPS = 1e-15
 
@@ -480,15 +483,76 @@ class CrossEntropyLambda(Objective):
 # Ranking (reference: src/objective/rank_objective.hpp — LambdarankNDCG :138,
 # RankXENDCG :378; CUDA mirror cuda_rank_objective.cu)
 # ---------------------------------------------------------------------------
+def _padded_index(starts: np.ndarray, sizes: np.ndarray) -> np.ndarray:
+    """[Q, M] int32 row index of queries that start at ``starts`` and hold
+    ``sizes`` rows, M the longest (at least 1), padded with -1 —
+    vectorized (no O(total rows) Python loop)."""
+    m = max(int(sizes.max()), 1) if len(sizes) else 1
+    pos = np.arange(m, dtype=np.int32)[None, :]
+    idx = np.asarray(starts)[:, None].astype(np.int32) + pos
+    return np.where(pos < np.asarray(sizes)[:, None], idx, -1)
+
+
 def _pad_queries(boundaries: np.ndarray) -> Tuple[np.ndarray, int]:
     """Build a [Q, M] row-index matrix (padded with -1) from query
-    boundaries — vectorized (no O(total rows) Python loop)."""
+    boundaries."""
+    idx = _padded_index(boundaries[:-1], np.diff(boundaries))
+    return idx, idx.shape[1]
+
+
+#: queries are grouped by length into classes of this width up to
+#: ``_CLASS_LINEAR_TOP`` documents and of doubling width beyond it: the
+#: pair block of a class costs its queries times its longest query, so a
+#: class of width 128 pads a query by 64 documents on average whatever
+#: its length (a fifth at the few hundred documents real result lists
+#: hold; powers of two would pad by a third), and the doubling keeps the
+#: number of classes, each a sort and a pair block of its own in the one
+#: gradient program, at 8 + log2(longest / 1024)
+_CLASS_WIDTH = 128
+_CLASS_LINEAR_TOP = 1024
+
+
+def _length_class(sizes: np.ndarray) -> np.ndarray:
+    """The class of each query length: see ``_CLASS_WIDTH``."""
+    sizes = np.maximum(np.asarray(sizes, np.int64), 1)
+    linear = -(-sizes // _CLASS_WIDTH)
+    beyond = _CLASS_LINEAR_TOP // _CLASS_WIDTH + np.ceil(np.log2(
+        np.maximum(sizes, _CLASS_LINEAR_TOP) / _CLASS_LINEAR_TOP)
+    ).astype(np.int64)
+    return np.where(sizes <= _CLASS_LINEAR_TOP, linear, beyond)
+
+
+class _QueryClass(NamedTuple):
+    """The queries of one length class, padded to the longest of them."""
+    index: jax.Array          # [Qc, Mc] int32 row of each slot, -1 = pad
+    gain: jax.Array           # [Qc, Mc] float32 label gain, 0 in the pad
+    inv_max_dcg: jax.Array    # [Qc] float32
+    queries: np.ndarray       # [Qc] the queries' numbers, ascending
+
+
+def _queries_by_length(boundaries: np.ndarray, num_data: int):
+    """Group the queries into length classes. Returns ``[(queries [Qc],
+    index [Qc, Mc] padded with -1)]`` in ascending class order, and
+    ``row_slot`` [num_data] int32: where each row sits in the classes'
+    slots laid end to end, to read per-slot results back by one gather; a
+    row of no query (the sharded learner's pad rows) points one past the
+    last slot. Queries of one length make one class, which is
+    ``_pad_queries``' matrix."""
+    boundaries = np.asarray(boundaries, np.int64)
     sizes = np.diff(boundaries)
-    q = len(sizes)
-    m = int(sizes.max()) if q else 1
-    pos = np.arange(m, dtype=np.int32)[None, :]
-    idx = boundaries[:-1, None].astype(np.int32) + pos
-    return np.where(pos < sizes[:, None], idx, -1), m
+    cls = _length_class(sizes)
+    row_slot = np.zeros(num_data, np.int32)
+    out, base = [], 0
+    for c in np.unique(cls):
+        qs = np.flatnonzero(cls == c)
+        idx = _padded_index(boundaries[qs], sizes[qs])
+        valid = idx >= 0
+        row_slot[idx[valid]] = base + np.flatnonzero(valid.reshape(-1))
+        out.append((qs, idx))
+        base += idx.size
+    if len(boundaries):
+        row_slot[int(boundaries[-1]):] = base
+    return out, row_slot
 
 
 class LambdarankNDCG(Objective):
@@ -522,11 +586,6 @@ class LambdarankNDCG(Objective):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             raise ValueError("ranking objective requires query groups (set_group)")
-        qb = metadata.query_boundaries
-        idx, m = _pad_queries(qb)
-        self.query_index = jnp.asarray(idx)          # [Q, M]
-        self.query_mask = jnp.asarray(idx >= 0)      # [Q, M]
-        self.max_query = m
         lbl = _np(metadata.label).astype(np.int32)
         max_label = int(lbl.max()) if len(lbl) else 0
         if self.label_gain is None:
@@ -540,17 +599,9 @@ class LambdarankNDCG(Objective):
         row_gain = gains[lbl]
         self.row_gain = jnp.asarray(row_gain, jnp.float32)
         self.row_label = jnp.asarray(lbl, jnp.int32)
-        # inverse max DCG per query, vectorized over the padded query matrix
-        # (reference: lambdarank_ndcg init)
-        gp = np.where(idx >= 0, row_gain[np.maximum(idx, 0)], -np.inf)
-        gp = -np.sort(-gp, axis=1)                           # desc per query
-        k = min(m, self.truncation_level)
-        disc = 1.0 / np.log2(np.arange(k) + 2.0)
-        mdcg = np.sum(np.where(np.isfinite(gp[:, :k]), gp[:, :k], 0.0)
-                      * disc[None, :], axis=1)
-        self.inv_max_dcg = jnp.asarray(
-            np.where(mdcg > 0, 1.0 / np.maximum(mdcg, 1e-300), 0.0),
-            jnp.float32)                                     # [Q]
+        with span("rank_layout"):
+            self._layout_queries(metadata.query_boundaries, row_gain,
+                                 num_data)
         # per-position bias state (updated every iteration -> the gradient
         # fn must not be jit-frozen; see is_stochastic)
         self.positions = None
@@ -571,10 +622,72 @@ class LambdarankNDCG(Objective):
                 .astype(np.float32))
             self.is_stochastic = True  # stateful bias updates each call
 
+    def _layout_queries(self, boundaries, row_gain, num_data):
+        """The queries by length class (``_queries_by_length``), each
+        class with its inverse max DCG per query (reference:
+        lambdarank_ndcg init), and the counters of the layout."""
+        classes, row_slot = _queries_by_length(boundaries, num_data)
+        self.query_classes = []
+        slots = 0
+        for qs, idx in classes:
+            valid = idx >= 0
+            gain = np.where(valid, row_gain[np.maximum(idx, 0)], 0.0)
+            top = -np.sort(-np.where(valid, gain, -np.inf), axis=1)
+            k = min(idx.shape[1], self.truncation_level)
+            disc = 1.0 / np.log2(np.arange(k) + 2.0)
+            mdcg = np.sum(np.where(np.isfinite(top[:, :k]), top[:, :k], 0.0)
+                          * disc[None, :], axis=1)
+            inv = np.where(mdcg > 0, 1.0 / np.maximum(mdcg, 1e-300), 0.0)
+            self.query_classes.append(_QueryClass(
+                jnp.asarray(idx), jnp.asarray(gain, jnp.float32),
+                jnp.asarray(inv, jnp.float32), qs))
+            chunk, n_chunks = self._chunks(idx.shape[0])
+            slots += chunk * n_chunks * idx.shape[1]
+        self.row_slot = jnp.asarray(row_slot)
+        docs = int(boundaries[-1])
+        #: what the gradient program computes against what there is: the
+        #: update's ``iteration`` event carries these (gbdt)
+        self.rank_counters = {
+            "rank_slots": slots, "rank_docs": docs,
+            "rank_slots_per_doc": slots / docs if docs else 0.0,
+            "rank_classes": len(self.query_classes)}
+
+    def layout_arrays(self):
+        """The layout's device arrays as one pytree, for a jitted caller
+        to hand in as an argument (``bound_layout``)."""
+        return ([(c.index, c.gain, c.inv_max_dcg)
+                 for c in self.query_classes], self.row_slot)
+
+    @contextlib.contextmanager
+    def bound_layout(self, arrays):
+        """``get_gradients`` inside reads ``arrays`` (``layout_arrays``'
+        pytree, traced) in place of the arrays held. Held arrays traced
+        into a program are its constants: tens of MB in its text, and
+        another program, compiled anew, for every order the same queries
+        come in."""
+        classes, row_slot = arrays
+        held = self.query_classes, self.row_slot
+        self.query_classes = [
+            c._replace(index=i, gain=g, inv_max_dcg=d)
+            for c, (i, g, d) in zip(held[0], classes)]
+        self.row_slot = row_slot
+        try:
+            yield
+        finally:
+            self.query_classes, self.row_slot = held
+
     # queries processed in chunks of this many per pair-tensor block; the
     # block is [CHUNK, T, M] floats — memory stays bounded for MS-LTR-scale
     # datasets (the old formulation materialized [Q, M, M])
     _QUERY_CHUNK = 256
+
+    @classmethod
+    def _chunks(cls, q: int) -> Tuple[int, int]:
+        """(queries a chunk, chunks) for a class of ``q`` queries: as few
+        chunks as ``_QUERY_CHUNK`` allows, of even size, so that fewer
+        padding queries than chunks are computed."""
+        n_chunks = max(1, -(-q // cls._QUERY_CHUNK))
+        return -(-q // n_chunks), n_chunks
 
     def _query_chunk_grads(self, s, g, mask, inv_max_dcg):
         """Lambda gradients for one chunk of padded queries [Qc, M].
@@ -646,29 +759,24 @@ class LambdarankNDCG(Objective):
         hess_q = jnp.take_along_axis(hess_sorted, rank_of, axis=1)
         return grad_q, hess_q
 
-    def get_gradients(self, score):
-        idx = self.query_index                       # [Q, M]
-        mask = self.query_mask
+    def _class_grads(self, score, cls):
+        """Per-slot lambda gradients of one length class, [Qc * Mc] each,
+        in chunks of ``_QUERY_CHUNK`` queries."""
+        idx, g = cls.index, cls.gain
+        mask = idx >= 0
         q, m = idx.shape
-        safe_idx = jnp.maximum(idx, 0)
-        if self.positions is not None:
-            # ranking math sees position-debiased scores (reference:
-            # rank_objective.hpp:70 score + pos_biases_[positions_[j]])
-            score = score + self.pos_biases[self.positions]
-        s = jnp.where(mask, score[safe_idx], -jnp.inf)        # [Q, M]
-        g = jnp.where(mask, self.row_gain[safe_idx], 0.0)     # gains
+        s = jnp.where(mask, score[jnp.maximum(idx, 0)], -jnp.inf)  # [Qc, Mc]
 
-        chunk = min(self._QUERY_CHUNK, q)
-        q_pad = (-q) % chunk
+        chunk, n_chunks = self._chunks(q)
+        q_pad = chunk * n_chunks - q
         if q_pad:
             s = jnp.pad(s, ((0, q_pad), (0, 0)), constant_values=-jnp.inf)
             g = jnp.pad(g, ((0, q_pad), (0, 0)))
             mask_p = jnp.pad(mask, ((0, q_pad), (0, 0)))
-            imd = jnp.pad(self.inv_max_dcg, (0, q_pad))
+            imd = jnp.pad(cls.inv_max_dcg, (0, q_pad))
         else:
             mask_p = mask
-            imd = self.inv_max_dcg
-        n_chunks = (q + q_pad) // chunk
+            imd = cls.inv_max_dcg
 
         def one_chunk(args):
             sc, gc, mc, imdc = args
@@ -679,13 +787,22 @@ class LambdarankNDCG(Objective):
             (s.reshape(n_chunks, chunk, m), g.reshape(n_chunks, chunk, m),
              mask_p.reshape(n_chunks, chunk, m),
              imd.reshape(n_chunks, chunk)))
-        grad_q = grad_q.reshape(-1, m)[:q]
-        hess_q = hess_q.reshape(-1, m)[:q]
+        return (grad_q.reshape(-1, m)[:q].reshape(-1),
+                hess_q.reshape(-1, m)[:q].reshape(-1))
 
-        grad = jnp.zeros_like(score).at[safe_idx.reshape(-1)].add(
-            jnp.where(mask, grad_q, 0.0).reshape(-1))
-        hess = jnp.zeros_like(score).at[safe_idx.reshape(-1)].add(
-            jnp.where(mask, hess_q, 0.0).reshape(-1))
+    def get_gradients(self, score):
+        if self.positions is not None:
+            # ranking math sees position-debiased scores (reference:
+            # rank_objective.hpp:70 score + pos_biases_[positions_[j]])
+            score = score + self.pos_biases[self.positions]
+        # every class's slots laid end to end and a zero after the last,
+        # for the rows of no query; each row reads its own slot
+        per_class = [self._class_grads(score, c) for c in self.query_classes]
+        zero = jnp.zeros((1,), score.dtype)
+        grad = jnp.concatenate(
+            [g for g, _ in per_class] + [zero])[self.row_slot]
+        hess = jnp.concatenate(
+            [h for _, h in per_class] + [zero])[self.row_slot]
         grad, hess = self._weighted(grad, hess)
         if self.positions is not None:
             # Newton step on the per-position bias factors (reference:

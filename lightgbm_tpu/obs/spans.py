@@ -45,7 +45,10 @@ Inside ``Booster.update()`` the ``iteration`` span also holds the
 update's counters (:func:`bump`): ``dispatches`` (calls of the booster's
 own jitted programs: the step, the gradients), ``host_syncs`` (entries
 into sites that block on the device) and ``d2h_bytes``; the closing
-``iteration`` event reports them (``GBDT._obs_iteration_tick``).
+``iteration`` event reports them (``GBDT._obs_iteration_tick``), and
+with them what a ranking objective's layout makes the gradient program
+compute: ``rank_slots`` (padded [queries, length] slots over all length
+classes), ``rank_docs``, ``rank_slots_per_doc``, ``rank_classes``.
 
 Span taxonomy (every name a device program or tick site carries):
 
@@ -69,14 +72,19 @@ Span taxonomy (every name a device program or tick site carries):
                           ``find_bins`` (the sample and the boundaries)
                           and ``binning``
 ``booster_init``          ``Booster.__init__`` with a train set, past its
-                          ``construct``; child ``to_device`` (the binned
-                          matrix's first move to the device, in
-                          ``GBDT._setup_train``)
+                          ``construct``; children ``to_device`` (the
+                          binned matrix's first move to the device, in
+                          ``GBDT._setup_train``) and, under a ranking
+                          objective, ``rank_layout`` (the queries grouped
+                          into length classes, each class's index, gains
+                          and inverse max DCG: objectives.py)
 ``compact_setup``         ``_setup_compact_state`` (first update)
 ``build_step``            ``_build_compact_step_fn`` / ``_build_step_fn``
 ``iteration``             all of ``Booster.update()``; children ``bag``,
-                          ``gradient``, ``step_dispatch`` (one per call of
-                          the jitted step), ``valid_scores``,
+                          ``gradient`` (``rank_grads`` where the program
+                          called is the ranking gradients' own, ahead of
+                          the compact step), ``step_dispatch`` (one per
+                          call of the jitted step), ``valid_scores``,
                           ``flush_trees`` (the ``device_get`` of the
                           pending trees: where the loop blocks)
 ========================  ==================================================
@@ -103,9 +111,9 @@ _TRACE_MODES = ("full", "annotations")
 #: loop (module docstring: which host spans record)
 ALWAYS_ON = frozenset((
     "import", "construct", "find_bins", "binning", "to_device",
-    "booster_init", "compact_setup", "build_step",
-    "iteration", "bag", "gradient", "step_dispatch", "valid_scores",
-    "flush_trees"))
+    "booster_init", "rank_layout", "compact_setup", "build_step",
+    "iteration", "bag", "gradient", "rank_grads", "step_dispatch",
+    "valid_scores", "flush_trees"))
 
 #: the update's counters (:func:`bump`), as its ``iteration`` event and
 #: the metrics stream's record carry them

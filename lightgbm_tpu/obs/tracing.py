@@ -20,8 +20,9 @@ SPAN_TAXONOMY = (
     "partition", "checkpoint_write", "predict_warmup", "serve_tick",
     "autotune", "featurize", "contrib",
     "import", "construct", "find_bins", "to_device", "booster_init",
-    "compact_setup", "build_step",
-    "iteration", "bag", "step_dispatch", "valid_scores", "flush_trees",
+    "rank_layout", "compact_setup", "build_step",
+    "iteration", "bag", "rank_grads", "step_dispatch", "valid_scores",
+    "flush_trees",
 )
 
 

@@ -139,19 +139,41 @@ def fused_ring_bytes(block_size: int, num_cols: int, mbatch: int,
 
 
 def fused_block_cap(num_cols: int, mbatch: int, quant: bool = False,
-                    hist_layout: str = "lane") -> int:
+                    hist_layout: str = "lane", num_features: int = 0,
+                    num_bins: int = 0) -> int:
     """Largest 32-multiple block size whose streaming buffers AND pending
     ring fit the scoped-VMEM caps (the automatic derivation and the
-    LGBM_TPU_FUSED_BS clamp both go through here)."""
+    LGBM_TPU_FUSED_BS clamp both go through here) and, where the caller
+    says how many features of how many bins the rows hold, whose flush
+    stays inside ``_FLUSH_ONEHOT_ROWS``."""
     bs = max(32, (_VMEM_STREAM_CAP // max(num_cols, 1)) // 32 * 32)
     while bs > 32 and fused_ring_bytes(bs, num_cols, mbatch, quant,
                                        hist_layout) > _VMEM_RING_BUDGET:
         bs -= 32
+    if num_features and num_bins:
+        _, f_pad, group = _hist_packing(num_features, num_bins)
+        groups = -(-f_pad // group)
+        bs = min(bs, max(32, _FLUSH_ONEHOT_ROWS
+                         // (groups * max(1, mbatch)) // 32 * 32))
     return bs
 
 # most per-feature one-hot compare tiles a matmul group may hold at once
 # (see _hist_packing)
 _MAX_GROUP_TILES = 8
+
+# The flush's feature loop is unrolled, and Mosaic unrolls every vector
+# operation over its registers: the kernel's text, and the stack slot each
+# matmul group's one-hot holds, grow with groups x depth x block, the rows
+# of one-hot one flush builds. Past 8 MB or so of text every streamed row
+# pays, histogrammed or not (PERF.md section 6, PR 30; the v5e compiler's
+# generated_code_size_in_bytes beside the chip's ns a parent row):
+#   14 groups x 2 x 384 = 10,752: 3.6 MB, 4.9 ns    14 x 4 x 384: 6.7 MB, 4.9
+#   69 x 2 x 192 = 26,496: 7.5 MB, clean            14 x 8 x 256 = 28,672: 82
+#   14 x 6 x 384 = 32,256: 9.6 MB, 62 ns            14 x 8 x 384: 12.6 MB, 96
+#   110 x 2 x 192 = 42,240: 12.0 MB, 183 ns, and 23 MB of stack against the
+#   compiler's 16 MB (refused outright before the block was bounded)
+# The largest that has run clean is the bound (fused_block_cap).
+_FLUSH_ONEHOT_ROWS = 26_496
 
 # sp scalar-prefetch vector layout (i32[16])
 _MODE, _BASE_T, _PHI, _COUNT, _NLEFT, _FEAT, _BIN, _DLEFT, _NANBIN, _ISCAT, \

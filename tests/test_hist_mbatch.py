@@ -271,6 +271,36 @@ def test_fused_block_cap_accounts_for_ring_depth():
     assert fused_block_cap(640, 8) <= fused_block_cap(128, 8)
 
 
+@pytest.mark.parametrize("cols, f, b, k, block", [
+    (128, 28, 256, 2, 384),      # higgs: 14 groups, 10,752 rows of one-hot
+    (128, 28, 64, 2, 384),       # 63 bins: 4 groups
+    (256, 137, 256, 2, 192),     # 69 groups: the largest that ran clean
+    (256, 220, 256, 2, 96),      # 110 groups: 192 ran 183 ns a parent row
+    (256, 220, 256, 1, 192),
+    (128, 28, 256, 8, 224),      # depth 8 at 256 and 384 ran 82 and 96 ns
+    (128, 28, 256, 4, 384),
+    (2048, 2000, 64, 2, 32),     # 250 groups: the floor
+])
+def test_fused_block_cap_bounds_the_rows_of_one_hot_a_flush_unrolls(
+        cols, f, b, k, block):
+    """The flush's text and stack grow with feature groups x depth x
+    block; past ``_FLUSH_ONEHOT_ROWS`` every streamed row pays (PERF.md,
+    PR 30). The cells that ran clean keep the block they had."""
+    from lightgbm_tpu.engines.registry import clamp_fused_block
+    from lightgbm_tpu.ops.fused_split import (_FLUSH_ONEHOT_ROWS,
+                                              _hist_packing)
+    assert fused_block_cap(cols, k, num_features=f, num_bins=b) == block
+    assert clamp_fused_block(512, cols, k, "lane", b, f) == block
+    # an override for experiments is held to the same bound
+    assert clamp_fused_block(512, cols, k, "lane", b, f,
+                             env_override="512") == block
+    _, f_pad, group = _hist_packing(f, b)
+    assert (block == 32
+            or -(-f_pad // group) * k * block <= _FLUSH_ONEHOT_ROWS)
+    # without the features the cap is the record's alone, as it was
+    assert fused_block_cap(cols, k) >= block
+
+
 # ------------------------------------------------------ steady-state guard
 def test_steady_state_guard_with_mbatch_set():
     """5 post-warmup compact iterations with tpu_hist_mbatch=4: zero

@@ -294,6 +294,13 @@ def test_taxonomy_trace_metrics_acceptance(tmp_path, monkeypatch):
     np.testing.assert_allclose(np.asarray(out),
                                bst.predict(X[:16]), rtol=0, atol=0)
 
+    # the ranking objective's own spans: `rank_layout` at init and, on
+    # the compact grower, `rank_grads` around its gradient program
+    lgb.train({"objective": "lambdarank", "num_leaves": 7, "verbosity": -1,
+               "tpu_grower": "compact", "min_data_in_leaf": 5},
+              lgb.Dataset(X[:200], label=(y[:200] > 0).astype(np.float32),
+                          group=[50, 30, 120]), num_boost_round=1)
+
     # `import` is stamped once, as the package loads: before the reset
     missing = set(spans.SPAN_TAXONOMY) - spans.seen_spans() - {"import"}
     assert not missing, f"taxonomy spans never entered: {missing}"

@@ -756,3 +756,30 @@ def test_real_timed_sweep_and_cli(tmp_path):
     (block,) = list(data["entries"].values())
     assert any("ms" in r for r in block["table"])
     assert block["winner"]["entry"] == "xla_lane"
+
+
+# ------------------------------------------------- the flush's bound (PR 30)
+@pytest.mark.parametrize("features, bins, cols, cfg, env, want", [
+    (28, 255, 128, {}, "", (384, 2)),         # higgs keeps its block and depth
+    (28, 63, 128, {}, "", (384, 2)),
+    (137, 255, 256, {}, "", (192, 2)),        # 69 groups: at the bound
+    # 110 groups: depth 2 would be cut to block 96; the same 192 rows a
+    # flush at depth 1 are the same sums through a block twice as large
+    (220, 255, 256, {}, "", (192, 1)),
+    # a depth somebody named is kept, and the block pays for it
+    (220, 255, 256, {"tpu_hist_mbatch": 2}, "", (96, 2)),
+    (220, 255, 256, {"tpu_hist_mbatch": 4}, "", (32, 4)),
+    # so is a block from the environment, inside the same bound
+    (220, 255, 256, {}, "96", (96, 2)),
+    (220, 255, 256, {}, "384", (96, 2)),
+    (28, 255, 128, {"tpu_hist_mbatch": 8}, "", (224, 8)),
+])
+def test_fit_fused_flush_trades_the_default_depth_for_the_block(
+        features, bins, cols, cfg, env, want):
+    res = registry.resolve(
+        dict({"tpu_autotune": "off"}, **cfg), platform="tpu",
+        shape=registry.DatasetShape(7_000_000, features, bins, "serial"))
+    assert res.entry_id == "fused_lane" and res.fused_block
+    got = registry.fit_fused_flush(res, cols, bins + 1, features,
+                                   env_override=env)
+    assert got == want

@@ -153,9 +153,9 @@ def _fused_compile(one_chip, f, b, rows=1 << 20, packed4=False, **kw):
     layout = RowLayout(num_features=f, num_extra=HIGGS_EXTRAS,
                        packed4=packed4)
     c = layout.num_cols
-    bs = min(512, fused_block_cap(c, kw.get("mbatch", 8),
-                                  kw.get("quant", False),
-                                  kw.get("hist_layout", "lane")))
+    bs = kw.pop("block_size", None) or min(512, fused_block_cap(
+        c, kw.get("mbatch", 8), kw.get("quant", False),
+        kw.get("hist_layout", "lane")))
     n = rows + bs + 32
 
     def arr(shape, dtype):
@@ -179,6 +179,23 @@ def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
     assert res.entry_id == "fused_lane"
     assert res.sources["hist_mbatch"] == "fused"
     _fused_compile(one_chip, f=28, b=256, mbatch=res.hist_mbatch)
+
+
+def test_fused_kernel_compiles_for_v5e_at_220_features(one_chip,
+                                                       no_persistent_cache):
+    """The ranking cell's kernel (`istella_train`): 220 features in
+    256-byte records at 256 bins. At the fused default depth 2 and the
+    record's own block of 192 its 110 unrolled feature groups wanted 23 MB
+    of scoped VMEM where the compiler gives 16 MB, and PR 30's parent was
+    refused at its first step; what the registry fits for that many
+    groups (depth 1 at the same 192 rows a flush) compiles."""
+    layout = RowLayout(num_features=220, num_extra=HIGGS_EXTRAS)
+    res = registry.resolve(
+        {"tpu_autotune": "off"}, platform="tpu",
+        shape=registry.DatasetShape(7_325_625, 220, 255, "serial"))
+    bs, depth = registry.fit_fused_flush(res, layout.num_cols, 256, 220)
+    assert (bs, depth) == (192, 1)
+    _fused_compile(one_chip, f=220, b=256, mbatch=depth, block_size=bs)
 
 
 @pytest.mark.slow
