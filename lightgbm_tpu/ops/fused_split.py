@@ -17,7 +17,27 @@ whole walk:
   * the parent leaf's contiguous segment streams HBM -> VMEM once, with
     double-buffered DMA;
   * each block stably partitions via ONE dest-indexed one-hot MXU matmul
-    (dest = carry_offset + rank, so the carry append costs nothing extra);
+    into the two streams' carries. A carry is a RING of one block,
+    [bs, C], whose rows at and above its count are zero; a selected row
+    lands at dest = (count + rank) mod bs, the right stream's ring bs
+    slots below the left's, so the one-hot is [2 bs, bs] and the carry
+    append costs nothing extra. A block that does not fill a ring adds
+    into it. One that does flushes `where(slot < count, carry, block's
+    rows)`, the carry's rows and then the block's first bs - count, and
+    the rows that wrapped, already in place below the old count, are the
+    new carry: nothing shifts. (Through PR 30 a carry was 2 bs tall and
+    shifted down a block on every flush, and the one-hot was [4 bs, bs].)
+  * what a row decides (its routing bin, its side, its rank, its slot)
+    is computed with the block's rows along LANES, [32, bs]: the matmul
+    that picks the routing column out of the block also transposes it,
+    the ranks are a matmul against a triangular constant, and `dest`
+    comes out as the row vector the one-hot's compare broadcasts. Held
+    one row a sublane, as a lane reduction leaves a column, each of the
+    chain's forty operations cost bs / 8 registers where it now costs
+    bs / 128; with the [4 bs, bs] permutation that was the walk's time:
+    the loop body is straight-line code the chip runs a bundle a cycle,
+    2,780 bundles a block of 384 rows before PR 31 (4.85 ns a parent
+    row) and 1,295 since (PERF.md section 6, PR 31).
   * left rows flush in place into the PARENT's residency array (the left
     write cursor can never overtake the read cursor); right rows flush to
     the OTHER array at the same global offsets (dual residency);
@@ -48,10 +68,11 @@ neighbour segments resident there survive. All DMA offsets in the kernel are
 of the form `32*t + k*BS`, which the compiler can prove aligned.
 
 Numerics: row bytes move through the permutation matmul as (byte - 128) int8
-values at 2x the bf16 MXU rate (one-hot contraction, i32 accumulate — exact;
-a spare padding lane carries the per-slot receive indicator so the offset is
-undone exactly at flush). With no spare lane the kernel falls back to bf16
-(0..255 exact, f32 accumulate). Histogram channels use the same hi/lo-bf16
+values at 2x the bf16 MXU rate (one-hot contraction, i32 accumulate — exact).
+byte <-> byte - 128 is the top bit flipped, done on the packed bytes on the
+way in and on the way out; a carry slot that no row has reached holds 0 and
+would flush as 128, and every such slot is one a blend replaces or that
+lands in dead bytes. Histogram channels use the same hi/lo-bf16
 split as ops/pallas_histogram.py: counts exact, grad/hess ~2^-17 relative.
 
 Batched-M histogram pipeline (round 6; measured on the chip in PR 29: no
@@ -100,9 +121,13 @@ from .compact import RowLayout
 _A = 32  # row alignment every DMA offset is provably divisible by
 
 # ---- scoped-VMEM accounting (shared with boosting/gbdt.py and tpulint) ----
-# The kernel's fixed streaming buffers (inbuf/carries/stages/aux) scale with
-# block_size * num_cols; 49152 is the empirical bs*C product the round-3
-# kernel tolerated on v5e. The batched-M pending ring (hist_flush) ADDS
+# The kernel's fixed streaming buffers scale with block_size * num_cols:
+# the double-buffered input block, the two streams' double-buffered stages
+# and the read-modify-write block as bytes (7 bs*C, 8 in the copy-back
+# variant), and the two ring carries as 32-bit words (8 bs*C; 16 before
+# PR 31). 49152 is the empirical bs*C product the round-3 kernel tolerated
+# on v5e with the taller carries; the cap stays where the cells' blocks
+# were measured. The batched-M pending ring (hist_flush) ADDS
 # mbatch-proportional residency: the staged bin blocks, the transposed
 # channel slots, and the per-feature-group one-hot + block-diagonal
 # transients of the ONE big contraction — so the block size must shrink as
@@ -236,7 +261,7 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                   hist_ref, sem_in, sem_l, sem_r, sem_aux, inbuf, lcarry,
                   rcarry, lstage, rstage, auxbuf, pendbuf, pendch, smem, *,
                   layout: RowLayout, num_bins: int, bs: int,
-                  bitset_words: int, use_int8: bool,
+                  bitset_words: int,
                   interpret: bool, dual: bool,
                   hist_debug: str = "", quant: bool = False,
                   mbatch: int = 1, hist_layout: str = "lane"):
@@ -308,25 +333,31 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     rcarry[:, :] = jnp.zeros_like(rcarry)
     auxbuf[...] = jnp.zeros_like(auxbuf)
 
-    iota = lax.broadcasted_iota(i32, (bs, 1), 0)[:, 0]
-    lane = lax.broadcasted_iota(i32, (bs, C), 1)
+    slot_i = lax.broadcasted_iota(i32, (bs, 1), 0)
+    iota = slot_i[:, 0]
     io2 = lax.broadcasted_iota(i32, (bs, bs), 0)
     jo2 = lax.broadcasted_iota(i32, (bs, bs), 1)
-    # strict lower triangular: ranks via MXU (int8 runs at 2x bf16 rate)
-    lt = (io2 > jo2).astype(jnp.int8 if use_int8 else jnp.bfloat16)
-    iota4 = lax.broadcasted_iota(i32, (4 * bs, bs), 0)
+    # strict upper triangular: ranks via MXU (int8 runs at 2x bf16 rate)
+    ut = (io2 < jo2).astype(jnp.int8)
+    # rows of a [ROWS, bs] operand that holds one value a block row, along
+    # lanes: a whole int8 tile
+    ROWS = 32
+    lane_t = lax.broadcasted_iota(i32, (ROWS, bs), 1)
+    row_t = lax.broadcasted_iota(i32, (ROWS, bs), 0)
+    iota2 = lax.broadcasted_iota(i32, (2 * bs, bs), 0)
 
-    def carry_block_i32(c):
-        """First BS carry rows as exact [BS, C] i32 byte values.
+    def flip_offset(bytes8, out_t):
+        """byte <-> (byte - 128) on [BS, C] packed bytes: the top bit
+        flipped, whichever way."""
+        return pltpu.bitcast(
+            pltpu.bitcast(bytes8, i32) ^ jnp.int32(-0x7F7F7F80), out_t)
 
-        int8 mode stores carries in offset form (byte - 128, with lane C-1
-        carrying the receive indicator from the permutation matmul); the
-        +128 correction applies only to filled slots and the indicator lane
-        is zeroed so flushed bytes match the bf16/XLA paths bit-for-bit."""
-        if use_int8:
-            fixed = c[:bs] + 128 * c[:bs, C - 1:C]
-            return jnp.where(lane == C - 1, 0, fixed)
-        return c[:bs].astype(i32)
+    def carry_block_u8(c):
+        """A [BS, C] block in carry form (byte - 128, i32) as its bytes.
+        A slot that received no row reads 128 and not 0: every such slot is
+        one a caller blends away or that lands in dead bytes (the right
+        stream's psi head slots, a tail's rows past its count)."""
+        return flip_offset(c.astype(jnp.int8), jnp.uint8)
 
     def start_read(i, slot):
         """Issue the parent-segment block read from its residency array."""
@@ -421,17 +452,18 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         of that feature's bin column against a [BS, BS_] lane iota, with
         the per-feature results concatenated group-wide so each group is
         contracted in ONE MXU matmul (grouping bounds the one-hot operand
-        near 512 lanes, see _hist_packing). A jnp.repeat-based batched
-        lane spread was tried instead of the per-feature compare loop and
+        near 512 lanes, see _hist_packing). The channel operand has 8
+        rows, so every 128 x 128 tile of one-hot is loaded into the MXU
+        to stream eight rows through it: F x B / 16,384 tile loads a row
+        are what a histogram row costs (0.44 on higgs, 3.44 at 220
+        features; 9.1 and 58.8 ns on the chip). A jnp.repeat-based
+        batched lane spread in place of the per-feature compare loop
         lowers to far slower relayouts on this Mosaic toolchain (0.54 vs
         1.07 it/s on the 10.5M higgs bench)."""
         bins = rows_u8.astype(i32)[:, :layout.feat_cols]
         # tightly packed: each feature spans B lanes (not 128-padded), so
         # B <= 64 fits 2+ features per lane tile; group widths and offsets
         # stay 128-aligned via the align unit from _hist_packing
-        # (a jnp.repeat-based batched lane spread was tried and lowers to
-        # far slower relayouts on this Mosaic toolchain: 0.54 vs 1.07 it/s
-        # on the 10.5M higgs bench)
         _, _, w = _hist_packing(F, B)   # group width (features)
         iota_b = lax.broadcasted_iota(i32, (bs, BS_), 1)
         zero_col = jnp.full((bs, 1), -1, i32)   # matches no bin lane
@@ -624,8 +656,13 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
             hist_flush(pending)
             smem[_PEND] = pushes - pending
 
-    def stage_flush(stream, data_u8, hbm_base, do_hist, hist_mask):
-        """Write one full block via the stream's staging ring; maybe hist."""
+    def stage_flush(stream, data_u8, hbm_base, do_hist, h0, n_valid=bs,
+                    head_rows=None):
+        """Write one full block via the stream's staging ring; maybe hist
+        (of the block's rows [h0, n_valid)). ``head_rows`` (dual residency,
+        the right stream's first block): so many leading rows belong to a
+        segment that may be live in the destination array, and are read
+        from there and written back as they were."""
         stage, sem, cslot = ((lstage, sem_l, _LF) if stream == 0
                              else (rstage, sem_r, _RF))
         # left stream writes the parent's residency array, right the other
@@ -640,6 +677,13 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                 sem.at[slot]).wait()
 
         stage[slot] = data_u8
+        if head_rows is not None:
+            @pl.when(head_rows > 0)
+            def _():
+                rmw_read(hbm_base)
+                stage[slot] = jnp.where(
+                    slot_i < head_rows, auxbuf[:, :].astype(i32),
+                    stage[slot].astype(i32)).astype(jnp.uint8)
         hbm_base = clamp_base(hbm_base)
 
         @pl.when(to_work)
@@ -656,7 +700,8 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
 
         @pl.when(do_hist)
         def _():
-            hist_accum(stage[slot], hist_mask)
+            hist_accum(stage[slot], jnp.logical_and(
+                iota >= h0, iota < n_valid).astype(jnp.float32))
         smem[cslot] = cnt + 1
 
     def drain(stream):
@@ -685,25 +730,37 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
 
         wait_read(slot)
         blk_u8 = inbuf[slot]
-        blk = blk_u8.astype(i32)
-        g_idx = base + i * bs + iota
-        in_seg = jnp.logical_and(g_idx >= start, g_idx < start + count)
 
         @pl.when(mode == 1)
         def _():
+            g_idx = base + i * bs + iota
+            in_seg = jnp.logical_and(g_idx >= start, g_idx < start + count)
             hist_accum(blk_u8, in_seg.astype(jnp.float32))
 
         @pl.when(mode == 0)
         def _():
-            head = g_idx < start
+            # bytes ride the MXU as (b - 128) int8, made on the packed
+            # bytes as they arrive
+            blk8 = flip_offset(blk_u8, jnp.int8)
+            # Everything a row decides (its bin, side, rank and slot) is
+            # held with the block's rows along LANES, [ROWS, bs]: bs / 128
+            # registers an operation. One row a sublane, as a lane
+            # reduction leaves it, is bs / 8 registers an operation, and
+            # the routing chain is some forty operations long. So the
+            # routing column is transposed by the matmul that picks it:
+            fcol = (feature >> 1) if packed4 else feature
+            pick = (lax.broadcasted_iota(i32, (ROWS, C), 1)
+                    == fcol).astype(jnp.int8)
+            col = lax.dot_general(
+                pick, blk8, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=i32) + 128               # [R, BS]
             if packed4:
-                # two features per byte: select the byte column, then the
-                # nibble (the & 0xF mask strips the neighbour feature)
-                byte = jnp.sum(
-                    jnp.where(lane == (feature >> 1), blk, 0), axis=1)
-                col = (byte >> ((feature & 1) * 4)) & 0xF
-            else:
-                col = jnp.sum(jnp.where(lane == feature, blk, 0), axis=1)
+                # two features per byte: the nibble of the byte column
+                # (the & 0xF mask strips the neighbour feature)
+                col = (col >> ((feature & 1) * 4)) & 0xF
+            g_idx = base + i * bs + lane_t
+            in_seg = jnp.logical_and(g_idx >= start, g_idx < start + count)
+            head = g_idx < start
             # routing predicate — mirrors ops/split.py go_left_pred
             gl_num = jnp.logical_or(
                 col <= bin_,
@@ -719,87 +776,78 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
             sel_l = jnp.logical_or(jnp.logical_and(gl, in_seg), head)
             sel_r = jnp.logical_and(jnp.logical_not(gl), in_seg)
 
-            lane2 = lax.broadcasted_iota(i32, (bs, 2), 1)
-            sel2i = jnp.where(lane2 == 0,
-                              sel_l.astype(i32)[:, None],
-                              sel_r.astype(i32)[:, None])
-            if use_int8:
-                ranks = lax.dot_general(
-                    lt, sel2i.astype(jnp.int8),
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=i32)                 # [BS, 2]
-            else:
-                ranks = lax.dot_general(
-                    lt, sel2i.astype(jnp.float32).astype(jnp.bfloat16),
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32).astype(i32)
-            rank_l = ranks[:, 0]
-            rank_r = ranks[:, 1]
+            # ranks via the MXU: row 0 counts the lefts ahead of each row,
+            # row 1 the rights
+            sel2 = jnp.where(row_t == 0, sel_l.astype(i32),
+                             jnp.where(row_t == 1, sel_r.astype(i32), 0))
+            ranks = lax.dot_general(
+                sel2.astype(jnp.int8), ut,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=i32)                     # [R, BS]
+            sel_l, sel_r = sel_l[0:1], sel_r[0:1]
+            rank_l = ranks[0:1]
+            rank_r = ranks[1:2]
             nl_b = jnp.sum(sel_l.astype(i32))
             nr_b = jnp.sum(sel_r.astype(i32))
 
+            # each stream's carry is a ring of bs slots: a selected row
+            # lands at (cnt + rank) mod bs, the right stream's ring bs
+            # slots below the left's in the one permutation
             lcnt = smem[_LCNT]
             rcnt = smem[_RCNT]
+            dl = lcnt + rank_l
+            dr = rcnt + rank_r
             dest = jnp.where(
-                sel_l, lcnt + rank_l,
-                jnp.where(sel_r, 2 * bs + rcnt + rank_r, 4 * bs))
-            oh = (iota4 == dest[None, :])                       # [4BS, BS] i1
-            if use_int8:
-                # bytes ride the MXU as (b - 128) int8; lane C-1 is repurposed
-                # as a constant 1 so each dest slot also receives a "filled"
-                # indicator, letting carry_block_i32 undo the offset exactly
-                blk8 = jnp.where(lane == C - 1, 1, blk - 128).astype(jnp.int8)
-                comp = lax.dot_general(
-                    oh.astype(jnp.int8), blk8,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=i32)                 # [4BS, C]
-            else:
-                blk_bf = blk.astype(jnp.float32).astype(jnp.bfloat16)
-                comp = lax.dot_general(
-                    oh.astype(jnp.bfloat16), blk_bf,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            lcarry[:, :] = lcarry[:, :] + comp[:2 * bs]
-            rcarry[:, :] = rcarry[:, :] + comp[2 * bs:]
-
+                sel_l, jnp.where(dl >= bs, dl - bs, dl),
+                jnp.where(sel_r, jnp.where(dr >= bs, dr, dr + bs), 2 * bs))
+            oh = (iota2 == dest)                                # [2BS, BS] i1
+            comp = lax.dot_general(
+                oh.astype(jnp.int8), blk8,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=i32)                     # [2BS, C]
+            comp_l = comp[:bs]
+            comp_r = comp[bs:]
             new_l = lcnt + nl_b
             new_r = rcnt + nr_b
+
+            def ring_flush(carry, comp_s, cnt, new):
+                """The block a full ring flushes, in carry form: the carry's
+                rows below ``cnt``, then the block's first bs - cnt. The
+                block's later rows wrapped to slots below ``cnt``, already
+                in place: they are the new carry."""
+                full = jnp.where(slot_i < cnt, carry[:, :], comp_s)
+                carry[:, :] = jnp.where(slot_i < new - bs, comp_s,
+                                        jnp.zeros_like(comp_s))
+                return full
+
+            @pl.when(new_l < bs)
+            def _():
+                # the slots written were zero (rows at and above cnt are)
+                lcarry[:, :] = lcarry[:, :] + comp_l
 
             @pl.when(new_l >= bs)
             def _():
                 lf = smem[_LF]
                 h0 = jnp.where(lf == 0, phi, 0)
-                stage_flush(
-                    0, carry_block_i32(lcarry).astype(jnp.uint8),
-                    base + lf * bs, smaller_left == 1,
-                    (iota >= h0).astype(jnp.float32))
-                lcarry[:, :] = jnp.concatenate(
-                    [lcarry[bs:], jnp.zeros_like(lcarry[:bs])], axis=0)
+                full = ring_flush(lcarry, comp_l, lcnt, new_l)
+                stage_flush(0, carry_block_u8(full), base + lf * bs,
+                            smaller_left == 1, h0)
             smem[_LCNT] = new_l - bs * (new_l >= bs).astype(i32)
+
+            @pl.when(new_r < bs)
+            def _():
+                rcarry[:, :] = rcarry[:, :] + comp_r
 
             @pl.when(new_r >= bs)
             def _():
                 rf = smem[_RF]
                 h0 = jnp.where(rf == 0, psi, 0)
-                if dual:
-                    @pl.when(rf == 0)
-                    def _():
-                        # RMW blend: the psi pre-rows belong to a segment
-                        # that may be live in the destination array
-                        rmw_read(rbase)
-                    keep = jnp.logical_and(rf == 0, iota < psi)
-                    data = jnp.where(keep[:, None], auxbuf[:, :].astype(i32),
-                                     carry_block_i32(rcarry))
-                else:
-                    # copy-back mode: the psi head slots land in dead
-                    # scratch bytes; no blend needed
-                    data = carry_block_i32(rcarry)
-                stage_flush(
-                    1, data.astype(jnp.uint8),
-                    rbase + rf * bs, smaller_left == 0,
-                    (iota >= h0).astype(jnp.float32))
-                rcarry[:, :] = jnp.concatenate(
-                    [rcarry[bs:], jnp.zeros_like(rcarry[:bs])], axis=0)
+                full = ring_flush(rcarry, comp_r, rcnt, new_r)
+                # the psi pre-rows: an RMW blend under dual residency; in
+                # copy-back mode they land in dead scratch bytes
+                stage_flush(1, carry_block_u8(full), rbase + rf * bs,
+                            smaller_left == 0, h0,
+                            head_rows=h0 if dual else None)
             smem[_RCNT] = new_r - bs * (new_r >= bs).astype(i32)
         return 0
 
@@ -835,31 +883,28 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                         inbuf.at[0], sem_in.at[0]).start()
             wait_read(0)
             blend = jnp.where(
-                (iota < lcnt)[:, None], carry_block_i32(lcarry),
+                slot_i < lcnt, carry_block_u8(lcarry[:, :]).astype(i32),
                 inbuf[0].astype(i32)).astype(jnp.uint8)
             h0 = jnp.where(lf == 0, phi, 0)
-            mask = jnp.logical_and(iota >= h0, iota < lcnt)
-            stage_flush(0, blend, base + lf * bs, smaller_left == 1,
-                        mask.astype(jnp.float32))
+            stage_flush(0, blend, base + lf * bs, smaller_left == 1, h0,
+                        lcnt)
 
         @pl.when(rcnt > 0)
         def _():
             rf = smem[_RF]
             h0 = jnp.where(rf == 0, psi, 0)
-            valid = jnp.logical_and(iota >= h0, iota < rcnt)
+            data = carry_block_u8(rcarry[:, :])
             if dual:
                 # RMW blend against the destination array: the psi head rows
                 # (rf == 0) and everything beyond rcnt may be live neighbours
                 rmw_read(rbase + rf * bs)
-                data = jnp.where(valid[:, None], carry_block_i32(rcarry),
-                                 auxbuf[:, :].astype(i32))
-            else:
-                # copy-back mode: full-block write, overrun lands in dead
-                # scratch bytes
-                data = carry_block_i32(rcarry)
-            stage_flush(1, data.astype(jnp.uint8),
-                        rbase + rf * bs, smaller_left == 0,
-                        valid.astype(jnp.float32))
+                valid = jnp.logical_and(slot_i >= h0, slot_i < rcnt)
+                data = jnp.where(valid, data.astype(i32),
+                                 auxbuf[:, :].astype(i32)).astype(jnp.uint8)
+            # (copy-back mode: full-block write, overrun lands in dead
+            # scratch bytes)
+            stage_flush(1, data, rbase + rf * bs, smaller_left == 0, h0,
+                        rcnt)
 
         drain(0)
         drain(1)
@@ -1061,14 +1106,11 @@ def fused_split(
     if hist_layout == "sublane":
         hist_debug = ""     # bisect probes assume the lane accumulator
     mbatch = max(1, min(int(mbatch), 16))   # 8*mbatch <= 128 MXU rows
-    # int8 MXU path needs one free padding lane for the receive indicator
-    use_int8 = layout.num_real_cols < C
-    carry_t = jnp.int32 if use_int8 else jnp.float32
     hist_t = jnp.int32 if quant else jnp.float32
     ch_t = jnp.int8 if quant else jnp.bfloat16
     kernel = functools.partial(
         _fused_kernel, layout=layout, num_bins=B, bs=bs, bitset_words=W,
-        use_int8=use_int8, interpret=interpret, dual=dual,
+        interpret=interpret, dual=dual,
         hist_debug=hist_debug, quant=quant, mbatch=mbatch,
         hist_layout=hist_layout)
 
@@ -1091,8 +1133,8 @@ def fused_split(
                 (pltpu.SemaphoreType.DMA if dual
                  else pltpu.SemaphoreType.DMA((2,))),       # sem_aux
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # inbuf
-                pltpu.VMEM((2 * bs, C), carry_t),   # lcarry
-                pltpu.VMEM((2 * bs, C), carry_t),   # rcarry
+                pltpu.VMEM((bs, C), jnp.int32),     # lcarry
+                pltpu.VMEM((bs, C), jnp.int32),     # rcarry
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # lstage
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # rstage
                 (pltpu.VMEM((bs, C), jnp.uint8) if dual

@@ -23,9 +23,13 @@ from lightgbm_tpu.ops.fused_split import fused_split
 i32 = jnp.int32
 
 
-def _make_work(rng, n, f, b, extra=1):
+def _make_work(rng, n, f, b, extra=1, route=None):
+    """``route``: (feature, fn(column) -> None) rewrites one feature's bins
+    in place before the rows are packed."""
     layout = RowLayout(num_features=f, num_extra=extra)
     binned = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    if route is not None:
+        route[1](binned[:, route[0]])
     g = rng.randn(n).astype(np.float32)
     h = np.abs(rng.randn(n)).astype(np.float32)
     cnt = (rng.rand(n) > 0.25).astype(np.float32)
@@ -80,18 +84,89 @@ def _run_ref(work0, b, layout, start, count, n_left, feat, bin_,
     return np.asarray(wr), np.asarray(href)
 
 
+FEAT = 2                # the routing feature of the named cases
+LEFT, RIGHT = 0, 255    # bins on either side of a 256-bin case's threshold
+
+
+def _ring_case(rng, case, start, count, n=3000):
+    """(layout, work0, bins, threshold bin, fused block, n_left) of a named
+    partition case.
+
+    Each stream's carry is a ring of one block: a row lands at
+    (cnt + rank) mod block, and a block that fills the ring flushes the
+    carry's rows, then its own first rows, and keeps the rows that wrapped.
+    The cases place blocks of the walk (block k holds the rows from
+    32 * (start // 32) + k * block on) where the ring's arithmetic has an
+    edge. ``psi`` is the right ring's first count, (start + n_left) % 32."""
+    f, b, bin_, bs, col = 5, 256, 100, 128, None
+
+    def block0_right(c, psi):
+        """Block 0's rows all go right, into a ring that starts at psi
+        (spare rows past block 3 trim n_left to it)."""
+        c[start:start + bs] = RIGHT
+        spare = start + 4 * bs + np.arange(31)
+        c[spare] = RIGHT
+        n_left = int((c[start:start + count] <= bin_).sum())
+        c[spare[:(psi - n_left - start) % 32]] = LEFT
+
+    if case == "median_bs32":
+        # half a block to each stream: one of the two rings wraps in
+        # nearly every block, at a block of the alignment's own size
+        bin_, bs = 127, 32
+    elif case == "median_bs128":
+        bin_ = 127
+    elif case == "no_spare_lane":
+        # 112 + 12 + 4 bytes fill the 128 lanes: the last lane carries a
+        # row's own byte through the permutation and the carries
+        f, b, bin_ = 112, 64, 31
+    elif case == "all_left_blocks":
+        # block 0 fills an empty ring exactly (n == bs at cnt == 0, no
+        # wrap); block 3 sends a whole block through a ring part full
+        def col(c):
+            c[start:start + bs] = LEFT
+            c[start + 3 * bs:start + 4 * bs] = LEFT
+    elif case == "all_right_blocks":
+        # the same for the right ring, empty at first (psi == 0)
+        def col(c):
+            c[start + 3 * bs:start + 4 * bs] = RIGHT
+            block0_right(c, 0)
+    elif case == "head_wrap_block0":
+        # phi > 0 head rows ride the left ring while block 0's rows all go
+        # right into a ring that starts at psi > 0: it wraps in block 0
+        def col(c):
+            block0_right(c, 17)
+    else:
+        assert case == "mixed"
+    layout, work0 = _make_work(rng, n, f, b, route=col and (FEAT, col))
+    n_left = int((work0[start:start + count, FEAT] <= bin_).sum())
+    phi, psi = start % 32, (start + n_left) % 32
+    if case == "no_spare_lane":
+        assert layout.num_real_cols == layout.num_cols
+    if case == "all_right_blocks":
+        assert phi == psi == 0
+    if case == "head_wrap_block0":
+        assert phi > 0 and psi + (bs - phi) > bs
+    return layout, work0, b, bin_, bs, n_left
+
+
+RING_CASES = [(0, 3000, "all_left_blocks"), (0, 3000, "all_right_blocks"),
+              (0, 3000, "median_bs32"), (37, 2219, "median_bs32"),
+              (37, 2219, "median_bs128"), (37, 2219, "head_wrap_block0"),
+              (0, 3000, "no_spare_lane"), (37, 2219, "no_spare_lane")]
+
+
 class TestFusedSplit:
     @pytest.mark.parametrize("dual", [True, False])
-    @pytest.mark.parametrize("start,count", [(0, 3000), (37, 2219), (96, 128),
-                                             (500, 1), (200, 0)])
-    def test_partition_and_hist_parity(self, rng, start, count, dual):
-        n, f, b = 3000, 5, 256
-        layout, work0 = _make_work(rng, n, f, b)
-        feat, bin_ = 2, 100
-        sub = work0[start:start + count, feat]
-        n_left = int((sub <= bin_).sum())
+    @pytest.mark.parametrize(
+        "start,count,case",
+        [(0, 3000, "mixed"), (37, 2219, "mixed"), (96, 128, "mixed"),
+         (500, 1, "mixed"), (200, 0, "mixed")] + RING_CASES)
+    def test_partition_and_hist_parity(self, rng, start, count, case, dual):
+        n, feat = 3000, FEAT
+        layout, work0, b, bin_, bs, n_left = _ring_case(rng, case, start,
+                                                        count)
         wf, sf, hf = _run_fused(work0, layout, b, 0, start, count, n_left,
-                                feat, bin_, dual=dual)
+                                feat, bin_, dual=dual, bs=bs)
         wr, href = _run_ref(work0, b, layout, start, count, n_left, feat,
                             bin_)
         wm = _merged(wf, sf, start, count, n_left, dual)
@@ -145,20 +220,27 @@ class TestFusedSplit:
         np.testing.assert_allclose(hf[:, :, :2], href[:, :, :2], atol=2e-2)
 
     @pytest.mark.parametrize("dual", [True, False])
-    def test_untouched_outside_segment(self, rng, dual):
-        n, f, b = 2000, 4, 128
-        layout, work0 = _make_work(rng, n, f, b)
-        start, count = 600, 700
-        sub = work0[start:start + count, 0]
-        n_left = int((sub <= 40).sum())
+    @pytest.mark.parametrize("case", ["mixed", "median_bs32",
+                                      "all_right_blocks", "head_wrap_block0",
+                                      "no_spare_lane"])
+    def test_untouched_outside_segment(self, rng, case, dual):
+        n, feat = 2000, FEAT
+        start, count = (608, 700) if case == "all_right_blocks" else (613, 700)
+        layout, work0, b, bin_, bs, n_left = _ring_case(rng, case, start,
+                                                        count, n=n)
         wf, sf, _ = _run_fused(work0, layout, b, 0, start, count, n_left,
-                               0, 40, dual=dual)
-        wf = np.asarray(wf)
+                               feat, bin_, dual=dual, bs=bs)
+        wf, sf = np.asarray(wf), np.asarray(sf)
         np.testing.assert_array_equal(wf[:start], work0[:start])
         np.testing.assert_array_equal(wf[start + count:n],
                                       work0[start + count:n])
+        if dual:
+            # the other array's live neighbours survive the right ring's
+            # read-modify-write blends (it starts as zeros here)
+            assert not sf[:start + n_left].any()
+            assert not sf[start + count:].any()
         # the left child stays in place in the parent's array
         np.testing.assert_array_equal(wf[start:start + n_left],
                                       _run_ref(work0, b, layout, start,
-                                               count, n_left, 0, 40)[0]
+                                               count, n_left, feat, bin_)[0]
                                       [start:start + n_left])

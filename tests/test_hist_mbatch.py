@@ -137,12 +137,7 @@ def test_fused_split_mode_parity_with_mbatch():
     np.testing.assert_array_equal(outs[1][2][:, :, 2:], outs[8][2][:, :, 2:])
 
 
-def test_compact_grower_tree_identical_at_fused_default_and_depth_8():
-    """Grower level: a compact-grower forest grown at the depth a fused
-    entry now resolves by default and at an explicit depth 8 (the
-    standalone engines' default, which fused entries used to inherit)
-    has identical splits and leaf counts — the depth regroups an f32
-    accumulation and nothing else."""
+def _forest_data():
     rng = np.random.RandomState(23)
     n, f = 1500, 8
     X = rng.randn(n, f).astype(np.float32)
@@ -154,6 +149,36 @@ def test_compact_grower_tree_identical_at_fused_default_and_depth_8():
         "tpu_grower": "compact", "tpu_autotune": "off",
         "tpu_fused_interpret": True, "tpu_fused_block": 128,
     }
+    return X, y, base
+
+
+def _walk(node, out):
+    if "leaf_index" in node:
+        out.append(("leaf", node["leaf_index"], node["leaf_count"]))
+        return
+    out.append((node["split_feature"], node["threshold"],
+                node["decision_type"], node["internal_count"]))
+    _walk(node["left_child"], out)
+    _walk(node["right_child"], out)
+
+
+def _assert_same_forest(trees_a, trees_b, n_trees):
+    assert len(trees_a) == len(trees_b) == n_trees
+    for a, b in zip(trees_a, trees_b):
+        assert a["num_leaves"] == b["num_leaves"] > 1
+        wa, wb = [], []
+        _walk(a["tree_structure"], wa)
+        _walk(b["tree_structure"], wb)
+        assert wa == wb
+
+
+def test_compact_grower_tree_identical_at_fused_default_and_depth_8():
+    """Grower level: a compact-grower forest grown at the depth a fused
+    entry now resolves by default and at an explicit depth 8 (the
+    standalone engines' default, which fused entries used to inherit)
+    has identical splits and leaf counts — the depth regroups an f32
+    accumulation and nothing else."""
+    X, y, base = _forest_data()
     trees = {}
     for name, extra in (("default", {}), ("k8", {"tpu_hist_mbatch": 8})):
         params = dict(base, **extra)
@@ -164,23 +189,29 @@ def test_compact_grower_tree_identical_at_fused_default_and_depth_8():
         assert gp.hist_mbatch == (8 if extra else registry.FUSED_MBATCH)
         trees[name] = bst.dump_model()["tree_info"]
     assert registry.FUSED_MBATCH != 8       # else this compares nothing
+    _assert_same_forest(trees["default"], trees["k8"], 3)
 
-    def walk(node, out):
-        if "leaf_index" in node:
-            out.append(("leaf", node["leaf_index"], node["leaf_count"]))
-            return
-        out.append((node["split_feature"], node["threshold"],
-                    node["decision_type"], node["internal_count"]))
-        walk(node["left_child"], out)
-        walk(node["right_child"], out)
 
-    assert len(trees["default"]) == len(trees["k8"]) == 3
-    for a, b in zip(trees["default"], trees["k8"]):
-        assert a["num_leaves"] == b["num_leaves"] > 1
-        wa, wb = [], []
-        walk(a["tree_structure"], wa)
-        walk(b["tree_structure"], wb)
-        assert wa == wb
+def test_compact_grower_forest_identical_with_fused_kernel_and_without():
+    """Grower level: the fused kernel's partition (a block's permutation
+    into ring carries) leaves every row where the XLA compact walk
+    (``tpu_fused=off``) leaves it: the same splits, the same leaf counts,
+    and after three trees of 14 splits each the same row order. The block
+    of 32 makes a ring wrap in nearly every block of every split."""
+    X, y, base = _forest_data()
+    trees, perms = {}, {}
+    for name, extra in (("fused", {"tpu_fused_block": 32}),
+                        ("xla", {"tpu_fused": "off"})):
+        params = dict(base, **extra)
+        bst = lgb.train(params, lgb.Dataset(X, label=y, params=params), 3,
+                        keep_training_booster=True)
+        gp = bst._gbdt.grower_params
+        assert gp.fused_block == (32 if name == "fused" else 0)
+        trees[name] = bst.dump_model()["tree_info"]
+        perms[name] = bst._gbdt._compact_perm()
+    _assert_same_forest(trees["fused"], trees["xla"], 3)
+    assert sorted(perms["fused"]) == list(range(len(y)))
+    np.testing.assert_array_equal(perms["fused"], perms["xla"])
 
 
 # --------------------------------------------------- standalone Mosaic
