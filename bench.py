@@ -325,45 +325,6 @@ def _record_shape(key, payload):
         json.dump(rec, fh, indent=1, sort_keys=True)
 
 
-def _arm_autotune(params):
-    """BENCH_AUTOTUNE=1: route the round through the startup microbench
-    autotuner (lightgbm_tpu/engines/autotune.py) with a bench-local
-    cache — the recorded row then reflects MEASURED per-shape engine
-    selection (tagged ``autotuned: true``) and the cache's sweep tables
-    land in BENCH_SHAPES.json["autotune"]. The cache persists across
-    rounds (the point: round 2 resolves with zero microbenches), so a
-    deliberate re-sweep is BENCH_AUTOTUNE_MODE=always. Returns the
-    cache path, or None when unarmed."""
-    if os.environ.get("BENCH_AUTOTUNE", "") != "1":
-        return None
-    cache = os.environ.get(
-        "BENCH_AUTOTUNE_CACHE",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     ".bench_autotune.json"))
-    params["tpu_autotune"] = os.environ.get("BENCH_AUTOTUNE_MODE",
-                                            "first_run")
-    params["tpu_autotune_cache"] = cache
-    return cache
-
-
-def _record_autotune_tables(cache):
-    """Copy the autotune cache's decision blocks (winner + full sweep
-    table per shape-class) into BENCH_SHAPES.json["autotune"]. Best
-    effort — never sinks a round that already measured throughput."""
-    if not cache:
-        return
-    try:
-        from lightgbm_tpu.engines import autotune as eng_autotune
-        tables = eng_autotune.sweep_tables(cache)
-        if tables:
-            _record_shape("autotune", tables)
-            sys.stderr.write(f"[bench] autotune decisions recorded for "
-                             f"{sorted(tables)}\n")
-    except Exception as err:  # noqa: BLE001 - accounting best-effort
-        sys.stderr.write(f"[bench] autotune table recording failed: "
-                         f"{err}\n")
-
-
 def run_hist_microbench(print_json=True):
     """BENCH_HIST_MICRO=1: the tentpole's speed claim, measured directly —
     the quantized int8 one-hot contraction (int8 x int8 -> int32,
@@ -444,7 +405,7 @@ def run_hist_microbench(print_json=True):
 
 def _run_layout_sweep(jax, dev, n, f, reps):
     """{u8, pack4} x {lane, sublane} x {f32, int8, int16-narrowed} at a
-    pack4-eligible shape (B=16) — the autotuner's data (ROADMAP item 5).
+    pack4-eligible shape (B=16).
 
     Every cell records rows/s plus its speedup vs the u8-lane-f32 cell of
     the SAME shape, so "which engine wins where" is a table lookup, not
@@ -1190,7 +1151,6 @@ def _main(stage=None):
         # binary one-hot features: a small sample fully determines the bins,
         # and the host-side mapper loop over F=4228 dominates construct time
         params["bin_construct_sample_cnt"] = 20_000
-    autotune_cache = _arm_autotune(params)
     t0 = time.time()
     ds = lgb.Dataset(X, label=y, params=params)
     ds.construct()
@@ -1397,11 +1357,7 @@ def _main(stage=None):
         # loads the number, so the row says so — comparing a ledgered
         # round's it/s against untraced history would be a silent lie
         **({"profiler_loaded": True} if ledger_on else {}),
-        # BENCH_AUTOTUNE rounds trained under measured per-shape engine
-        # selection; the sweep tables live under the "autotune" key
-        **({"autotuned": True} if autotune_cache else {}),
     })
-    _record_autotune_tables(autotune_cache)
     print(json.dumps({
         "metric": f"synthetic-{shape}{ROWS // 1_000_000}M-"
                   f"{NUM_LEAVES}leaf boosting throughput",

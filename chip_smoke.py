@@ -82,22 +82,17 @@ def check(ok, what):
         raise AssertionError(f"chip_smoke: {what}")
 
 
-def base_params(rehearsal, leaves, autotune_cache):
+def base_params(rehearsal, leaves):
     params = {
         "objective": "binary", "num_leaves": leaves, "max_bin": MAX_BIN,
         "learning_rate": 0.1, "min_data_in_leaf": 100, "verbosity": 1,
-        # the sealed machine keeps no home directory: the autotune cache
-        # lives in this run's temp dir, so the sweep always runs and its
-        # table can be read back
-        "tpu_autotune_cache": autotune_cache,
     }
     if rehearsal:
         # the same grower and kernel at toy rows: compact is forced (auto
-        # needs >= 65536 rows), the kernel runs in Pallas interpret mode,
-        # and the sweep is armed explicitly (it arms itself only on a TPU)
+        # needs >= 65536 rows) and the kernel runs in Pallas interpret mode
         params.update(tpu_grower="compact", tpu_fused="on",
                       tpu_fused_interpret=True, tpu_fused_block=128,
-                      tpu_autotune="first_run", min_data_in_leaf=20)
+                      min_data_in_leaf=20)
     return params
 
 
@@ -149,23 +144,16 @@ def resolved_engine(bst):
     }
 
 
-def engine_proof(bst, rehearsal, autotune_cache, phase="engine"):
-    """Which engine trained: resolved fields + their sources, the autotune
-    table, the structural clamps, and the kernel in the compiled step."""
-    from lightgbm_tpu.engines import autotune
+def engine_proof(bst, rehearsal, phase="engine"):
+    """Which engine trained: resolved fields + their sources, the
+    structural clamps, and the kernel in the compiled step."""
     from lightgbm_tpu.ops.fused_split import fused_block_cap
     g = bst._gbdt
     gp, res = g.grower_params, g._engine_resolution
     requested = int(g.config.get("tpu_fused_block", 512))
     fields = resolved_engine(bst)
-    tables = {k: b for k, b in autotune.sweep_tables(autotune_cache).items()
-              if k.endswith("/" + str(res.shape_class))}   # this run's sweep
-    rows = [r for blk in tables.values() for r in blk.get("table", [])]
     text = step_program_text(bst, "compact_step_k0") if g._use_compact else ""
     emit(phase, rehearsal=rehearsal, resolved=fields, sources=res.sources,
-         autotuned=res.autotuned, shape_class=res.shape_class,
-         autotune={k: {"winner": b.get("winner"), "table": b.get("table")}
-                   for k, b in tables.items()},
          fallbacks={
              "fused_block_requested": requested,
              "fused_block_vmem_cap": fused_block_cap(
@@ -177,12 +165,9 @@ def engine_proof(bst, rehearsal, autotune_cache, phase="engine"):
          step_program_bytes=len(text))
     check(g._use_compact, "the compact grower is not in use")
     check(gp.fused_block > 0, "the fused kernel is off (fused_block == 0)")
-    check(not any("error" in r for r in rows),
-          f"an autotune candidate failed: {rows}")
     if rehearsal:
         check(gp.fused_interpret, "rehearsal must run the kernel interpreted")
     else:
-        check(rows, "the autotune sweep did not run")
         check(not gp.fused_interpret, "the kernel ran in interpret mode")
         check("tpu_custom_call" in text,
               "no tpu_custom_call in the compiled step program")
@@ -212,12 +197,11 @@ def run_one_chip(args, lgb, np, tmp):
     holdout = 2048 if rh else 200_000
     n_predict = 3000 if rh else 1_000_000
     warm_rows = 1024 if rh else 4096
-    autotune_cache = os.path.join(tmp, "autotune.json")
 
     t0 = time.perf_counter()
     X, y = make_higgs_like(rows + holdout, FEATURES, seed=args.seed)
     Xh, yh, X, y = X[rows:], y[rows:], X[:rows], y[:rows]
-    params = base_params(rh, leaves, autotune_cache)
+    params = base_params(rh, leaves)
     params["tpu_serve_endpoints"] = "predict,leaf,contrib"
     datagen_s = time.perf_counter() - t0
 
@@ -248,7 +232,7 @@ def run_one_chip(args, lgb, np, tmp):
     check(auc >= floor, f"held-out AUC {auc:.4f} below the floor {floor}")
 
     # ---- engine proof
-    engine_proof(bst, rh, autotune_cache)
+    engine_proof(bst, rh)
 
     # ---- predict: device engine vs the same model reloaded from its text
     Xp = X[:n_predict]
@@ -364,7 +348,7 @@ def compare_parallel(np, name, serial, par, X, step_key, asks_for=None):
           f"{name}: scores differ from serial by {diff}")
 
 
-def run_four_chips(args, lgb, np, tmp):
+def run_four_chips(args, lgb, np):
     import jax
 
     from bench import make_higgs_like
@@ -372,11 +356,10 @@ def run_four_chips(args, lgb, np, tmp):
     check(jax.device_count() == 4,
           f"--chips 4 needs exactly four devices, found {jax.device_count()}"
           " (rehearsal: XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-    autotune_cache = os.path.join(tmp, "autotune.json")
 
     def fit(rows, leaves, iters, learners):
         X, y = make_higgs_like(rows, FEATURES, seed=args.seed)
-        params = base_params(rh, leaves, autotune_cache)
+        params = base_params(rh, leaves)
         ds = lgb.Dataset(X, label=y, params=params)
         ds.construct()
         out = {}
@@ -402,8 +385,8 @@ def run_four_chips(args, lgb, np, tmp):
     X, b = fit(args.rows or (8192 if rh else 4_194_304),
                15 if rh else LEAVES, 2 if rh else 3, ("serial", "data"))
     check(b["serial"]._gbdt.mesh is None, "serial run built a mesh")
-    engine_proof(b["serial"], rh, autotune_cache, phase="engine_serial")
-    engine_proof(b["data"], rh, autotune_cache, phase="engine_data")
+    engine_proof(b["serial"], rh, phase="engine_serial")
+    engine_proof(b["data"], rh, phase="engine_data")
     compare_parallel(np, "data_vs_serial", b["serial"], b["data"], X,
                      "compact_step_k0", asks_for="reduce_scatter")
     del b
@@ -464,7 +447,7 @@ def main(argv=None):
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
         if args.chips == 4:
-            run_four_chips(args, lgb, np, tmp)
+            run_four_chips(args, lgb, np)
         else:
             run_one_chip(args, lgb, np, tmp)
     emit("warnings", messages=log.warnings)

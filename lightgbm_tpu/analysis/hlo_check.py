@@ -148,15 +148,15 @@ MODE_TEMPLATES: Dict[str, dict] = {
     # a new engine entry cannot land without either a contract here or
     # a justified contract_exempt on the entry. xla_lane pins the
     # registry's fully-concretized serial program — every engine knob
-    # explicit (no "auto" left for the trace-time dispatch), autotune
-    # off — so a drift in how the registry threads its resolution into
+    # explicit (no "auto" left for the trace-time dispatch) — so a drift
+    # in how the registry threads its resolution into
     # GrowerParams shows up as contract drift, not just a perf change.
     "xla_lane": {
         "description": "engine-registry entry xla_lane: the chunked "
                        "one-hot einsum engine with every knob "
                        "concretized through registry.resolve "
-                       "(tpu_hist_impl=xla, lane layout, batched-M 8, "
-                       "tpu_autotune=off) on the serial compact step — "
+                       "(tpu_hist_impl=xla, lane layout, batched-M 8) "
+                       "on the serial compact step — "
                        "no collectives, no host traffic",
         "params": dict(_BASE, tpu_grower="compact", tpu_hist_impl="xla",
                        tpu_hist_layout="lane", tpu_hist_mbatch=8,

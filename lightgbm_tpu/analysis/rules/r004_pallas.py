@@ -48,8 +48,7 @@ the caller (ops/fused_split.py module docstring):
     compare (one-hot, routing predicate) silently mismatches on half
     the rows (ops/fused_split.py bin_col is the canonical site).
   * engine-registry ownership (round 12): histogram-engine selection
-    lives in ONE place, ``lightgbm_tpu/engines/`` — the registry that
-    the startup microbench autotuner feeds. Outside that package,
+    lives in ONE place, ``lightgbm_tpu/engines/``. Outside that package,
     (a) a ``GrowerParams(...)`` / ``._replace(...)`` call setting an
     engine knob (``hist_impl``/``hist_layout``/``hist_mbatch``/
     ``fused_block``) from anything but a registry resolution (a value
@@ -58,11 +57,10 @@ the caller (ops/fused_split.py module docstring):
     returning two or more of ``"xla"``/``"pallas"``/``"fused"``), and
     (c) a histogram call pinning a constant ``impl=``/``layout=`` are
     all findings — a hardcoded engine choice silently bypasses the
-    measured per-shape decision AND the user/env override order. The
-    one sanctioned escape hatch is ``ops/histogram.py::_resolve_impl``
-    (allowlist-anchored): the trace-time per-call-width dispatch that
-    still runs when the registry hands ``"auto"`` through
-    (``tpu_autotune=off`` / no cached decision).
+    registry's user/env override order. The one sanctioned escape hatch
+    is ``ops/histogram.py::_resolve_impl`` (allowlist-anchored): the
+    trace-time per-call-width dispatch that runs when the registry hands
+    ``"auto"`` through.
   * serving-engine contract coverage (round 20): every serving
     ``EngineEntry`` (``id`` starting with ``serve``) must either name an
     HLO contract id (``contracts=("serve_walk",)`` — verified by
@@ -311,9 +309,8 @@ class PallasContractRule(Rule):
                     f"{name}({kw.arg}=...) outside lightgbm_tpu/engines "
                     "selects a histogram engine knob away from the "
                     "registry — populate it from a registry.resolve "
-                    "Resolution (user > env > autotune cache > default) "
-                    "so the measured per-shape decision and the "
-                    "override order cannot be bypassed"))
+                    "Resolution (user > env > platform and shape) so "
+                    "the override order cannot be bypassed"))
         return out
 
     def _check_engine_call_consts(self, module, node: ast.Call, func_of
@@ -369,9 +366,8 @@ class PallasContractRule(Rule):
             f"function selects between engine impls {sorted(consts)} "
             "outside lightgbm_tpu/engines — engine-selection policy "
             "belongs to the registry (engines/registry.py), where the "
-            "autotune cache and the user/env override order apply; the "
-            "only sanctioned exception is the trace-time "
-            "tpu_autotune=off dispatch in ops/histogram.py "
+            "user/env override order applies; the only sanctioned "
+            "exception is the trace-time dispatch in ops/histogram.py "
             "_resolve_impl (allowlisted)")]
 
     def _check_call(self, module, node: ast.Call, func_of) -> List[Finding]:

@@ -670,16 +670,9 @@ class Booster:
         # JUST-updated config, not the _setup_train-era attributes —
         # reset_parameter({"tpu_step_buckets": "off"}) must actually take
         # the exact-keyed escape hatch and a hist-overlap/mbatch/layout
-        # toggle must not be a silent no-op. prior= reuses the run's
-        # IN-MEMORY autotune decision verbatim: no cache file I/O in the
-        # training loop (the stock learning-rate callback calls this
-        # every iteration), and the measured engine can neither vanish
-        # (unwritable cache) nor flip (cache rewritten by another
-        # process) under a live run
+        # toggle must not be a silent no-op
         resolved = engine_registry.resolve(
-            self.config, shape=getattr(gbdt, "_engine_shape", None),
-            allow_sweep=False,
-            prior=getattr(gbdt, "_engine_resolution", None))
+            self.config, shape=getattr(gbdt, "_engine_shape", None))
         gbdt._engine_resolution = resolved
         gbdt._step_buckets = resolved.step_buckets
         key_leaves, key_depth = bucketed_tree_shape(
@@ -698,8 +691,7 @@ class Booster:
                 env_override=os.environ.get("LGBM_TPU_FUSED_BS", ""))
         if resolved.fused_block and not resolved_fb:
             # the clamp took the fused kernel off: the standalone depth
-            resolved_mbatch = engine_registry.standalone_mbatch(
-                self.config, resolved)
+            resolved_mbatch = engine_registry.resolve_mbatch(self.config)
         gbdt.grower_params = gbdt.grower_params._replace(
             num_leaves=key_leaves,
             max_depth=key_depth,

@@ -218,66 +218,11 @@ def _forced_split_schedule(path: str, mappers, num_leaves: int):
             jnp.asarray(bins, jnp.int32))
 
 
-def _pick_fused_block(cfg) -> int:
-    """Thin delegate: ``tpu_fused`` resolution lives in the engine
-    registry (lightgbm_tpu/engines/registry.py, the ONE selection
-    owner); kept under the historical name for its callers/tests."""
-    from ..engines import registry as engine_registry
-    return engine_registry.resolve_fused_block(cfg)
-
-
-def _pick_hist_mbatch(cfg) -> int:
-    """Thin delegate: the standalone engines' ``tpu_hist_mbatch`` (user >
-    LGBM_TPU_HIST_MBATCH env > autotune > default 8) resolves in the
-    engine registry; the fused kernel's own depth does too
-    (registry.resolve_mbatch, ``fused=True``)."""
-    from ..engines import registry as engine_registry
-    return engine_registry.resolve_mbatch(cfg)
-
-
-def _pick_hist_layout(cfg, num_bins: int) -> str:
-    """Thin delegate: ``tpu_hist_layout`` resolves in the engine
-    registry. Without an autotune-cache decision "auto" keeps the
-    conservative lane default (registry.resolve_layout makes it honest
-    where a measured sublane win exists for the shape-class)."""
-    from ..engines import registry as engine_registry
-    return engine_registry.resolve_layout(cfg, num_bins)
-
-
-def _validated_mbatch_env(value: str) -> int:
-    """Thin delegate (engines/registry.py validated_mbatch_env)."""
-    from ..engines import registry as engine_registry
-    return engine_registry.validated_mbatch_env(value)
-
-
-def _validated_fused_block_env(value: str, num_cols: int,
-                               vmem_cap_bs: int) -> int:
-    """Thin delegate (engines/registry.py validated_fused_block_env)."""
-    from ..engines import registry as engine_registry
-    return engine_registry.validated_fused_block_env(
-        value, num_cols, vmem_cap_bs)
-
-
 def _clamp_block(block: int, n: int, floor: int = 128) -> int:
     """Shrink a streaming block size toward the data size (power-of-two)."""
     while block // 2 >= max(n, floor) and block > floor:
         block //= 2
     return max(block, floor)
-
-
-def _pick_step_buckets(cfg) -> bool:
-    """Thin delegate: ``tpu_step_buckets`` (the bucketed grower-step
-    ladder; ``off`` = the exact-keyed parity escape hatch) resolves in
-    the engine registry."""
-    from ..engines import registry as engine_registry
-    return engine_registry.resolve_step_buckets(cfg)
-
-
-def _pick_hist_overlap(cfg) -> int:
-    """Thin delegate: ``tpu_hist_overlap`` (async histogram-collective
-    overlap) resolves in the engine registry."""
-    from ..engines import registry as engine_registry
-    return engine_registry.resolve_overlap(cfg)
 
 
 def bucketed_tree_shape(step_buckets: bool, num_leaves: int,
@@ -925,18 +870,13 @@ class GBDT:
         # THE engine-registry callsite (lightgbm_tpu/engines/registry.py):
         # one resolve populates every engine knob of GrowerParams —
         # {fused, pallas, xla} x layout x batched-M x ladder x overlap —
-        # user > env > autotune cache > heuristic default. With
-        # tpu_autotune armed the startup microbench times the eligible
-        # candidates on a strided sample of the REAL binned matrix
-        # (strictly before the steady-state window; compiles land in the
-        # "autotune" phase) and persists the per-shape-class winner.
+        # user > env > what platform and shape decide
         from ..engines import registry as engine_registry
-        binned_host = train_set.binned
         shape = engine_registry.DatasetShape(
             rows=int(self._n_real),
             # STORED columns (post-EFB): the width the histogram engines
-            # actually stream, and the width the microbench sample has
-            features=int(binned_host.shape[1]),
+            # actually stream
+            features=int(train_set.binned.shape[1]),
             num_bins=int(train_set.max_num_bins),
             mode=(self.tree_learner if self.mesh is not None
                   or self._multiproc else "serial"),
@@ -947,16 +887,14 @@ class GBDT:
             gspmd=self.mesh is not None and not self._use_compact,
             compact=self._use_compact)
 
-        def _autotune_sample(n, _b=binned_host):
-            if len(_b) <= n:
-                return _b
-            stride = max(1, len(_b) // n)
-            return _b[::stride][:n]
-
         self._engine_shape = shape
-        resolved = engine_registry.resolve(
-            cfg, shape=shape, sample_provider=_autotune_sample)
+        resolved = engine_registry.resolve(cfg, shape=shape)
         self._engine_resolution = resolved
+        if shape.gspmd and engine_registry.on_tpu():
+            log.info("engine registry: this step is partitioned by GSPMD "
+                     "(masked grower under a mesh); histograms take the XLA "
+                     "einsum — a Mosaic kernel cannot be partitioned "
+                     "automatically")
 
         # bucketed step ladder (the compile-once training contract): the
         # jit key carries (leaf rung, depth bucket), the actual budgets
@@ -1477,8 +1415,7 @@ class GBDT:
             if not resolved_bs:
                 # the XLA walk's segment_histogram is a standalone
                 # engine: it keeps their depth, not the fused kernel's
-                resolved_depth = engine_registry.standalone_mbatch(
-                    self.config, self._engine_resolution)
+                resolved_depth = engine_registry.resolve_mbatch(self.config)
             if (resolved_bs, resolved_depth) != (gp.fused_block,
                                                 gp.hist_mbatch):
                 gp = gp._replace(fused_block=resolved_bs,
@@ -2317,8 +2254,7 @@ class GBDT:
                 # the masked grower does not fuse: the standalone depth
                 from ..engines import registry as engine_registry
                 self.grower_params = self.grower_params._replace(
-                    hist_mbatch=engine_registry.standalone_mbatch(
-                        self.config, self._engine_resolution))
+                    hist_mbatch=engine_registry.resolve_mbatch(self.config))
                 if self._engine_shape is not None:
                     self._engine_shape = self._engine_shape._replace(
                         compact=False)
@@ -3088,62 +3024,27 @@ class GBDT:
             return 0.0
         return self._quant_state(c, mode)[2]
 
-    def _resolve_serving_engine(self, engine: str, depth: int,
-                                tbatch: int, t_bkt: int,
-                                c: Optional[Dict[str, Any]] = None) -> str:
+    def _resolve_serving_engine(self, engine: str, depth: int) -> str:
         """``walk`` or ``level`` via the registry's serving resolve
-        order (user > env > autotune cache > depth heuristic), memoized
-        per (engine knob, depth, tree bucket, K)."""
+        order (user > env > depth heuristic), memoized per (engine knob,
+        depth, cap) so the choice is logged once."""
         from ..engines import registry as engreg
         cap = self._level_cap()
-        k = max(self.num_tree_per_iteration, 1)
         memo = getattr(self, "_serve_engine_memo", None)
         if memo is None:
             memo = self._serve_engine_memo = {}
-        key = (engine, depth, t_bkt, k, cap)
+        key = (engine, depth, cap)
         hit = memo.get(key)
         if hit is not None:
             return hit
-        racer = None
-        if c is not None and engine == "auto":
-            racer = lambda: self._serving_race_runners(c, depth, tbatch)
         res = engreg.resolve_serving_engine(
-            self.config, depth=depth, level_cap=cap, tree_bucket=t_bkt,
-            num_class=k, quant=self._quant_mode() or "off", racer=racer)
+            self.config, depth=depth, level_cap=cap,
+            quant=self._quant_mode() or "off")
         memo[key] = res.engine
         if res.source != "user":
             log.info(f"serving engine: {res.entry_id} "
                      f"({res.source}; depth={depth}, cap={cap})")
         return res.engine
-
-    def _serving_race_runners(self, c: Dict[str, Any], depth: int,
-                              tbatch: int):
-        """(runners dict, rows) for the autotuner's serving race: walk
-        vs level (vs their quantized-slab twins when tpu_leaf_quant is
-        on), each a zero-arg dispatch of the REAL stacked trees over a
-        small rung — timed by engines/autotune.serving_decision_for."""
-        st = c["st"]
-        n = 2048
-        f = self.train_set.num_total_features
-        dev = jnp.zeros((n, f), self.train_set.binned.dtype)
-        nan_a, cat_a = self._pred_route_args()
-        k = max(self.num_tree_per_iteration, 1)
-        kk = np.int32(k)
-        qmode = self._quant_mode()
-        slab, scale = ((self._quant_state(c, qmode)[:2])
-                       if qmode else (st.leaf_value, None))
-        walk_st = st._replace(leaf_value=slab) if qmode else st
-        runners = {"walk": lambda: predict_raw_batched(
-            dev, walk_st, nan_a, cat_a, kk, num_class=k,
-            depth=depth_bucket(depth), tbatch=tbatch,
-            any_cat=self._pred_any_cat, leaf_scale=scale)}
-        if depth <= self._level_cap():
-            lvt = self._level_state(c, depth)
-            runners["level"] = lambda: predict_raw_level(
-                dev, lvt, slab, kk, num_class=k, depth=max(1, depth),
-                tbatch=tbatch, any_cat=self._pred_any_cat,
-                leaf_scale=scale)
-        return runners, n
 
     def _pad_request_to_bucket(self, mat: np.ndarray, rung: int,
                                packed: bool) -> jax.Array:
@@ -3204,8 +3105,7 @@ class GBDT:
             early_stop_freq=int(freq) if use_stop else 0,
             any_cat=self._pred_any_cat)
         kk = np.int32(k)
-        eng = self._resolve_serving_engine(engine, depth, tbatch,
-                                           st.num_trees, c)
+        eng = self._resolve_serving_engine(engine, depth)
         qmode = self._quant_mode()
         slab, scale = ((self._quant_state(c, qmode)[:2])
                        if qmode else (st.leaf_value, None))
@@ -3593,8 +3493,7 @@ class GBDT:
             return np.zeros((binned.shape[0], 0), np.int32)
         dev, packed = self._serving_device_request(binned, device_packed)
         nan_a, cat_a = self._pred_route_args()
-        eng = self._resolve_serving_engine(engine, depth, tb,
-                                           st.num_trees, c)
+        eng = self._resolve_serving_engine(engine, depth)
         if eng == "level":
             lv = predict_leaf_level(
                 dev, self._level_state(c, depth), depth=max(1, depth),
@@ -3652,8 +3551,7 @@ class GBDT:
         if t_real == 0 or n == 0:
             return np.zeros((n, t_real), np.int32)
         packed = self._pred_pack4
-        eng = self._resolve_serving_engine(engine, depth, tb,
-                                           st.num_trees, c)
+        eng = self._resolve_serving_engine(engine, depth)
         top = ladder[-1]
         parts = []
         for a in range(0, n, top):

@@ -218,8 +218,7 @@ PARAMS: Dict[str, Tuple[Any, type, Tuple[str, ...]]] = {
     # block-diagonal path), "sublane" lays bins along sublanes for
     # B <= 64 so the one-hot compare fills the register tile
     # (ops/pallas_histogram.py _hist_kernel_sublane, ops/fused_split.py
-    # hist_flush). auto = lane; pick per-shape from the
-    # BENCH_SHAPES.json["hist_micro"]["layout_sweep"] measurements
+    # hist_flush). auto = lane; sublane has not run on the chip
     "tpu_hist_layout": ("auto", str, ("hist_layout",)),
     # per-leaf narrowed quantized accumulation (reference:
     # GetHistBitsInLeaf): 0 = auto (currently the int8 -> int32 engine
@@ -230,20 +229,10 @@ PARAMS: Dict[str, Tuple[Any, type, Tuple[str, ...]]] = {
     # f32 channel each — half the contraction work, bit-identical
     # sums), 32 = always the int8 -> int32 engine
     "tpu_quant_hist_bits": (0, int, ("quant_hist_bits",)),
-    # startup microbench autotuner (lightgbm_tpu/engines/autotune.py):
-    # at _setup_train the eligible engine-registry candidates ({xla,
-    # pallas} x {lane, sublane} x batched-M) are timed on a strided
-    # sample of the real binned data and the per-shape-class winner is
-    # persisted to tpu_autotune_cache (atomic JSON; default
-    # ~/.cache/lightgbm_tpu/autotune.json) — repeat runs with the same
-    # shape-class resolve with ZERO microbenches. Resolve order:
-    # user > env > autotune cache > heuristic default. first_run (the
-    # default) arms implicitly on TPU backends for shapes >= 64k rows
-    # (or anywhere when set explicitly); always re-sweeps over a cache
-    # hit; off is the pure-heuristic escape hatch (bit-identical trees
-    # either way — engine choice changes speed only)
-    "tpu_autotune": ("first_run", str, ("autotune",)),  # off | first_run | always
-    "tpu_autotune_cache": ("", str, ("autotune_cache",)),
+    # retired: accepted and ignored, so that benchmarks/configs/*.json
+    # (`tpu_autotune: off`) load without an "Unknown parameter" warning;
+    # it goes with those lines in the next `benchmark` issue (ROADMAP D3)
+    "tpu_autotune": ("off", str, ("autotune",)),
     # data-parallel histogram reduction: reduce-scatter over the feature
     # axis + best-split all-gather vs full-histogram all-reduce
     # (ops/grower_compact.py hist_scatter)
@@ -291,8 +280,8 @@ PARAMS: Dict[str, Tuple[Any, type, Tuple[str, ...]]] = {
     # "batched"/"walk" = the depth-batched pointer walk, "level" = the
     # level-order heap relayout (contiguous per-depth slabs; falls back
     # to the walk past tpu_level_depth_cap), "auto" = registry resolve
-    # order (user > env LGBM_TPU_PREDICT_ENGINE > autotune cache >
-    # depth heuristic), "scan" = the pre-engine serial tree scan
+    # order (user > env LGBM_TPU_PREDICT_ENGINE > depth heuristic),
+    # "scan" = the pre-engine serial tree scan
     # (recompiles per batch shape; parity/bench reference)
     "tpu_predict_engine": ("batched", str, ()),
     # level-engine heap depth cap: per-level slab memory is O(2^D) per
@@ -565,6 +554,11 @@ class Config:
             self._explicit.add(key)
         for key in unknown:
             log.warning(f"Unknown parameter: {key}")
+        if "tpu_autotune" in resolved and \
+                self.tpu_autotune.lower() not in ("off", "0", "false"):
+            log.warning(f"tpu_autotune={self.tpu_autotune} is retired and "
+                        "has no effect: engines resolve from platform and "
+                        "shape (engines/registry.py)")
         for key in resolved:
             feature = UNIMPLEMENTED_PARAMS.get(key)
             if feature is None:

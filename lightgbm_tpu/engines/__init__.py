@@ -1,14 +1,10 @@
-"""Engine registry + startup microbench autotuner.
-
-ONE owner for histogram-engine selection (registry.py) and the
-measured per-shape decision plane on top of it (autotune.py) —
-ROADMAP item 1: the {fused, pallas, xla-einsum} x mbatch x block size
-x layout knob space collapses behind ``registry.resolve``, and the
-choices flip from heuristic guesses to startup measurements.
+"""Engine registry: ONE owner for histogram- and serving-engine
+selection (registry.py). The {fused, pallas, xla-einsum} x mbatch x block
+size x layout knob space resolves behind ``registry.resolve``, a pure
+function of the config, the dataset's shape and the platform.
 
 Module level stays jax-free (like ``obs``): ``scripts/tpulint``'s
-stub-package trick and the offline ``scripts/autotune`` CLI both import
-pieces of this package before a backend exists; everything that needs
-jax imports it lazily inside the function that runs on-device work.
+stub-package trick imports pieces of this package before a backend
+exists; everything that needs jax imports it lazily.
 """
 from . import registry  # noqa: F401  (jax-free)

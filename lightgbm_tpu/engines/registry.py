@@ -1,23 +1,19 @@
 """Engine registry: the ONE owner of histogram-engine selection.
 
-Through round 11 the engine knob space — {fused, pallas, xla-einsum} x
-batched-M depth x block size x {lane, sublane} layout x learner mode —
-was resolved by five ``_pick_*`` helpers spread through
-``boosting/gbdt.py``, plus env overrides (``LGBM_TPU_FUSED_BS``,
-``LGBM_TPU_HIST_MBATCH``) and per-op defaults. This module collapses
-all of it behind one table (:data:`ENTRIES`) and one callsite
-(:func:`resolve`), the way the reference resolves col-wise vs row-wise
-histogram dispatch from ONE decision point at ``InitTrain``
-(``dataset.h:727``) — and, like the reference, the decision can be
-*measured* instead of guessed: the startup microbench autotuner
-(``engines/autotune.py``, ``tpu_autotune``) times the eligible entries
-on a slice of the real binned data and records the winner per
-shape-class.
+The engine knob space — {fused, pallas, xla-einsum} x batched-M depth x
+block size x {lane, sublane} layout x learner mode — resolves behind one
+table (:data:`ENTRIES`) and one callsite (:func:`resolve`), the way the
+reference resolves col-wise vs row-wise histogram dispatch from ONE
+decision point at ``InitTrain`` (``dataset.h:727``).
 
-Resolve order, per knob (the contract every test in
+:func:`resolve` is a pure function of the config, the dataset's shape
+and the platform: it reads no file, times nothing and carries nothing
+from one call to the next. Resolve order, per knob (the contract
 tests/test_registry.py pins)::
 
-    user explicit > env override > autotune cache > heuristic default
+    user explicit > LGBM_TPU_* override > what platform and shape decide
+
+so a run with nothing set resolves what the benchmark's cells resolve.
 
 Registry entries carry their HLO-contract id: ``scripts/
 verify_contracts.py`` enumerates contracts per entry (the entry id is
@@ -29,9 +25,8 @@ parity is pinned by the cross-engine bit-identity tests instead).
 tpulint R004 enforces the ownership: ``GrowerParams(hist_*=...)`` or a
 direct engine-callable choice outside this package is a finding; the
 one sanctioned escape hatch is ``ops/histogram.py::_resolve_impl``
-(allowlist-anchored), the trace-time dispatch that keeps the measured
-per-width heuristic when the registry hands ``"auto"`` through
-(``tpu_autotune=off`` / no cache).
+(allowlist-anchored), the trace-time per-width dispatch that answers
+when the registry hands ``"auto"`` through.
 
 Module level is jax-free; functions that need a backend import jax
 lazily.
@@ -39,19 +34,13 @@ lazily.
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 from ..utils import log
 
 #: platforms with a Mosaic backend — the ONE copy; every "is this a
 #: TPU" question in the package goes through :func:`on_tpu`
 TPU_PLATFORMS = ("tpu",)
-
-#: batched-M depths the autotuner sweeps for the STANDALONE engines (the
-#: Mosaic kernel's window partition and the XLA einsum's chunk widening:
-#: M = 8K MXU rows, K <= 16). The default (8) leads so a tie resolves to
-#: today's behavior, not to an arbitrary cell.
-MBATCH_CANDIDATES = (8, 16, 1)
 
 #: the fused kernel's own depth (ops/fused_split.py hist_flush), which it
 #: no longer inherits from the standalone engines. On the chip (higgs,
@@ -91,7 +80,7 @@ class DatasetShape(NamedTuple):
     gspmd: bool = False
     #: the compact grower takes this run (False: the masked grower on one
     #: chip — small data, a stochastic or caller-supplied objective).
-    #: Only the compact grower fuses; not part of the shape class
+    #: Only the compact grower fuses
     compact: bool = True
 
 
@@ -102,14 +91,13 @@ class EngineEntry(NamedTuple):
     that pin this entry's steady-state step program (at least one file
     name must contain the entry id); ``contract_exempt`` is the
     mandatory justification when no CPU contract can exist (TPU-only
-    Mosaic kernels). ``sweepable`` entries are timed standalone by the
-    autotuner; the fused kernel is selected structurally (it replaces
-    the partition+histogram streams and its binding constraint is the
-    scoped-VMEM validator, :func:`clamp_fused_block`). It inherits the
-    winning LAYOUT, which threads into its ``hist_flush``, and not the
-    winning depth: what wins a standalone 16k-row race (8) loses a factor
-    of ten inside the fused walk, so a fused entry runs
-    :data:`FUSED_MBATCH` unless the user or the environment names a depth.
+    Mosaic kernels). The fused kernel is selected structurally (it
+    replaces the partition+histogram streams and its binding constraint
+    is the scoped-VMEM validator, :func:`clamp_fused_block`); it takes the
+    resolved LAYOUT, which threads into its ``hist_flush``, and runs
+    :data:`FUSED_MBATCH` unless the user or the environment names a depth
+    (the standalone engines' 8 loses a factor of ten inside the fused
+    walk).
     """
     id: str
     impl: str                     # hist_impl fed to ops/histogram dispatch
@@ -118,9 +106,7 @@ class EngineEntry(NamedTuple):
     description: str
     contracts: Tuple[str, ...] = ()
     contract_exempt: str = ""
-    max_bins: int = 256           # eligibility bound on the bin width
     requires_tpu: bool = False
-    sweepable: bool = True
     #: mesh shapes (spmd_check keys: "1", "8", "4x2") every contract of
     #: this entry must carry a verified `memory` block for — the
     #: per-entry slice of the pod flight check (analysis/spmd_check.py);
@@ -150,7 +136,7 @@ ENTRIES: Tuple[EngineEntry, ...] = (
         contract_exempt="Mosaic kernels cannot lower on the CPU "
                         "contract harness; layout bit-identity is "
                         "pinned by tests/test_pack4_train.py",
-        max_bins=64, requires_tpu=True),
+        requires_tpu=True),
     EngineEntry(
         "fused_lane", "auto", "lane", True,
         "fused partition+histogram Mosaic kernel (ops/fused_split.py), "
@@ -158,7 +144,7 @@ ENTRIES: Tuple[EngineEntry, ...] = (
         contract_exempt="Mosaic kernels cannot lower on the CPU "
                         "contract harness; parity is pinned by "
                         "tests/test_fused.py leaf-count identity",
-        requires_tpu=True, sweepable=False),
+        requires_tpu=True),
     EngineEntry(
         "fused_sublane", "auto", "sublane", True,
         "fused Mosaic kernel with the bins-on-sublanes hist_flush "
@@ -166,7 +152,7 @@ ENTRIES: Tuple[EngineEntry, ...] = (
         contract_exempt="Mosaic kernels cannot lower on the CPU "
                         "contract harness; layout bit-identity is "
                         "pinned by tests/test_pack4_train.py",
-        max_bins=64, requires_tpu=True, sweepable=False),
+        requires_tpu=True),
 )
 
 
@@ -180,13 +166,13 @@ SERVING_ENTRIES: Tuple[EngineEntry, ...] = (
         "depth-batched pointer walk (ops/predict.py "
         "predict_raw_batched): one packed node-record gather over "
         "[Tb, L-1] per depth step",
-        contracts=("serve_walk",), sweepable=True),
+        contracts=("serve_walk",)),
     EngineEntry(
         "serve_level", "level", "lane", False,
         "level-order heap relayout (predict_raw_level): depth step d "
         "reads the contiguous [Tb, 2^d] per-level slab; buckets deeper "
         "than tpu_level_depth_cap keep the walk",
-        contracts=("serve_level",), sweepable=True),
+        contracts=("serve_level",)),
     EngineEntry(
         "serve_qleaf", "qleaf", "lane", False,
         "quantized leaf slab (tpu_leaf_quant=int8|f16) over the "
@@ -196,31 +182,20 @@ SERVING_ENTRIES: Tuple[EngineEntry, ...] = (
                         "program shape (only the leaf-slab dtype "
                         "narrows); score deviation is pinned by the "
                         "RECORDED bound and "
-                        "tests/test_level_engine.py",
-        sweepable=True),
+                        "tests/test_level_engine.py"),
 )
 
 #: tpu_predict_engine spellings the serving resolver accepts
 SERVING_ENGINE_VALUES = ("batched", "walk", "level", "scan", "auto")
 
 
-class Candidate(NamedTuple):
-    """One autotune sweep cell: an engine entry at a batched-M depth."""
-    entry: EngineEntry
-    mbatch: int
-
-    @property
-    def key(self) -> str:
-        return f"{self.entry.id}-k{self.mbatch}"
-
-
 class Resolution(NamedTuple):
     """The registry's answer: every engine knob, with provenance.
 
-    ``sources`` maps knob -> one of ``user`` / ``env`` / ``autotune`` /
-    ``default`` so logs and tests can see WHICH rung of the resolve
-    order produced each value (``hist_impl`` may also read ``gspmd`` and
-    ``hist_mbatch`` ``fused``: the structural answers). ``hist_mbatch``
+    ``sources`` maps knob -> one of ``user`` / ``env`` / ``default`` so
+    logs and tests can see WHICH rung of the resolve order produced each
+    value (``hist_impl`` may also read ``gspmd`` and ``hist_mbatch``
+    ``fused``: the structural answers). ``hist_mbatch``
     is the depth the run's histograms are built at: the fused kernel's
     under a fused entry, the standalone engines' otherwise.
     """
@@ -232,37 +207,6 @@ class Resolution(NamedTuple):
     hist_overlap: int
     step_buckets: bool
     sources: Dict[str, str]
-    shape_class: Optional[str] = None
-    autotuned: bool = False
-    # the raw autotune winner this resolution applied (None = none):
-    # reset_parameter re-resolves against THIS, not a cache re-read —
-    # the in-run engine choice must survive an unwritable cache and
-    # must never flip because the file changed under a live run
-    decision: Optional[Dict[str, Any]] = None
-
-
-# ---------------------------------------------------------------------------
-# shape classes
-# ---------------------------------------------------------------------------
-def _rung(x: int) -> int:
-    """Power-of-two rung (>= 1) — shape classes bucket like the step
-    ladder does, so near-identical datasets share one decision."""
-    return 1 << max(0, (max(1, int(x)) - 1).bit_length())
-
-
-def shape_class(shape: DatasetShape) -> str:
-    """Canonical shape-class key: learner mode + row/feature rungs +
-    exact bin width + dtype/layout markers. The autotune cache and
-    BENCH_SHAPES["autotune"] both key on it."""
-    tags = ""
-    if shape.quant:
-        tags += "-quant"
-    if shape.pack4:
-        tags += "-pack4"
-    if shape.gspmd:
-        tags += "-gspmd"
-    return (f"{shape.mode}-r{_rung(shape.rows)}-f{_rung(shape.features)}"
-            f"-b{int(shape.num_bins)}{tags}")
 
 
 def current_platform() -> str:
@@ -278,32 +222,8 @@ def on_tpu(platform: Optional[str] = None) -> bool:
     return (platform or current_platform()) in TPU_PLATFORMS
 
 
-def eligible_entries(shape: DatasetShape, platform: str
-                     ) -> List[EngineEntry]:
-    """Entries that can serve ``shape`` on ``platform`` (the Mosaic
-    entries are exactly the ``requires_tpu`` ones: they need the backend
-    and a step GSPMD does not partition)."""
-    mosaic_ok = on_tpu(platform) and not shape.gspmd
-    return [e for e in ENTRIES
-            if shape.num_bins <= e.max_bins
-            and (mosaic_ok or not e.requires_tpu)]
-
-
-def sweep_candidates(shape: DatasetShape, platform: str
-                     ) -> List[Candidate]:
-    """The autotune sweep grid: sweepable eligible entries x mbatch."""
-    out: List[Candidate] = []
-    for entry in eligible_entries(shape, platform):
-        if not entry.sweepable:
-            continue
-        for k in MBATCH_CANDIDATES:
-            out.append(Candidate(entry, k))
-    return out
-
-
 # ---------------------------------------------------------------------------
-# cfg access (Config objects AND plain dicts — the gbdt delegates and
-# their tests pass both)
+# cfg access (Config objects AND plain dicts: tests pass both)
 # ---------------------------------------------------------------------------
 def _get(cfg, name: str, default: Any = None) -> Any:
     if hasattr(cfg, "get"):
@@ -323,8 +243,7 @@ def _explicit(cfg, name: str) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# per-knob resolvers (validation/warning behavior of the former gbdt
-# _pick_* helpers, now registry-owned; gbdt keeps thin delegates)
+# per-knob resolvers
 # ---------------------------------------------------------------------------
 def validated_mbatch_env(value: str) -> int:
     """Round and re-guard an ``LGBM_TPU_HIST_MBATCH`` override (1-16)."""
@@ -362,21 +281,21 @@ def validated_fused_block_env(value: str, num_cols: int,
     return bs
 
 
-def resolve_mbatch(cfg, decision: Optional[Dict[str, Any]] = None,
-                   sources: Optional[Dict[str, str]] = None,
+def resolve_mbatch(cfg, sources: Optional[Dict[str, str]] = None,
                    fused: bool = False) -> int:
     """``tpu_hist_mbatch``: K row blocks per one-hot contraction,
     M = 8K MXU rows; always clamped to [1, 16].
 
-    The standalone engines (``fused=False``): user > env
-    (LGBM_TPU_HIST_MBATCH) > autotune > default 8 — the depth their
-    sweep measures, and where the chip has it ahead (k8 0.340 ms against
-    k1 0.445 ms on 16k rows). The fused kernel (``fused=True``): user >
-    env > :data:`FUSED_MBATCH` (source ``fused``). Neither the sweep's
-    winner nor the standalone default reaches it: inside the fused walk
-    no depth is faster than 1, 2 costs 0.7% and 8 a factor of ten
-    (PERF.md section 6, PR 29), and the sweep never times the fused
-    kernel."""
+    user > env (LGBM_TPU_HIST_MBATCH) > the engine's own depth: 8 for the
+    standalone engines (``fused=False``; on the chip k8 0.340 ms against
+    k1 0.445 ms on 16k rows), :data:`FUSED_MBATCH` for the fused kernel
+    (``fused=True``, source ``fused``): inside the fused walk no depth is
+    faster than 1, 2 costs 0.7% and 8 a factor of ten (PERF.md section 6,
+    PR 29). ``resolve_mbatch(cfg)`` is also the depth of a run whose
+    fused kernel was taken off AFTER :func:`resolve` answered for it
+    (:func:`clamp_fused_block` found no block that fits, or
+    caller-supplied gradients sent the run to the masked grower): its
+    histograms come from a standalone engine after all."""
     src = "default"
     k = int(_get(cfg, "tpu_hist_mbatch", 8) or 8)
     if _explicit(cfg, "tpu_hist_mbatch"):
@@ -386,58 +305,27 @@ def resolve_mbatch(cfg, decision: Optional[Dict[str, Any]] = None,
         src = "env"
     elif fused:
         k, src = FUSED_MBATCH, "fused"
-    elif decision and decision.get("hist_mbatch"):
-        k = int(decision["hist_mbatch"])
-        src = "autotune"
     if sources is not None:
         sources["hist_mbatch"] = src
     return max(1, min(k, 16))
 
 
-def standalone_mbatch(cfg, res: Optional[Resolution]) -> int:
-    """The depth of a run whose fused kernel was taken off AFTER
-    :func:`resolve` answered for it (:func:`clamp_fused_block` found no
-    block that fits, or caller-supplied gradients sent the run to the
-    masked grower): its histograms come from a standalone engine after
-    all, so it runs what :func:`resolve_mbatch` gives those, from the
-    run's in-memory decision."""
-    return resolve_mbatch(cfg, res.decision if res is not None else None)
-
-
 def resolve_layout(cfg, num_bins: int,
-                   decision: Optional[Dict[str, Any]] = None,
-                   platform: Optional[str] = None,
                    sources: Optional[Dict[str, str]] = None) -> str:
     """``tpu_hist_layout``: the Mosaic one-hot register layout.
 
     "sublane" lays bins along sublanes (B <= 64 only — wider bin counts
-    leave no room to group features into the 128 MXU rows). ``auto``
-    is honest where a measurement exists: an autotune-cache winner for
-    this shape-class selects the layout it measured fastest (the PR 6
-    sweep showed sublane competitive at B <= 64); without a cache the
-    conservative lane default holds."""
+    leave no room to group features into the 128 MXU rows) and is taken
+    only when the user names it; ``auto`` is ``lane``."""
     mode = str(_get(cfg, "tpu_hist_layout", "auto") or "auto").lower()
-    src = "user" if mode not in ("", "auto") else "default"
+    src = "user"
     if mode in ("", "auto"):
-        mode = "lane"
-        if decision and decision.get("hist_layout"):
-            cand = str(decision["hist_layout"])
-            if cand == "sublane" and (num_bins <= 0 or num_bins > 64):
-                pass      # stale cache vs a wider re-bin: keep lane
-            elif cand == "sublane" and not on_tpu(platform):
-                pass      # Mosaic layout needs a TPU backend
-            elif cand in ("lane", "sublane"):
-                mode, src = cand, "autotune"
+        mode, src = "lane", "default"
     elif mode not in ("lane", "sublane"):
         log.warning(f"tpu_hist_layout={mode!r} is not one of "
-                    "auto|lane|sublane; using the lane layout (auto "
-                    "stays on the conservative lane default until an "
-                    "autotune cache records a sublane win for this "
-                    "shape-class — tpu_autotune=first_run)")
-        if sources is not None:
-            sources["hist_layout"] = "default"
-        return "lane"
-    if mode == "sublane" and num_bins > 64:
+                    "auto|lane|sublane; using the lane layout")
+        mode, src = "lane", "default"
+    elif mode == "sublane" and num_bins > 64:
         # num_bins <= 0 means "width unknown" (no train-set context,
         # e.g. reset_parameter on a loaded booster) — the bound is
         # enforced where a real width exists, not against a guess
@@ -445,23 +333,20 @@ def resolve_layout(cfg, num_bins: int,
             f"tpu_hist_layout=sublane needs num_bins <= 64 (got "
             f"{num_bins}): bins lie along sublanes and wider counts "
             "cannot group features into the 128 MXU rows; using lane")
-        if sources is not None:
-            sources["hist_layout"] = "default"
-        return "lane"
+        mode, src = "lane", "default"
     if sources is not None:
         sources["hist_layout"] = src
     return mode
 
 
-def resolve_impl(cfg, decision: Optional[Dict[str, Any]] = None,
-                 sources: Optional[Dict[str, str]] = None,
+def resolve_impl(cfg, sources: Optional[Dict[str, str]] = None,
                  gspmd: bool = False) -> str:
     """``tpu_hist_impl``: the standalone histogram engine. user >
-    autotune > "auto" (the trace-time per-width heuristic in
-    ops/histogram.py _resolve_impl — the ``tpu_autotune=off`` escape
-    hatch). A GSPMD-partitioned step (:class:`DatasetShape`) takes the
-    XLA einsum — structurally, since "auto" would pick the Mosaic kernel
-    on a TPU; asking for ``pallas`` there outright is an error."""
+    "auto" (the trace-time per-width choice in ops/histogram.py
+    _resolve_impl). A GSPMD-partitioned step (:class:`DatasetShape`)
+    takes the XLA einsum — structurally, since "auto" would pick the
+    Mosaic kernel on a TPU; asking for ``pallas`` there outright is an
+    error."""
     src = "default"
     impl = str(_get(cfg, "tpu_hist_impl", "auto") or "auto").lower()
     if gspmd:
@@ -481,8 +366,6 @@ def resolve_impl(cfg, decision: Optional[Dict[str, Any]] = None,
             impl = "auto"
         else:
             src = "user"
-    elif decision and decision.get("hist_impl") in ("xla", "pallas"):
-        impl, src = str(decision["hist_impl"]), "autotune"
     else:
         impl = "auto"
     if sources is not None:
@@ -493,12 +376,10 @@ def resolve_impl(cfg, decision: Optional[Dict[str, Any]] = None,
 def resolve_fused_block(cfg, platform: Optional[str] = None,
                         sources: Optional[Dict[str, str]] = None) -> int:
     """``tpu_fused``: the fused per-split Mosaic kernel block size
-    (0 = off). auto = on whenever a real TPU backend is present; the
-    fused kernel is selected structurally, not by the microbench (see
-    EngineEntry.sweepable); its hist_flush inherits the autotuned
-    layout and runs its own depth (:func:`resolve_mbatch`). The
-    record-width scoped-VMEM clamp re-runs at :func:`clamp_fused_block`
-    once the row layout is known."""
+    (0 = off). auto = on whenever a real TPU backend is present; its
+    hist_flush takes the resolved layout and runs its own depth
+    (:func:`resolve_mbatch`). The record-width scoped-VMEM clamp re-runs
+    at :func:`clamp_fused_block` once the row layout is known."""
     mode = str(_get(cfg, "tpu_fused", "auto") or "auto").lower()
     src = "user" if _explicit(cfg, "tpu_fused") else "default"
     if sources is not None:
@@ -553,9 +434,8 @@ def resolve_overlap(cfg,
     psum_scatter/all-reduce per group, issued while the next group
     still accumulates — collective latency hides under the MXU
     contraction at unchanged byte totals. Only meaningful on the
-    distributed learners. ``auto`` stays off until a real-TPU sweep
-    says otherwise (the autotuner does not sweep it: overlap needs live
-    collectives, which a single-chip microbench cannot time)."""
+    distributed learners. ``auto`` stays off until a four-chip cell
+    says otherwise (ROADMAP R8)."""
     mode = str(_get(cfg, "tpu_hist_overlap", "auto") or "auto").lower()
     if sources is not None:
         sources["hist_overlap"] = \
@@ -621,7 +501,7 @@ def fit_fused_flush(res: "Resolution", num_cols: int, num_bins: int,
     s an iteration against 3.09 s at depth 2 and block 96, the
     comparison's gaps identical to the last digit; PERF.md section 6,
     PR 30). A block of 0 (the clamp took the kernel off) leaves the
-    depth to :func:`standalone_mbatch`."""
+    depth to ``resolve_mbatch(cfg)``."""
     block = clamp_fused_block(res.fused_block, num_cols, res.hist_mbatch,
                               res.hist_layout, num_bins, num_features,
                               env_override)
@@ -639,73 +519,41 @@ def fit_fused_flush(res: "Resolution", num_cols: int, num_bins: int,
 # THE resolve callsite
 # ---------------------------------------------------------------------------
 def resolve(cfg, shape: Optional[DatasetShape] = None,
-            sample_provider=None, platform: Optional[str] = None,
-            allow_sweep: bool = True,
-            prior: Optional[Resolution] = None) -> Resolution:
-    """Resolve every engine knob for one training run.
-
-    ``shape`` keys the autotune cache (None = no shape context, e.g. a
-    booster constructed without a train set: heuristic defaults only).
-    ``sample_provider(n)`` returns up to ``n`` rows of the REAL binned
-    matrix for the microbench; ``allow_sweep=False`` never runs a new
-    sweep. ``prior`` (reset_parameter) is the run's previous
-    Resolution: its in-memory decision is reused VERBATIM — no cache
-    re-read, no file I/O in the training loop, and the engine a run
-    measured at startup can neither vanish (unwritable cache) nor flip
-    (cache rewritten underneath a live run) on a mid-run re-resolve.
-    """
+            platform: Optional[str] = None) -> Resolution:
+    """Resolve every engine knob for one training run: a pure function
+    of ``cfg``, ``shape`` and ``platform`` (plus the ``LGBM_TPU_*``
+    overrides), so ``_setup_train`` and ``reset_parameter`` make the same
+    call and get the same answer. ``shape`` None = no shape context (a
+    booster constructed without a train set): the bin width is unknown
+    and the run is taken to fuse where the platform allows."""
     platform = platform or current_platform()
     sources: Dict[str, str] = {}
-    decision = None
-    swept = False
-    sclass = shape_class(shape) if shape is not None else None
-    if prior is not None:
-        decision = prior.decision
-    elif shape is not None:
-        from . import autotune
-        decision, swept = autotune.decision_for(
-            cfg, shape, platform, sample_provider=sample_provider,
-            allow_sweep=allow_sweep)
     # 0 = bin width unknown (no train-set context): the sublane bound
     # cannot be checked, so it is not enforced against a made-up width
     num_bins = int(shape.num_bins) if shape is not None else 0
-    layout = resolve_layout(cfg, num_bins, decision, platform, sources)
+    layout = resolve_layout(cfg, num_bins, sources)
     gspmd = shape is not None and shape.gspmd
-    impl = resolve_impl(cfg, decision, sources, gspmd=gspmd)
-    if gspmd and prior is None and on_tpu(platform):
-        log.info("engine registry: this step is partitioned by GSPMD "
-                 "(masked grower under a mesh); histograms take the XLA "
-                 "einsum — a Mosaic kernel cannot be partitioned "
-                 "automatically")
+    impl = resolve_impl(cfg, sources, gspmd=gspmd)
     fused_block = resolve_fused_block(cfg, platform, sources)
     # only the compact grower fuses: the masked grower (a GSPMD step, or
     # one chip's small or caller-gradient runs) builds every histogram
     # with a standalone engine, at the standalone engines' depth
     fused = bool(fused_block) and not gspmd and (
         shape is None or shape.compact)
-    mbatch = resolve_mbatch(cfg, decision, sources, fused=fused)
+    mbatch = resolve_mbatch(cfg, sources, fused=fused)
     step_buckets = resolve_step_buckets(cfg, sources)
     overlap = resolve_overlap(cfg, sources)
     if fused:
         entry_id = "fused_sublane" if layout == "sublane" else "fused_lane"
-    elif decision and decision.get("entry"):
-        entry_id = str(decision["entry"])
     elif impl == "pallas":
         entry_id = ("pallas_sublane" if layout == "sublane"
                     else "pallas_lane")
     else:
         entry_id = "xla_lane"
-    res = Resolution(
+    return Resolution(
         entry_id=entry_id, fused_block=fused_block, hist_impl=impl,
         hist_mbatch=mbatch, hist_layout=layout, hist_overlap=overlap,
-        step_buckets=step_buckets, sources=sources, shape_class=sclass,
-        autotuned=bool(decision), decision=decision)
-    if decision and prior is None:
-        log.info(
-            f"engine registry: shape-class {sclass} -> {entry_id} "
-            f"(layout={layout}, mbatch={mbatch}, impl={impl}; "
-            f"{'measured now' if swept else 'autotune cache'})")
-    return res
+        step_buckets=step_buckets, sources=sources)
 
 
 # ---------------------------------------------------------------------------
@@ -717,75 +565,48 @@ class ServingResolution(NamedTuple):
     ``engine`` is the resolved router (``walk`` | ``level``);
     ``entry_id`` the registry entry it maps to (``serve_qleaf`` when a
     quantized leaf slab rides the router); ``source`` the resolve-order
-    rung that produced it (user / env / autotune / default).
+    rung that produced it (user / env / default).
     """
     engine: str
     entry_id: str
     source: str
-    shape_class: Optional[str] = None
-    decision: Optional[Dict[str, Any]] = None
-
-
-def serving_shape_class(tree_bucket: int, depth: int, num_class: int,
-                        quant: str = "off") -> str:
-    """Autotune cache key for one serving shape: tree bucket + depth +
-    class count (+ quant mode), the jit-key axes a frozen model's
-    serving programs are compiled on. Distinct from the training shape
-    classes by the ``serve-`` prefix."""
-    tag = "" if quant in ("", "off", None) else f"-q{quant}"
-    return f"serve-t{int(tree_bucket)}-d{int(depth)}-k{int(num_class)}{tag}"
-
-
-def _serving_entry_id(engine: str, quant: str) -> str:
-    if quant not in ("", "off", None):
-        return "serve_qleaf"
-    return f"serve_{engine}"
 
 
 def resolve_serving_engine(cfg, depth: int, level_cap: int,
-                           tree_bucket: int = 0, num_class: int = 1,
-                           quant: str = "off",
-                           platform: Optional[str] = None,
-                           racer=None) -> ServingResolution:
+                           quant: str = "off") -> ServingResolution:
     """Resolve ``tpu_predict_engine`` to a serving router.
 
     The same per-knob order as :func:`resolve`::
 
-        user explicit > env LGBM_TPU_PREDICT_ENGINE > autotune cache
-        > heuristic default
+        user explicit > env LGBM_TPU_PREDICT_ENGINE > depth heuristic
 
     ``level`` demotes to ``walk`` (with a warning) when the stack is
     deeper than ``level_cap`` — the per-level slab is O(2^depth) per
-    tree, so deep/ragged buckets keep the walk. ``auto`` consults the
-    autotune cache (shape class :func:`serving_shape_class`) and, when
-    armed with a ``racer``, times the candidate engines on the real
-    stacked trees (engines/autotune.serving_decision_for); unarmed it
-    falls to the depth heuristic. ``scan`` never reaches here (callers
-    branch to the reference path first).
+    tree, so deep/ragged buckets keep the walk. ``auto`` is the depth
+    heuristic: ``level`` up to the cap, ``walk`` past it. ``scan`` never
+    reaches here (callers branch to the reference path first).
     """
-    platform = platform or current_platform()
-    sclass = serving_shape_class(tree_bucket, depth, num_class, quant)
+    def answer(engine: str, source: str) -> ServingResolution:
+        entry = ("serve_qleaf" if quant not in ("", "off", None)
+                 else f"serve_{engine}")
+        return ServingResolution(engine, entry, source)
 
     def norm(value: str, source: str) -> Optional[ServingResolution]:
         if value in ("batched", "walk"):
-            return ServingResolution("walk", _serving_entry_id(
-                "walk", quant), source, sclass)
+            return answer("walk", source)
         if value == "level":
             if depth > level_cap:
                 log.warning(
                     f"tpu_predict_engine=level: stacked depth {depth} "
                     f"exceeds tpu_level_depth_cap={level_cap}; the "
                     "bucket keeps the pointer walk")
-                return ServingResolution("walk", _serving_entry_id(
-                    "walk", quant), source, sclass)
-            return ServingResolution("level", _serving_entry_id(
-                "level", quant), source, sclass)
+                return answer("walk", source)
+            return answer("level", source)
         if value not in ("", "auto"):
             log.warning(f"tpu_predict_engine={value!r} is not one of "
                         f"{'|'.join(SERVING_ENGINE_VALUES)}; using the "
                         "depth-batched walk")
-            return ServingResolution("walk", _serving_entry_id(
-                "walk", quant), source, sclass)
+            return answer("walk", source)
         return None
 
     raw = str(_get(cfg, "tpu_predict_engine", "batched")
@@ -804,16 +625,4 @@ def resolve_serving_engine(cfg, depth: int, level_cap: int,
         res = norm(raw, "default")
         if res is not None:
             return res
-    # auto: measured decision when armed, depth heuristic otherwise
-    from . import autotune
-    decision, _swept = autotune.serving_decision_for(
-        cfg, sclass, platform, runners_provider=racer)
-    eng = (decision or {}).get("serve_engine")
-    if eng in ("walk", "level"):
-        if eng == "level" and depth > level_cap:
-            eng = "walk"
-        return ServingResolution(eng, _serving_entry_id(eng, quant),
-                                 "autotune", sclass, decision)
-    eng = "level" if depth <= level_cap else "walk"
-    return ServingResolution(eng, _serving_entry_id(eng, quant),
-                             "default", sclass)
+    return answer("level" if depth <= level_cap else "walk", "default")

@@ -33,7 +33,7 @@ Which host spans record. The spans of set-up and of the update loop
 default, ``Booster.update()`` writes its ``iteration`` event there anyway,
 and an update enters about five of them (a dict and a locked append each:
 microseconds against an iteration). Every other host span (the serving
-ticks, ``checkpoint_write``, ``autotune``) records only inside a
+ticks, ``checkpoint_write``) records only inside a
 :func:`trace_session` and is otherwise one shared no-op — a coalescer
 ticking thousands of times a second would turn the ring over and push
 out the compile and iteration events a post-mortem wants.
@@ -65,8 +65,6 @@ Span taxonomy (every name a device program or tick site carries):
 ``checkpoint_write``      io/checkpoint.write_snapshot atomic tick
 ``predict_warmup``        one serving-ladder rung warm (basic.py)
 ``serve_tick``            one coalescer micro-batch device dispatch
-``autotune``              the startup engine microbench sweep
-                          (engines/autotune.py — strictly pre-steady-state)
 ``import``                ``import lightgbm_tpu`` (stamped, not entered)
 ``construct``             all of ``Dataset.construct()``; children
                           ``find_bins`` (the sample and the boundaries)

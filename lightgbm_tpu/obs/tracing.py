@@ -18,7 +18,7 @@ from typing import Optional, Tuple
 SPAN_TAXONOMY = (
     "binning", "gradient", "hist_build", "collective_reduce", "split_scan",
     "partition", "checkpoint_write", "predict_warmup", "serve_tick",
-    "autotune", "featurize", "contrib",
+    "featurize", "contrib",
     "import", "construct", "find_bins", "to_device", "booster_init",
     "rank_layout", "compact_setup", "build_step",
     "iteration", "bag", "rank_grads", "step_dispatch", "valid_scores",

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lightgbm_tpu.boosting.gbdt import _validated_fused_block_env
+from lightgbm_tpu.engines.registry import validated_fused_block_env
 from lightgbm_tpu.ops.compact import RowLayout
 from lightgbm_tpu.ops.fused_split import fused_split
 from lightgbm_tpu.parallel.comm_accounting import collective_bytes
@@ -79,16 +79,16 @@ def test_fused_split_raises_on_short_pad():
 
 # ------------------------------------------- ADVICE #3: env override guard
 def test_env_override_rounded_to_32_multiple():
-    assert _validated_fused_block_env("100", 128, 384) == 96
-    assert _validated_fused_block_env("5", 128, 384) == 32
-    assert _validated_fused_block_env("256", 128, 384) == 256
+    assert validated_fused_block_env("100", 128, 384) == 96
+    assert validated_fused_block_env("5", 128, 384) == 32
+    assert validated_fused_block_env("256", 128, 384) == 256
 
 
 def test_env_override_clamped_to_vmem_cap():
     """An oversize override must not recreate the VMEM blowup the scoped
     guard prevents (pre-fix: accepted raw)."""
-    assert _validated_fused_block_env("8192", 128, 384) == 384
-    assert _validated_fused_block_env("512", 2048, 64) == 64
+    assert validated_fused_block_env("8192", 128, 384) == 384
+    assert validated_fused_block_env("512", 2048, 64) == 64
 
 
 # ------------------------------------------- ADVICE #4: docstring accuracy

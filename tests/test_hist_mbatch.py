@@ -146,7 +146,7 @@ def _forest_data():
     base = {
         "objective": "binary", "num_leaves": 15, "max_bin": 63,
         "learning_rate": 0.1, "min_data_in_leaf": 20, "verbosity": -1,
-        "tpu_grower": "compact", "tpu_autotune": "off",
+        "tpu_grower": "compact",
         "tpu_fused_interpret": True, "tpu_fused_block": 128,
     }
     return X, y, base
@@ -366,15 +366,15 @@ def test_hist_mbatch_env_override_validated():
     knob beats the env override, the env override beats the default —
     and out-of-range env values are still clamped to [1, 16]."""
     import os
-    from lightgbm_tpu.boosting.gbdt import _pick_hist_mbatch
-    assert _pick_hist_mbatch({"tpu_hist_mbatch": 12}) == 12
+    from lightgbm_tpu.engines.registry import resolve_mbatch
+    assert resolve_mbatch({"tpu_hist_mbatch": 12}) == 12
     os.environ["LGBM_TPU_HIST_MBATCH"] = "99"
     try:
         # explicit user knob wins over the env override
-        assert _pick_hist_mbatch({"tpu_hist_mbatch": 4}) == 4
+        assert resolve_mbatch({"tpu_hist_mbatch": 4}) == 4
         # env override (validated: 99 clamps to 16) wins over the default
-        assert _pick_hist_mbatch({}) == 16
+        assert resolve_mbatch({}) == 16
         os.environ["LGBM_TPU_HIST_MBATCH"] = "5"
-        assert _pick_hist_mbatch({}) == 5
+        assert resolve_mbatch({}) == 5
     finally:
         del os.environ["LGBM_TPU_HIST_MBATCH"]

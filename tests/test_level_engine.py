@@ -8,8 +8,7 @@ The acceptance surface this file pins:
     col_of, multiclass, iteration windows, pred_leaf;
   * trees deeper than tpu_level_depth_cap fall back to the walk per
     bucket (resolve-level demotion with a warning), answers unchanged;
-  * resolve_serving_engine honors the user > env > autotune > heuristic
-    order, and the autotuner's serving race persists + reuses winners;
+  * resolve_serving_engine honors the user > env > depth-heuristic order;
   * quantized serving stays within the RECORDED max-score-error bound
     (leaf_quant_bound), the bound is exact/tight on a single tree, and
     quantized scores are identical across the walk and level routers;
@@ -33,7 +32,7 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.analysis import guards
-from lightgbm_tpu.engines import autotune, registry
+from lightgbm_tpu.engines import registry
 from lightgbm_tpu.ops.predict import quantize_leaves
 from lightgbm_tpu.serving.coalescer import MicroBatchCoalescer, ServeFuture
 
@@ -142,16 +141,14 @@ def test_level_depth_cap_demotes_to_walk():
     # registry level: an explicit level request over the cap keeps the
     # walk (with the quantized entry id when a slab rides along)
     res = registry.resolve_serving_engine(
-        {"tpu_predict_engine": "level"}, depth=12, level_cap=10,
-        tree_bucket=16, platform="cpu")
+        {"tpu_predict_engine": "level"}, depth=12, level_cap=10)
     assert (res.engine, res.source) == ("walk", "user")
     res = registry.resolve_serving_engine(
-        {"tpu_predict_engine": "level"}, depth=5, level_cap=10,
-        tree_bucket=16, platform="cpu")
+        {"tpu_predict_engine": "level"}, depth=5, level_cap=10)
     assert (res.engine, res.entry_id) == ("level", "serve_level")
     res = registry.resolve_serving_engine(
         {"tpu_predict_engine": "level"}, depth=5, level_cap=10,
-        tree_bucket=16, platform="cpu", quant="int8")
+        quant="int8")
     assert (res.engine, res.entry_id) == ("level", "serve_qleaf")
     # end to end: a cap below the stacked depth serves via the walk
     # fallback and still answers exactly
@@ -172,56 +169,23 @@ def test_level_depth_cap_demotes_to_walk():
         g._serve_engine_memo = None
 
 
-# ------------------------------------------------ resolve order + race
-def test_serving_resolve_order_user_env_heuristic(monkeypatch):
-    monkeypatch.setenv("LGBM_TPU_PREDICT_ENGINE", "level")
+# ------------------------------------------------------- resolve order
+@pytest.mark.parametrize("cfg, env, depth, want", [
     # user beats env
-    res = registry.resolve_serving_engine(
-        {"tpu_predict_engine": "walk"}, depth=4, level_cap=10,
-        platform="cpu")
-    assert (res.engine, res.source) == ("walk", "user")
+    ({"tpu_predict_engine": "walk"}, "level", 4, ("walk", "user")),
     # env beats the heuristic when the knob is unset
-    res = registry.resolve_serving_engine({}, depth=4, level_cap=10,
-                                          platform="cpu")
-    assert (res.engine, res.source) == ("level", "env")
-    monkeypatch.delenv("LGBM_TPU_PREDICT_ENGINE")
-    # auto, unarmed: shallow stacks take the level heuristic, deep the walk
-    res = registry.resolve_serving_engine(
-        {"tpu_predict_engine": "auto"}, depth=4, level_cap=10,
-        platform="cpu")
-    assert (res.engine, res.source) == ("level", "default")
-    res = registry.resolve_serving_engine(
-        {"tpu_predict_engine": "auto"}, depth=12, level_cap=10,
-        platform="cpu")
-    assert (res.engine, res.source) == ("walk", "default")
-
-
-def test_serving_autotune_race_persists_winner(tmp_path, monkeypatch):
-    """auto + armed cache: the race times the real runners once, the
-    winner persists, and the next resolve reuses it without re-racing."""
-    times = iter([0.004, 0.001])        # walk slow, level fast
-    monkeypatch.setattr(autotune, "_time_candidate",
-                        lambda fn, reps=0: next(times))
-    cfg = {"tpu_predict_engine": "auto", "tpu_autotune": "first_run",
-           "tpu_autotune_cache": str(tmp_path / "at.json")}
-    calls = []
-
-    def racer():
-        calls.append(1)
-        return ({"walk": lambda: None, "level": lambda: None}, 2048)
-
-    res = registry.resolve_serving_engine(cfg, depth=5, level_cap=10,
-                                          tree_bucket=16, platform="cpu",
-                                          racer=racer)
-    assert (res.engine, res.source) == ("level", "autotune")
-    assert len(calls) == 1
-    # second resolve: cache hit, no second race (the stub timer is
-    # exhausted — a re-race would raise StopIteration)
-    res2 = registry.resolve_serving_engine(cfg, depth=5, level_cap=10,
-                                           tree_bucket=16, platform="cpu",
-                                           racer=racer)
-    assert (res2.engine, res2.source) == ("level", "autotune")
-    assert len(calls) == 1
+    ({}, "level", 4, ("level", "env")),
+    # auto: shallow stacks take the level heuristic, deep ones the walk
+    ({"tpu_predict_engine": "auto"}, "", 4, ("level", "default")),
+    ({"tpu_predict_engine": "auto"}, "", 12, ("walk", "default")),
+], ids=["user-over-env", "env-over-heuristic", "auto-shallow", "auto-deep"])
+def test_serving_resolve_order_user_env_heuristic(monkeypatch, cfg, env,
+                                                  depth, want):
+    """user > LGBM_TPU_PREDICT_ENGINE > the depth heuristic: nothing is
+    timed and nothing is read from a file."""
+    monkeypatch.setenv("LGBM_TPU_PREDICT_ENGINE", env)
+    res = registry.resolve_serving_engine(cfg, depth=depth, level_cap=10)
+    assert (res.engine, res.source) == want
 
 
 # -------------------------------------------------- quantized leaf slabs

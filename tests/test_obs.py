@@ -253,17 +253,12 @@ def test_summarize_table_renders(tmp_path, capsys):
 
 
 # ------------------------------------------- the acceptance criterion (A)
-def test_taxonomy_trace_metrics_acceptance(tmp_path, monkeypatch):
+def test_taxonomy_trace_metrics_acceptance(tmp_path):
     """5-iteration compact data-parallel run with spans enabled
     (annotations mode — the device programs carry the named scopes either
     way; the profiler-artifact flavor is the slow test below): the run +
     a warmed serve tick touch EVERY taxonomy span, the metrics stream
-    parses, and bench ingestion finds the per-iteration counters. The
-    autotune span comes from an armed (stub-timed — the REAL sweep is
-    slow-lane, tests/test_registry.py) startup microbench."""
-    from lightgbm_tpu.engines import autotune as eng_autotune
-    monkeypatch.setattr(eng_autotune, "_time_candidate",
-                        lambda fn, *a, reps=0: 1e-3)
+    parses, and bench ingestion finds the per-iteration counters."""
     spans.reset()
     X, y = _make_data(800, 8)
     mpath = tmp_path / "metrics.jsonl"
@@ -275,8 +270,6 @@ def test_taxonomy_trace_metrics_acceptance(tmp_path, monkeypatch):
         "tpu_metrics_path": str(mpath),
         "tpu_checkpoint_dir": str(ckpt), "tpu_checkpoint_freq": 2,
         "tpu_flight_buffer": 256,
-        "tpu_autotune": "first_run",
-        "tpu_autotune_cache": str(tmp_path / "autotune.json"),
     }
     ds = lgb.Dataset(X, label=y)
     bst = lgb.train(params, ds, num_boost_round=5,

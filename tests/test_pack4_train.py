@@ -305,14 +305,14 @@ class TestSublaneLayout:
         np.testing.assert_array_equal(b_lane.predict(X), b_sub.predict(X))
 
     def test_layout_knob_validation(self):
-        from lightgbm_tpu.boosting.gbdt import _pick_hist_layout
-        assert _pick_hist_layout({"tpu_hist_layout": "auto"}, 256) == "lane"
-        assert _pick_hist_layout({"tpu_hist_layout": "sublane"}, 64) \
+        from lightgbm_tpu.engines.registry import resolve_layout
+        assert resolve_layout({"tpu_hist_layout": "auto"}, 256) == "lane"
+        assert resolve_layout({"tpu_hist_layout": "sublane"}, 64) \
             == "sublane"
         # wide bins cannot lay on sublanes — warn + lane
-        assert _pick_hist_layout({"tpu_hist_layout": "sublane"}, 256) \
+        assert resolve_layout({"tpu_hist_layout": "sublane"}, 256) \
             == "lane"
-        assert _pick_hist_layout({"tpu_hist_layout": "bogus"}, 64) == "lane"
+        assert resolve_layout({"tpu_hist_layout": "bogus"}, 64) == "lane"
 
 
 # ------------------------------------------------------ steady-state guard

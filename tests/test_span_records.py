@@ -194,8 +194,8 @@ def test_taxonomy(case):
         assert "update" not in names
     elif case == "always_on":
         assert spans.ALWAYS_ON <= set(names)
-        assert not {"serve_tick", "predict_warmup", "checkpoint_write",
-                    "autotune"} & spans.ALWAYS_ON
+        assert not {"serve_tick", "predict_warmup",
+                    "checkpoint_write"} & spans.ALWAYS_ON
     elif case == "no_repeat":
         assert len(set(names)) == len(names)
     else:

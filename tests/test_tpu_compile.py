@@ -90,7 +90,7 @@ HIST_CASES = {
     "higgs-split-k8": (1 << 20, 28, 256, "split", 8, "lane"),
     "higgs-int8-k8": (1 << 20, 28, 256, "int8", 8, "lane"),
     "b64-sublane-k8": (1 << 20, 28, 64, "split", 8, "sublane"),
-    # the autotuner's 16k-row sample at its deepest batched-M candidate
+    # a 16k-row call at the deepest batched-M the knob allows
     "sweep-sample-k16": (1 << 14, 28, 256, "split", 16, "lane"),
 }
 HIST_CASES_SLOW = {
@@ -174,7 +174,7 @@ def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
     dual residency, block clamped to 384, at the depth the registry
     resolves for a fused entry on a TPU with nothing set."""
     res = registry.resolve(
-        {"tpu_autotune": "off"}, platform="tpu",
+        {}, platform="tpu",
         shape=registry.DatasetShape(HIGGS_ROWS, 28, 255, "serial"))
     assert res.entry_id == "fused_lane"
     assert res.sources["hist_mbatch"] == "fused"
@@ -191,7 +191,7 @@ def test_fused_kernel_compiles_for_v5e_at_220_features(one_chip,
     groups (depth 1 at the same 192 rows a flush) compiles."""
     layout = RowLayout(num_features=220, num_extra=HIGGS_EXTRAS)
     res = registry.resolve(
-        {"tpu_autotune": "off"}, platform="tpu",
+        {}, platform="tpu",
         shape=registry.DatasetShape(7_325_625, 220, 255, "serial"))
     bs, depth = registry.fit_fused_flush(res, layout.num_cols, 256, 220)
     assert (bs, depth) == (192, 1)
@@ -256,8 +256,6 @@ def _retarget(args, dim_map, sharding_of):
 STEP_PARAMS = {
     "objective": "binary", "num_leaves": 255, "max_bin": 255,
     "min_data_in_leaf": 100, "verbosity": -1,
-    # the sweep would EXECUTE Mosaic candidates; nothing runs here
-    "tpu_autotune": "off",
 }
 
 

@@ -69,7 +69,9 @@ def test_ledger_record_merges_atomically(tmp_path):
 
 def test_load_contract_known_modes():
     c = ledger.load_contract("data_scatter")
-    assert c is not None and ledger.model_bytes_per_step(c) == 1440
+    # the file's own number, whatever the XLA that recorded it
+    assert c is not None
+    assert ledger.model_bytes_per_step(c) == c["measured"]["total"] > 0
     assert ledger.load_contract("no_such_mode") is None
 
 
