@@ -19,9 +19,9 @@ the caller (ops/fused_split.py module docstring):
     ops/fused_split.py).
   * batched-M pending rings (round 6, ops/fused_split.py hist_flush):
     a constant ``mbatch`` must keep 8*mbatch within the 128 MXU rows,
-    and ``mbatch x block_size`` VMEM residency (bin slots, transposed
-    channel slots, and the flush's one-hot/block-diagonal transients,
-    evaluated for both the bf16 and int8 channel layouts) must stay
+    and ``mbatch x block_size`` VMEM residency (the transposed block
+    slots, their channel slots, and the flush's one-hot, evaluated for
+    both the bf16 and int8 channel layouts) must stay
     under the scoped-VMEM ring budget — the arithmetic lives in
     ops/fused_split.py fused_ring_bytes and is evaluated here at the
     minimum 128-byte record width.
@@ -33,11 +33,10 @@ the caller (ops/fused_split.py module docstring):
   * bins-on-sublanes layout contracts (round 6): a constant
     ``hist_layout="sublane"`` needs ``num_bins <= 64`` (bins lie along
     sublanes; wider counts cannot group features into the 128 MXU rows),
-    and the pending-ring VMEM budget is evaluated under BOTH layouts —
-    the sublane layout stages channels row-major, which the VMEM tiling
-    pads to the full 128-lane width (a 4-8x larger channel-slot term
-    that the ring-bytes formula must charge, ops/fused_split.py
-    fused_ring_bytes). The formula takes the RECORD width as its
+    and the pending-ring VMEM budget is evaluated under BOTH layouts
+    (since PR 33 both stage the same transposed operands,
+    ops/fused_split.py fused_ring_bytes). The formula takes the RECORD
+    width as its
     ``num_cols`` — under RowLayout.packed4 that width is already the
     nibble-packed one, so packing tightens the bound instead of
     escaping it.
@@ -46,7 +45,7 @@ the caller (ops/fused_split.py module docstring):
     byte must mask the result with ``& 0xF`` — without the mask the
     neighbour feature's high nibble rides along and every downstream
     compare (one-hot, routing predicate) silently mismatches on half
-    the rows (ops/fused_split.py bin_col is the canonical site).
+    the rows (ops/fused_split.py bin_row is the canonical site).
   * engine-registry ownership (round 12): histogram-engine selection
     lives in ONE place, ``lightgbm_tpu/engines/``. Outside that package,
     (a) a ``GrowerParams(...)`` / ``._replace(...)`` call setting an
@@ -464,8 +463,7 @@ class PallasContractRule(Rule):
                                             fused_ring_bytes)
             # minimum 128-byte record width (packed4 layouts are NARROWER,
             # so this floor covers them); evaluated for both channel
-            # dtypes AND both register layouts — the sublane layout's
-            # row-major channel slots pad to 128 lanes and must be charged
+            # dtypes AND both register layouts
             worst = max(
                 fused_ring_bytes(bs, 128, mb, quant=q, hist_layout=hl)
                 for q in (False, True) for hl in layouts)
@@ -573,7 +571,8 @@ class PallasContractRule(Rule):
             cur = parents[cur]
             if isinstance(cur, (ast.FunctionDef, ast.AsyncFunctionDef)) \
                     and any(t in cur.name.lower()
-                            for t in ("pack", "nibble", "bin_col")):
+                            for t in ("pack", "nibble", "bin_col",
+                                      "bin_row")):
                 return True
         return False
 

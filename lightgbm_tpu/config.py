@@ -214,11 +214,11 @@ PARAMS: Dict[str, Tuple[Any, type, Tuple[str, ...]]] = {
     # residency by K, so tpu_fused_block is re-clamped against it
     "tpu_hist_mbatch": (8, int, ("hist_mbatch",)),
     # Mosaic one-hot register layout for the histogram engines: "lane"
-    # keeps bins along lanes (channel-major output, the batched-M
-    # block-diagonal path), "sublane" lays bins along sublanes for
-    # B <= 64 so the one-hot compare fills the register tile
-    # (ops/pallas_histogram.py _hist_kernel_sublane, ops/fused_split.py
-    # hist_flush). auto = lane; sublane has not run on the chip
+    # keeps bins along lanes (channel-major output), "sublane" lays bins
+    # along sublanes for B <= 64 (ops/pallas_histogram.py
+    # _hist_kernel_sublane: the one-hot compare fills the register tile;
+    # ops/fused_split.py hist_contract: the same operands with the
+    # one-hot streamed). auto = lane; sublane has not run on the chip
     "tpu_hist_layout": ("auto", str, ("hist_layout",)),
     # per-leaf narrowed quantized accumulation (reference:
     # GetHistBitsInLeaf): 0 = auto (currently the int8 -> int32 engine

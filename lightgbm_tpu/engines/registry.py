@@ -455,13 +455,14 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
     12; previously inlined in gbdt._setup_compact_state).
 
     The kernel's streaming buffers scale with ``block_size * num_cols``
-    and the batched-M pending ring with ``mbatch * block_size`` (bins +
-    transposed channels + the flush's one-hot and block-diagonal
-    transients — both register layouts charged, ops/fused_split.py
-    fused_ring_bytes), and the flush's unrolled text and stack with
-    feature groups x ``mbatch`` x ``block_size`` (``_FLUSH_ONEHOT_ROWS``:
-    220 features of 256 bins run block 96 at depth 2 where their
-    256-byte rows alone would allow 192); the histogram accumulator needs
+    and the pending ring with ``mbatch * block_size`` (the transposed
+    blocks, their channel operands and the flush's one-hot,
+    ops/fused_split.py fused_ring_bytes), and the flush's unrolled text
+    with feature groups x ``mbatch`` x ``block_size``
+    (``_FLUSH_ONEHOT_ROWS``: 220 features of 256 bins run block 128 at
+    depth 2 where their 256-byte rows alone would allow 256); from 128
+    rows up the block is whole lane tiles, because the histogram holds a
+    block's rows along lanes; the histogram accumulator needs
     ``f_pad * stride * 32`` bytes regardless of block size, so a shape
     whose accumulator alone blows the ~16MB scoped limit falls back to
     the XLA walk (returns 0). ``env_override`` (LGBM_TPU_FUSED_BS) is
@@ -497,11 +498,12 @@ def fit_fused_flush(res: "Resolution", num_cols: int, num_bins: int,
     block that holds as many rows a flush. One flush sums depth x block
     rows into the f32 accumulator, so the sums are the same bit for bit;
     the larger block streams faster and the shallower kernel compiles in
-    half the time (220 features of 256 bins: depth 1 at block 192 ran 2.85
-    s an iteration against 3.09 s at depth 2 and block 96, the
-    comparison's gaps identical to the last digit; PERF.md section 6,
-    PR 30). A block of 0 (the clamp took the kernel off) leaves the
-    depth to ``resolve_mbatch(cfg)``."""
+    half the time (220 features of 256 bins, PR 30's kernel: depth 1 at
+    block 192 ran 2.85 s an iteration against 3.09 s at depth 2 and block
+    96, the comparison's gaps identical to the last digit; PERF.md
+    section 6, PR 30; since PR 33 the pair is 256 and 128). A block of
+    0 (the clamp took the kernel off) leaves the depth to
+    ``resolve_mbatch(cfg)``."""
     block = clamp_fused_block(res.fused_block, num_cols, res.hist_mbatch,
                               res.hist_layout, num_bins, num_features,
                               env_override)

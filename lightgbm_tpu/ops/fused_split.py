@@ -44,6 +44,20 @@ whole walk:
   * the SMALLER child's histogram accumulates in VMEM whenever that stream
     flushes a full block — histogram work is n_smaller rows exactly, like the
     reference's smaller-leaf trick (serial_tree_learner.cpp:404);
+  * what the histogram needs of a row (each feature's bin, the gradient,
+    hessian and count words, the mask) is held with the block's rows along
+    LANES too (PR 33): one int8 identity contraction, the routing pick's
+    own form, transposes the block's byte columns into the pending ring as
+    [HR, bs] i32 rows (the block is the MXU's transposed weight load, no
+    XLU); a feature's one-hot is its bin ROW against a sublane iota,
+    [bins, bs], and the channel operand is assembled on [1, bs] rows and
+    born [8, bs]. Held one row a sublane (through PR 32) every feature's
+    bin column cost one XLU lane broadcast per 8 rows, 8 cycles each on 3
+    units: F / 3 cycles a histogrammed row whatever its bins (9.1 ns on
+    higgs at 255 bins, 10.7 at 63, 58 at 220 features; 5.2, 1.7 and 37.5
+    since), the XLU 94% full and the MXU 18%; the flush of 2 x 384 rows
+    was 7,379 bundles and is 4,005, with no lane broadcast, select, value
+    pack or spill in it (hist_contract; PERF.md section 6, PR 33);
   * `mode=1` turns the kernel into a plain segment histogram (used for the
     root), skipping all partition work.
 
@@ -75,27 +89,26 @@ would flush as 128, and every such slot is one a blend replaces or that
 lands in dead bytes. Histogram channels use the same hi/lo-bf16
 split as ops/pallas_histogram.py: counts exact, grad/hess ~2^-17 relative.
 
-Batched-M histogram pipeline (round 6; measured on the chip in PR 29: no
-speed at any depth, a tenfold loss at the old default of 8):
-the histogram contraction's output has only 8 rows (the channel count), so
-a per-block issue runs at M=8 of the MXU's 128 rows. With ``mbatch`` = K > 1
-the kernel stages K row blocks (bins + TRANSPOSED [8, bs] channel operands)
-in a pending ring and issues ONE contraction per feature group with a
-block-diagonal [8K, K*bs] channel LHS against the K blocks' row-concatenated
-one-hots — M = 8K MXU rows per issue, the TPU analogue of the reference CUDA
-constructor accumulating many row-blocks per launch
-(cuda_histogram_constructor.cu:17-68). The drain flushes the ``pushes % K``
-remainder exactly (stale slots zero out on the channel side). That was the
-design's argument. On a v5e it does not hold inside this kernel (PERF.md
-section 6, PR 29: higgs, 10.5M x 28, 255 leaves, block 384): K = 1 / 2 / 4
-train at 0.932 / 0.939 / 0.949 s an iteration, each deeper ring a little
-slower, and K = 6 / 7 / 8 / 16 at 6.6 / 8.3 / 9.7 / 25.3 s. Once one flush
-spans K x bs >= 2048 rows (K = 8 at bs = 192 does not, and runs like K = 1)
-the walk pays 60-96 ns for every row a split STREAMS, histogrammed or not;
-``hist_debug="sync"`` at K = 8's VMEM sizing runs like K = 1, so it is the
-flush's code in the loop body and not the ring's residency. What the ring
-does buy is fewer roundings: a flush folds K blocks' partial sums into the
-f32 accumulator in one addition, and at 63 bins K = 2 halves the worst
+The pending ring (round 6's batched-M pipeline; measured on the chip in
+PR 29: no speed at any depth, a tenfold loss at the old default of 8): with
+``mbatch`` = K > 1 the kernel stages K row blocks (transposed bins + [8, bs]
+channel operands) and contracts them together, once per K pushes; the drain
+flushes the ``pushes % K`` remainder exactly (stale slots zero out on the
+channel side). The design's argument was the MXU's rows: the contraction's
+output has 8 rows (the channel count), and a block-diagonal [8K, K*bs]
+channel operand against the K blocks' concatenated one-hots issued M = 8K
+(the TPU analogue of the reference CUDA constructor accumulating many
+row-blocks per launch, cuda_histogram_constructor.cu:17-68). On a v5e it
+never held inside this kernel: the one-hot is the MXU's WEIGHTS, a tile of
+it is loaded once whatever streams through it, and K = 1 / 2 / 4 trained at
+0.932 / 0.939 / 0.949 s an iteration (PERF.md section 6, PR 29: higgs,
+10.5M x 28, 255 leaves, block 384), K = 6 / 7 / 8 / 16 at 6.6 / 8.3 / 9.7 /
+25.3 s: past 8 MB of kernel text (PR 30) the walk pays 60-96 ns for every
+row a split STREAMS, histogrammed or not. Since PR 33 a flush contracts
+each staged block with its own [8, bs] operand (the same products and the
+same sums without the block diagonal's zeros). What the ring does buy is
+fewer roundings: a flush folds K blocks' partial sums before the one
+addition into the f32 accumulator, and at 63 bins K = 2 halves the worst
 leaf's hessian error against K = 1 (1.8e-4 against 3.4e-4 relative). So a
 fused entry runs K = 2, 0.7% slower than K = 1, unless the user or
 LGBM_TPU_HIST_MBATCH names a depth (engines/registry.py FUSED_MBATCH). The
@@ -126,52 +139,60 @@ _A = 32  # row alignment every DMA offset is provably divisible by
 # and the read-modify-write block as bytes (7 bs*C, 8 in the copy-back
 # variant), and the two ring carries as 32-bit words (8 bs*C; 16 before
 # PR 31). 49152 is the empirical bs*C product the round-3 kernel tolerated
-# on v5e with the taller carries; the cap stays where the cells' blocks
-# were measured. The batched-M pending ring (hist_flush) ADDS
-# mbatch-proportional residency: the staged bin blocks, the transposed
-# channel slots, and the per-feature-group one-hot + block-diagonal
-# transients of the ONE big contraction — so the block size must shrink as
-# the ring deepens, bounded by _VMEM_RING_BUDGET.
+# on v5e with the taller carries, 23 bytes a cell; the cap stays where the
+# cells' blocks were measured, with one exception. The histogram half holds
+# a block's rows along LANES (PR 33): a block that leaves a lane tile part
+# empty (192 rows: 256-byte records at the cap) sends the whole one-hot
+# down the compiler's select-and-pack path instead of the masked weight
+# load, 60 cycles a row against 36 at 220 features. Such a block takes
+# the next multiple of 128 where the stream's buffers, 15 bytes a cell
+# now, stay under what the cap held when it was measured
+# (fused_block_cap). The pending ring (hist_accum) ADDS
+# mbatch-proportional residency: the staged transposed blocks, the
+# channel slots, and the per-feature-group one-hot of a contraction — so
+# the block size must shrink as the ring deepens, bounded by
+# _VMEM_RING_BUDGET.
 _VMEM_STREAM_CAP = 49152
+_VMEM_STREAM_BYTES = 23 * _VMEM_STREAM_CAP
+_STREAM_BYTES_A_CELL = 15
 _VMEM_RING_BUDGET = 4 << 20
+_LANES = 128
 
 
 def fused_ring_bytes(block_size: int, num_cols: int, mbatch: int,
                      quant: bool = False, hist_layout: str = "lane") -> int:
     """Scoped-VMEM bytes of the pending ring + its flush transients.
 
-    Counted per slot: the [bs, C] u8 bin block (``num_cols`` already
-    reflects the nibble-packed width under RowLayout.packed4 — the packed
-    layout halves this term, it does not escape the accounting), the
-    channel operand, the row-concatenated one-hot of one feature group
-    (<= 512 lanes bf16, which covers the int8 layout too), and the
-    block-diagonal channel operand of the batched contraction.
-
-    ``hist_layout``: the lane layout stages channels TRANSPOSED [8, bs]
-    (bf16 padded to 16 sublanes / int8 to 32); the sublane layout stages
-    them row-major [bs, 8], which the VMEM tiling pads to the full
-    128-lane width — a 4-8x larger channel-slot term that must be charged,
-    not assumed away."""
+    Counted per slot: the block TRANSPOSED, its byte columns as [C, bs]
+    i32 rows (``num_cols`` already reflects the nibble-packed width under
+    RowLayout.packed4 — the packed layout halves this term, it does not
+    escape the accounting; the kernel stages only the columns the
+    histogram reads, this charges them all), the [8, bs] channel operand
+    (bf16 padded to 16 sublanes / int8 to 32), and the one-hot of one
+    feature group (<= 512 bins, bf16, which covers the int8 layout too).
+    Both ``hist_layout`` values stage the same operands."""
+    del hist_layout
     elt = 1 if quant else 2
-    bins = mbatch * block_size * num_cols
-    if hist_layout == "sublane":
-        cht = mbatch * block_size * 128 * elt
-    else:
-        cht = mbatch * (32 if quant else 16) * block_size * elt
+    bins = 4 * mbatch * block_size * num_cols
+    cht = mbatch * (32 if quant else 16) * block_size * elt
     oh = mbatch * block_size * 512 * elt
-    diag = 8 * mbatch * mbatch * block_size * elt
-    return bins + cht + oh + diag
+    return bins + cht + oh
 
 
 def fused_block_cap(num_cols: int, mbatch: int, quant: bool = False,
                     hist_layout: str = "lane", num_features: int = 0,
                     num_bins: int = 0) -> int:
-    """Largest 32-multiple block size whose streaming buffers AND pending
-    ring fit the scoped-VMEM caps (the automatic derivation and the
-    LGBM_TPU_FUSED_BS clamp both go through here) and, where the caller
-    says how many features of how many bins the rows hold, whose flush
-    stays inside ``_FLUSH_ONEHOT_ROWS``."""
-    bs = max(32, (_VMEM_STREAM_CAP // max(num_cols, 1)) // 32 * 32)
+    """Largest block size whose streaming buffers AND pending ring fit the
+    scoped-VMEM caps (the automatic derivation and the LGBM_TPU_FUSED_BS
+    clamp both go through here) and, where the caller says how many
+    features of how many bins the rows hold, whose flush stays inside
+    ``_FLUSH_ONEHOT_ROWS``: a multiple of 128 rows (whole lane tiles)
+    from 128 up, of 32 below."""
+    cols = max(num_cols, 1)
+    bs = max(32, (_VMEM_STREAM_CAP // cols) // 32 * 32)
+    full = _round_up(bs, _LANES)
+    if _STREAM_BYTES_A_CELL * full * cols <= _VMEM_STREAM_BYTES:
+        bs = full
     while bs > 32 and fused_ring_bytes(bs, num_cols, mbatch, quant,
                                        hist_layout) > _VMEM_RING_BUDGET:
         bs -= 32
@@ -180,25 +201,27 @@ def fused_block_cap(num_cols: int, mbatch: int, quant: bool = False,
         groups = -(-f_pad // group)
         bs = min(bs, max(32, _FLUSH_ONEHOT_ROWS
                          // (groups * max(1, mbatch)) // 32 * 32))
-    return bs
+    return bs // _LANES * _LANES if bs > _LANES else bs
 
 # most per-feature one-hot compare tiles a matmul group may hold at once
 # (see _hist_packing)
 _MAX_GROUP_TILES = 8
 
 # The flush's feature loop is unrolled, and Mosaic unrolls every vector
-# operation over its registers: the kernel's text, and the stack slot each
-# matmul group's one-hot holds, grow with groups x depth x block, the rows
-# of one-hot one flush builds. Past 8 MB or so of text every streamed row
-# pays, histogrammed or not (PERF.md section 6, PR 30; the v5e compiler's
-# generated_code_size_in_bytes beside the chip's ns a parent row):
-#   14 groups x 2 x 384 = 10,752: 3.6 MB, 4.9 ns    14 x 4 x 384: 6.7 MB, 4.9
-#   69 x 2 x 192 = 26,496: 7.5 MB, clean            14 x 8 x 256 = 28,672: 82
-#   14 x 6 x 384 = 32,256: 9.6 MB, 62 ns            14 x 8 x 384: 12.6 MB, 96
-#   110 x 2 x 192 = 42,240: 12.0 MB, 183 ns, and 23 MB of stack against the
-#   compiler's 16 MB (refused outright before the block was bounded)
-# The largest that has run clean is the bound (fused_block_cap).
-_FLUSH_ONEHOT_ROWS = 26_496
+# operation over its registers: the kernel's text grows with groups x depth
+# x block, the rows of one-hot one flush builds. Past 8 MB or so of text
+# every streamed row pays, histogrammed or not (PERF.md section 6, PR 30:
+# the v5e compiler's generated_code_size_in_bytes beside the chip's ns a
+# parent row; clean at 7.5 MB and under, 62-183 ns from 8.6 MB). With the
+# rows along lanes (PR 33) a row of one-hot is a compare, a mask pack and
+# a masked weight load, no select, pack or spill, and costs half the text
+# it did (rows of one-hot: MB now; MB through PR 32):
+#   14 groups x 2 x 384 = 10,752: 1.97; 3.51  14 x 4 x 384 = 21,504: 3.50; 6.63
+#   110 x 1 x 256 = 28,160: 3.69 (istella)      69 x 2 x 256 = 35,328: 5.71
+#   14 x 8 x 384 = 43,008: 6.62; 12.61          110 x 2 x 256 = 56,320: 8.94
+# so 7.5 MB is near 48,000 rows now. The bound is the largest flush that
+# has RUN clean in this form (fused_block_cap), not that estimate.
+_FLUSH_ONEHOT_ROWS = 28_160
 
 # sp scalar-prefetch vector layout (i32[16])
 _MODE, _BASE_T, _PHI, _COUNT, _NLEFT, _FEAT, _BIN, _DLEFT, _NANBIN, _ISCAT, \
@@ -243,23 +266,30 @@ def _hist_packing(f: int, b: int):
     return stride, f_pad, group
 
 
-def _assemble_f32(blk_i32, off: int):
-    """4 u8 lanes at static offset ``off`` -> f32 column [BS, 1].
+def _hist_rows(layout: RowLayout) -> int:
+    """Byte columns of a row record the histogram reads (the bins and the
+    gradient, hessian and count words), rounded up to whole int8 tiles:
+    the rows of a block's transposed form."""
+    return min(layout.num_cols, _round_up(layout.feat_cols + 12, 32))
+
+
+def _assemble_f32(rows_ref, t, off: int):
+    """4 byte ROWS at static offset ``off`` of slot ``t`` of a transposed
+    [K, HR, bs] i32 block ref -> the f32 words as a [1, bs] row.
 
     Assembles via multiplies, NOT shifts: Mosaic miscompiles `<< 16` on
     values cast from u8 (observed on v5e: some lanes come back zero), while
     integer multiply wraps correctly — byte3 * 2^24 overflowing into the sign
     bit is exactly the bit pattern we want.
     """
-    w = (blk_i32[:, off:off + 1] + blk_i32[:, off + 1:off + 2] * 256
-         + blk_i32[:, off + 2:off + 3] * 65536
-         + blk_i32[:, off + 3:off + 4] * 16777216)
+    b0, b1, b2, b3 = (rows_ref[t, off + k:off + k + 1, :] for k in range(4))
+    w = b0 + b1 * 256 + b2 * 65536 + b3 * 16777216
     return lax.bitcast_convert_type(w, jnp.float32)
 
 
 def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                   hist_ref, sem_in, sem_l, sem_r, sem_aux, inbuf, lcarry,
-                  rcarry, lstage, rstage, auxbuf, pendbuf, pendch, smem, *,
+                  rcarry, lstage, rstage, auxbuf, pendT, pendch, smem, *,
                   layout: RowLayout, num_bins: int, bs: int,
                   bitset_words: int,
                   interpret: bool, dual: bool,
@@ -279,18 +309,19 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     packed4 = layout.packed4
     i32 = jnp.int32
 
-    def bin_col(bins_i32, j):
-        """Bin column of LOGICAL feature ``j`` (static) as [bs, 1] i32.
+    def bin_row(t, j):
+        """Bins of LOGICAL feature ``j`` (static) in staged slot ``t`` as a
+        [1, bs] i32 row, the block's rows along lanes.
 
-        packed4 records store two features per byte: the byte at column
+        packed4 records store two features per byte: the byte row
         j >> 1 carries feature j in the nibble selected by j & 1. The
         & 0xF mask is load-bearing — without it the neighbour feature's
         nibble rides along and every one-hot compare mismatches
         (tpulint R004 flags unmasked pack4 nibble extracts)."""
         if packed4:
-            byte = bins_i32[:, j // 2:j // 2 + 1]
+            byte = pendT[t, j // 2:j // 2 + 1, :]
             return (byte >> (4 * (j % 2))) & 0xF
-        return bins_i32[:, j:j + 1]
+        return pendT[t, j:j + 1, :]
 
     mode = sp_ref[_MODE]
     base = sp_ref[_BASE_T] * _A
@@ -402,8 +433,39 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         pltpu.make_async_copy(
             work_out.at[pl.ds(0, bs), :], auxbuf, sem_aux).wait()
 
-    def assemble_ch8(rows_u8, mask_f32):
-        """Masked rows of a [BS, C] u8 buffer -> the [BS, 8] channel operand.
+    # ---------------- histogram half: rows along lanes ----------------
+    # (module docstring) a staged block is [HR, bs] i32: a feature's bins
+    # are one ROW, which a compare against a sublane iota broadcasts along
+    # sublanes (a replicated register, no XLU), and the channel words
+    # assemble on [1, bs] rows, bs / 128 registers an operation
+    HR = pendT.shape[1]
+    lane1 = lax.broadcasted_iota(i32, (1, bs), 1)
+    sub8 = lax.broadcasted_iota(i32, (8, bs), 0)
+    sub_b = lax.broadcasted_iota(i32, (BS_, bs), 0)
+    eye_h = (lax.broadcasted_iota(i32, (HR, C), 0)
+             == lax.broadcasted_iota(i32, (HR, C), 1)).astype(jnp.int8)
+    # quant: int8 one-hot x int8 packed channels -> int32 (exact, 2x MXU
+    # rate); f32: bf16 one-hot with f32 accumulation
+    cht = jnp.int8 if quant else jnp.bfloat16
+    acc_t = jnp.int32 if quant else jnp.float32
+    oh_src = jnp.int32 if quant else jnp.float32   # the one-hot, uncast
+    one, zero = jnp.ones((), oh_src), jnp.zeros((), oh_src)
+    _, _, group_w = _hist_packing(F, B)   # matmul group width (features)
+
+    def stage_block(t, rows_u8):
+        """Transpose a [bs, C] u8 block's first HR byte columns into
+        slot ``t`` of the pending ring: eye[HR, C] x (byte - 128)[bs, C]^T
+        on int8, the partition's own `pick` form. The block is the MXU's
+        transposed weight load (``vmatpush.s8.xpose``), HR streamed rows
+        a block; exact (one nonzero a sum, i32 accumulation)."""
+        pendT[t] = lax.dot_general(
+            eye_h, flip_offset(rows_u8, jnp.int8),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=i32) + 128                   # [HR, bs]
+
+    def assemble_chT(t, mask):
+        """Masked rows of staged slot ``t`` -> the [8, bs] channel operand,
+        born transposed: every operation runs on [1, bs] rows.
 
         f32 mode (bf16 output): (grad-hi, hess-hi, in-bag, raw, grad-lo,
         hess-lo, 0, 0) — the hi/lo split recovers ~f32 accuracy.
@@ -412,233 +474,126 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         hold small integer discretizer codes (exact in f32), so the hi/lo
         split collapses and the one-hot contraction runs
         int8 x int8 -> int32 at 2x the bf16 MXU rate with exact sums."""
-        rows = rows_u8.astype(i32)
-        m = mask_f32[:, None]                              # [BS, 1]
-        g = _assemble_f32(rows, layout.grad_off) * m
-        h = _assemble_f32(rows, layout.hess_off) * m
-        cw = _assemble_f32(rows, layout.cnt_off)
-        inbag = jnp.where(cw != 0.0, m, 0.0)
-        lane8 = lax.broadcasted_iota(i32, (bs, 8), 1)
+        g = _assemble_f32(pendT, t, layout.grad_off) * mask
+        h = _assemble_f32(pendT, t, layout.hess_off) * mask
+        cw = _assemble_f32(pendT, t, layout.cnt_off)
+        inbag = jnp.where(cw != 0.0, mask, 0.0)
         if quant:
-            chq = [g, h, inbag, m]
-            ch8 = jnp.zeros((bs, 8), jnp.float32)
-            for k, c in enumerate(chq):
-                ch8 = ch8 + jnp.where(lane8 == k, c, 0.0)
-            # f32 -> int8 is exact: codes are integers with |code| <= 127
-            return ch8.astype(i32).astype(jnp.int8)
-        if interpret:
-            # interpret mode traces through XLA, where
-            # --xla_allow_excess_precision elides f32->bf16->f32 as identity
-            # (zeroing the lo channels); reduce_precision is not elidable
-            ghi = lax.reduce_precision(g, exponent_bits=8, mantissa_bits=7)
-            hhi = lax.reduce_precision(h, exponent_bits=8, mantissa_bits=7)
+            chans = [g, h, inbag, mask]
         else:
-            # Mosaic has no reduce_precision lowering and does not elide the
-            # round-trip today (verified on v5e)
-            ghi = g.astype(jnp.bfloat16).astype(jnp.float32)
-            hhi = h.astype(jnp.bfloat16).astype(jnp.float32)
-        chans = [ghi, hhi, inbag, m, g - ghi, h - hhi,
-                 jnp.zeros_like(g), jnp.zeros_like(g)]
-        ch8 = jnp.zeros((bs, 8), jnp.float32)
+            if interpret:
+                # interpret mode traces through XLA, where
+                # --xla_allow_excess_precision elides f32->bf16->f32 as
+                # identity (zeroing the lo channels); reduce_precision is
+                # not elidable
+                ghi, hhi = (lax.reduce_precision(x, exponent_bits=8,
+                                                 mantissa_bits=7)
+                            for x in (g, h))
+            else:
+                # Mosaic has no reduce_precision lowering and does not
+                # elide the round-trip today (verified on v5e)
+                ghi, hhi = (x.astype(jnp.bfloat16).astype(jnp.float32)
+                            for x in (g, h))
+            chans = [ghi, hhi, inbag, mask, g - ghi, h - hhi]
+        ch = jnp.zeros((8, bs), jnp.float32)
         for k, c in enumerate(chans):
-            ch8 = ch8 + jnp.where(lane8 == k, c, 0.0)
-        return ch8.astype(jnp.bfloat16)
+            ch = jnp.where(sub8 == k, c, ch)
+        if quant:
+            # f32 -> int8 is exact: codes are integers with |code| <= 127
+            return ch.astype(i32).astype(jnp.int8)
+        return ch.astype(jnp.bfloat16)
 
-    def hist_matmuls(rows_u8, ch8):
-        """One-hot contraction of a block's bins against its channel
-        operand, accumulated into hist_ref.
+    def hist_contract(slots):
+        """One-hot contraction of staged blocks against their channel
+        operands, accumulated into hist_ref. ``slots``: (ring slot,
+        [8, bs] channel operand) pairs.
 
-        The one-hot for a feature group is built as a per-feature compare
-        of that feature's bin column against a [BS, BS_] lane iota, with
-        the per-feature results concatenated group-wide so each group is
-        contracted in ONE MXU matmul (grouping bounds the one-hot operand
-        near 512 lanes, see _hist_packing). The channel operand has 8
-        rows, so every 128 x 128 tile of one-hot is loaded into the MXU
-        to stream eight rows through it: F x B / 16,384 tile loads a row
-        are what a histogram row costs (0.44 on higgs, 3.44 at 220
-        features; 9.1 and 58.8 ns on the chip). A jnp.repeat-based
-        batched lane spread in place of the per-feature compare loop
-        lowers to far slower relayouts on this Mosaic toolchain (0.54 vs
-        1.07 it/s on the 10.5M higgs bench)."""
-        bins = rows_u8.astype(i32)[:, :layout.feat_cols]
-        # tightly packed: each feature spans B lanes (not 128-padded), so
-        # B <= 64 fits 2+ features per lane tile; group widths and offsets
-        # stay 128-aligned via the align unit from _hist_packing
-        _, _, w = _hist_packing(F, B)   # group width (features)
-        iota_b = lax.broadcasted_iota(i32, (bs, BS_), 1)
-        zero_col = jnp.full((bs, 1), -1, i32)   # matches no bin lane
-        # quant: int8 one-hot x int8 packed channels -> int32 (exact, 2x
-        # MXU rate); f32: bf16 one-hot with f32 accumulation
-        oh_t = jnp.int8 if quant else jnp.bfloat16
-        acc_t = jnp.int32 if quant else jnp.float32
+        A feature's one-hot is born transposed, [BS_, bs]: its bin row
+        against a sublane iota, one compare a register and no lane
+        broadcast. A group's features concatenate along sublanes (aligned
+        at every stride _hist_packing produces; grouping bounds the
+        operand near 512 bins) and the group is cast ONCE, with the
+        contraction its only reader: Mosaic then packs it straight into
+        the bf16 tile, and the v5e compiler folds compare, select and
+        pack into a masked transposed weight load (``vmpackc`` +
+        ``vmatpush.bf16.xpose.msk``). Cast a feature at a time, as through
+        PR 32, the pieces take the f32 tiling and are unpacked and packed
+        again on their way to the MXU: 36% of the old flush's VALU work.
+        The one-hot stays the MXU's weights and the channels its 8
+        streamed rows, as before. What a histogram row costs now IS its
+        weight tiles: F x B x bs / 2,048 transposed pushes a block, which
+        the chip takes at 0.52 a cycle (5.2 ns a row on higgs, 1.7 at 63
+        bins, 37.5 at 220 features; they were 9.1, 10.7 and 58.4). Each
+        block's partial sums fold before the one add into the accumulator,
+        so a flush of K blocks rounds as one (module docstring).
+
+        hist_layout="sublane" (tpu_hist_layout, B <= 64) swaps the roles
+        of the same two operands: the one-hot streams and the channels
+        are the weights, so the output lands BIN-major [group, 8]."""
+        sublane = hist_layout == "sublane"
         fc = 0
         while fc < F_pad:
-            wc = min(w, F_pad - fc)
-            oh = jnp.concatenate(
-                [((bin_col(bins, fc + j) if fc + j < F else zero_col)
-                  == iota_b).astype(oh_t)
-                 for j in range(wc)], axis=1)            # [BS, wc*BS_]
-            part = lax.dot_general(
-                ch8, oh, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=acc_t)            # [8, wc*BS_]
-            hist_ref[:, fc * BS_:(fc + wc) * BS_] += part
+            wc = min(group_w, F_pad - fc)
+            red = None
+            for t, chT in slots:
+                ohT = jnp.concatenate(
+                    [jnp.where(sub_b == bin_row(t, fc + j), one, zero)
+                     if fc + j < F else jnp.zeros((BS_, bs), oh_src)
+                     for j in range(wc)], axis=0).astype(cht)  # [wc*BS_, bs]
+                lhs, rhs = (ohT, chT) if sublane else (chT, ohT)
+                part = lax.dot_general(
+                    lhs, rhs, dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=acc_t)
+                red = part if red is None else red + part
+            if sublane:
+                hist_ref[fc * BS_:(fc + wc) * BS_, :] += red   # [wc*BS_, 8]
+            else:
+                hist_ref[:, fc * BS_:(fc + wc) * BS_] += red   # [8, wc*BS_]
             fc += wc
-
-    cht = jnp.int8 if quant else jnp.bfloat16
-    eye_bs = (io2 == jo2).astype(cht)   # transpose-by-matmul identity
-
-    def transpose_ch(ch8):
-        """[bs, 8] channel operand -> [8, bs] via an identity contraction.
-
-        Mosaic relayout transposes are catastrophically slow on this
-        toolchain (see hist_matmuls), so the transpose rides the MXU:
-        ch8^T = ch8^T @ I. Exact: one nonzero per output element, i32
-        accumulation for int8 codes / f32 for bf16 channels (whose values
-        are already bf16-representable, so the round-trip cast is exact)."""
-        if quant:
-            return lax.dot_general(
-                ch8, eye_bs, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=i32).astype(jnp.int8)
-        return lax.dot_general(
-            ch8, eye_bs, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(jnp.bfloat16)
 
     def hist_flush(n_valid):
-        """ONE batched one-hot contraction per feature group over the first
-        ``n_valid`` staged blocks of the pending ring (the batched-M
-        tentpole): the staged transposed channel operands form a
-        block-diagonal [8K, K*bs] LHS and the staged blocks' one-hots
-        concatenate row-wise into a [K*bs, group] RHS, so each MXU issue
-        carries M = 8*mbatch output rows (64-128 at K=8-16) instead of 8.
-        The K per-block partial sums come back stacked on the sublane axis
-        and reduce with K-1 vector adds. Slots past ``n_valid`` (a partial
-        drain, or stale data from a previous ring wrap) are zeroed on the
-        channel side, so whatever their bins one-hot into contributes
-        exactly zero — counts stay bit-identical to the K=1 sync path and
-        int32 quantized sums stay exact.
+        """Contract the first ``n_valid`` staged blocks of the pending
+        ring. Slots past ``n_valid`` (a partial drain, or stale data from
+        a previous ring wrap) are zeroed on the channel side, so whatever
+        their bins one-hot into contributes exactly zero — counts stay
+        bit-identical to the K=1 sync path and int32 quantized sums stay
+        exact."""
+        staged = [pendch[t] for t in range(mbatch)]
+        hist_contract([(t, jnp.where(n_valid > t, ch, jnp.zeros_like(ch)))
+                       for t, ch in enumerate(staged)])
 
-        hist_layout="sublane" (tpu_hist_layout, the B <= 64 Mosaic
-        layout): the SAME staged operands contract with swapped roles —
-        channels stay row-major [bs, 8] (no transpose matmul per push),
-        tile into the [K*bs, 8K] lane-banded RHS, and the one-hot LHS
-        contracts over its sublane axis, so the output lands BIN-major
-        [group, 8K] with bins along sublanes; the K row-window partials
-        sit in lane bands and reduce with K-1 adds of [group, 8] slices.
-        Counts/int32 sums stay bit-identical (same products, regrouped)."""
-        bins_k = [pendbuf[t].astype(i32)[:, :layout.feat_cols]
-                  for t in range(mbatch)]
-        _, _, w = _hist_packing(F, B)
-        iota_b = lax.broadcasted_iota(i32, (bs, BS_), 1)
-        zero_col = jnp.full((bs, 1), -1, i32)
-        oh_t = jnp.int8 if quant else jnp.bfloat16
-        acc_t = jnp.int32 if quant else jnp.float32
-
-        def group_ohs(fc, wc):
-            return [jnp.concatenate(
-                [((bin_col(bins, fc + j) if fc + j < F else zero_col)
-                  == iota_b).astype(oh_t)
-                 for j in range(wc)], axis=1)             # [bs, wc*BS_]
-                for bins in bins_k]
-
-        if hist_layout == "sublane":
-            bands = []
-            for t in range(mbatch):
-                chR = pendch[t]                           # [bs, 8]
-                chR = jnp.where(n_valid > t, chR, jnp.zeros_like(chR))
-                parts = []
-                if t:
-                    parts.append(jnp.zeros((bs, t * 8), cht))
-                parts.append(chR)
-                if mbatch - 1 - t:
-                    parts.append(jnp.zeros((bs, (mbatch - 1 - t) * 8), cht))
-                bands.append(parts[0] if len(parts) == 1
-                             else jnp.concatenate(parts, axis=1))
-            ch_bd = (bands[0] if mbatch == 1
-                     else jnp.concatenate(bands, axis=0))  # [K*bs, 8K]
-            fc = 0
-            while fc < F_pad:
-                wc = min(w, F_pad - fc)
-                ohs = group_ohs(fc, wc)
-                oh = ohs[0] if mbatch == 1 \
-                    else jnp.concatenate(ohs, axis=0)      # [K*bs, wc*BS_]
-                part = lax.dot_general(
-                    oh, ch_bd, dimension_numbers=(((0,), (0,)), ((), ())),
-                    preferred_element_type=acc_t)          # [wc*BS_, 8K]
-                red = part[:, 0:8]
-                for t in range(1, mbatch):
-                    red = red + part[:, 8 * t:8 * (t + 1)]
-                hist_ref[fc * BS_:(fc + wc) * BS_, :] += red
-                fc += wc
-            return
-
-        blocks = []
-        for t in range(mbatch):
-            chT = pendch[t]                               # [8, bs]
-            chT = jnp.where(n_valid > t, chT, jnp.zeros_like(chT))
-            parts = []
-            if t:
-                parts.append(jnp.zeros((8, t * bs), cht))
-            parts.append(chT)
-            if mbatch - 1 - t:
-                parts.append(jnp.zeros((8, (mbatch - 1 - t) * bs), cht))
-            blocks.append(parts[0] if len(parts) == 1
-                          else jnp.concatenate(parts, axis=1))
-        ch_diag = (blocks[0] if mbatch == 1
-                   else jnp.concatenate(blocks, axis=0))  # [8K, K*bs]
-        fc = 0
-        while fc < F_pad:
-            wc = min(w, F_pad - fc)
-            ohs = group_ohs(fc, wc)
-            oh = ohs[0] if mbatch == 1 else jnp.concatenate(ohs, axis=0)
-            part = lax.dot_general(
-                ch_diag, oh, dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=acc_t)             # [8K, wc*BS_]
-            red = part[0:8]
-            for t in range(1, mbatch):
-                red = red + part[8 * t:8 * (t + 1)]
-            hist_ref[:, fc * BS_:(fc + wc) * BS_] += red
-            fc += wc
-
-    def hist_accum(rows_u8, mask_f32):
-        """Batched-M histogram push: the block's channel operand is
-        assembled and transposed NOW (VPU chain + one tiny M=8 matmul),
-        staged into the K-deep pending ring, and the one-hot contractions
-        issue once per K pushes as ONE M=8K matmul per feature group
-        (hist_flush) — both deferring the MXU work off the assembly's
-        critical path (the round-5 double buffer's job, measured ~0.6
-        s/tree on v5e) and filling the MXU rows the M=8 issue wasted."""
+    def hist_accum(rows_u8, mask):
+        """Histogram push: the block is transposed into the pending ring
+        and its channel operand assembled NOW; the one-hot contractions
+        issue once per K pushes (hist_flush), each block's partial sums
+        folded before they meet the accumulator. ``mask``: [1, bs] f32,
+        the block's rows that count."""
         if hist_debug == "off":
             return  # timing bisect: histograms disabled (results invalid)
-        if hist_debug == "assembly":
-            ch8 = assemble_ch8(rows_u8, mask_f32)
-            ones = jnp.ones((bs, 128), jnp.bfloat16)
-            hist_ref[:, 0:128] += lax.dot_general(
-                ch8, ones, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return
-        if hist_debug == "matmul":
-            hist_matmuls(rows_u8, jnp.ones((bs, 8), jnp.bfloat16))
-            return
-        if hist_debug == "matmul2":
-            # data-dependent but trivially cheap ch8: defeats constant
-            # folding/hoisting so the matmuls' true cost is measured
-            cheap = (rows_u8[:, :8].astype(i32) + 1).astype(jnp.bfloat16)
-            hist_matmuls(rows_u8, cheap)
-            return
-        if hist_debug == "sync":
-            # the pre-pipelining, pre-batching behavior (timing comparison)
-            hist_matmuls(rows_u8, assemble_ch8(rows_u8, mask_f32))
+        if hist_debug:
+            # timing bisect probes: the pre-batching behavior, slot 0 only
+            stage_block(0, rows_u8)
+            if hist_debug == "assembly":
+                hist_ref[:, 0:128] += lax.dot_general(
+                    assemble_chT(0, mask), jnp.ones((128, bs), jnp.bfloat16),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            elif hist_debug == "matmul":
+                hist_contract([(0, jnp.ones((8, bs), jnp.bfloat16))])
+            elif hist_debug == "matmul2":
+                # data-dependent but trivially cheap channels: defeats
+                # constant folding/hoisting so the matmuls' true cost is
+                # measured
+                hist_contract([(0, (pendT[0, 0:8, :] + 1)
+                                .astype(jnp.bfloat16))])
+            else:   # "sync"
+                hist_contract([(0, assemble_chT(0, mask))])
             return
 
         pushes = smem[_PEND]
         cur = lax.rem(pushes, mbatch)
-        pendbuf[cur] = rows_u8
-        if hist_layout == "sublane":
-            # bins-on-sublanes flush contracts row-major channels — the
-            # per-push transpose matmul disappears entirely
-            pendch[cur] = assemble_ch8(rows_u8, mask_f32)
-        else:
-            pendch[cur] = transpose_ch(assemble_ch8(rows_u8, mask_f32))
+        stage_block(cur, rows_u8)
+        pendch[cur] = assemble_chT(cur, mask)
         smem[_PEND] = pushes + 1
 
         @pl.when(cur == mbatch - 1)
@@ -701,7 +656,7 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         @pl.when(do_hist)
         def _():
             hist_accum(stage[slot], jnp.logical_and(
-                iota >= h0, iota < n_valid).astype(jnp.float32))
+                lane1 >= h0, lane1 < n_valid).astype(jnp.float32))
         smem[cslot] = cnt + 1
 
     def drain(stream):
@@ -733,7 +688,7 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
 
         @pl.when(mode == 1)
         def _():
-            g_idx = base + i * bs + iota
+            g_idx = base + i * bs + lane1
             in_seg = jnp.logical_and(g_idx >= start, g_idx < start + count)
             hist_accum(blk_u8, in_seg.astype(jnp.float32))
 
@@ -995,18 +950,21 @@ def fused_split(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One fused split. Returns (work', scratch', hist_smaller [F, B, 4]);
     the histogram is int32 when ``quant`` (quantized-gradient codes,
-    int8 x int8 -> int32 contraction — see assemble_ch8).
+    int8 x int8 -> int32 contraction — see assemble_chT).
 
     ``mbatch`` (env/param ``tpu_hist_mbatch``) is the depth of the
-    histogram pending ring: K staged row blocks issue ONE one-hot
-    contraction per feature group with M = 8K MXU rows (hist_flush)
-    instead of K matmuls at M = 8. K = 1 is the reference path; the
-    engine registry hands a fused entry K = 2 by default: on the chip
-    every deeper ring measured slower, K = 8 by a factor of ten, and
-    K = 2 buys half the roundings for 0.7% (module docstring). Counts and int32 histograms are bit-identical at any K;
-    bf16 grad/hess within ~2^-17 relative — the f32 accumulation
-    regroups. The ring multiplies histogram-side VMEM residency by K, so
-    callers must size ``block_size`` through :func:`fused_block_cap`.
+    histogram pending ring: K staged row blocks are contracted together,
+    their partial sums folded before the one addition into the f32
+    accumulator (hist_flush). K = 1 is the reference path; the engine
+    registry hands a fused entry K = 2 by default: on the chip every
+    deeper ring measured slower, K = 8 by a factor of ten, and K = 2 buys
+    half the roundings for 0.7% (module docstring). Counts and int32
+    histograms are bit-identical at any K; bf16 grad/hess within ~2^-17
+    relative — the f32 accumulation regroups. The ring multiplies
+    histogram-side VMEM residency by K, so callers must size
+    ``block_size`` through :func:`fused_block_cap`; a block of whole lane
+    tiles (a multiple of 128 rows) keeps the one-hot on the masked weight
+    load, any other multiple of 32 is correct and slower.
     The signature's default of 8 is the standalone engines' and is what
     a direct caller gets; the grower always passes the resolved depth.
 
@@ -1139,13 +1097,12 @@ def fused_split(
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # rstage
                 (pltpu.VMEM((bs, C), jnp.uint8) if dual
                  else pltpu.VMEM((2, bs, C), jnp.uint8)),   # auxbuf
-                # batched-M pending ring: K staged bin blocks + their
-                # channel operands — TRANSPOSED [8, bs] for the lane
-                # layout, row-major [bs, 8] for sublane (hist_flush)
-                pltpu.VMEM((mbatch, bs, C), jnp.uint8),   # pendbuf
-                (pltpu.VMEM((mbatch, bs, 8), ch_t)
-                 if hist_layout == "sublane"
-                 else pltpu.VMEM((mbatch, 8, bs), ch_t)),  # pendch
+                # pending ring: K staged blocks, TRANSPOSED (the byte
+                # columns the histogram reads as [HR, bs] i32 rows), and
+                # their [8, bs] channel operands (hist_accum)
+                pltpu.VMEM((mbatch, _hist_rows(layout), bs),
+                           jnp.int32),                    # pendT
+                pltpu.VMEM((mbatch, 8, bs), ch_t),        # pendch
                 pltpu.SMEM((8,), jnp.int32),
             ],
         ),

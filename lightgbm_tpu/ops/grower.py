@@ -167,10 +167,9 @@ class GrowerParams(NamedTuple):
     # key — this mirror keeps the knob visible on the params pytree)
     bin_pack4: bool = False
     # Mosaic one-hot register layout (tpu_hist_layout): "lane" = bins
-    # along lanes (channel-major output, the batched-M block-diagonal
-    # path), "sublane" = bins along sublanes for B <= 64
-    # (ops/pallas_histogram.py _hist_kernel_sublane,
-    # ops/fused_split.py hist_flush)
+    # along lanes (channel-major output), "sublane" = bins along
+    # sublanes for B <= 64 (ops/pallas_histogram.py
+    # _hist_kernel_sublane, ops/fused_split.py hist_contract)
     hist_layout: str = "lane"
     # batched-M histogram depth (env/param tpu_hist_mbatch): K row blocks
     # per one-hot contraction fill M = 8K of the 128 MXU rows. The depth

@@ -244,3 +244,74 @@ class TestFusedSplit:
                                       _run_ref(work0, b, layout, start,
                                                count, n_left, feat, bin_)[0]
                                       [start:start + n_left])
+
+
+# ------------------------------------------- the histogram's lane build
+# (bins, quant, packed4, block): every bin stride _hist_packing produces
+# (16, 64, 128-padded, 256), both channel layouts, nibble-packed bins, a
+# block of whole lane tiles and one that leaves a tile part empty
+LANE_HIST_CASES = (
+    [(b, q, False, bs) for b in (16, 64, 100, 256) for q in (False, True)
+     for bs in (128, 192)]
+    + [(16, False, True, 128), (16, True, True, 128), (16, False, True, 192)])
+
+
+def _lane_rows(rng, n, f, b, quant, packed4):
+    layout = RowLayout(num_features=f, num_extra=1, packed4=packed4)
+    binned = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    if quant:
+        g = rng.randint(-63, 64, n).astype(np.float32)
+        h = rng.randint(0, 64, n).astype(np.float32)
+    else:
+        g = rng.randn(n).astype(np.float32)
+        h = np.abs(rng.randn(n)).astype(np.float32)
+    cnt = (rng.rand(n) > 0.25).astype(np.float32)
+    work = pack_rows(jnp.asarray(binned), jnp.asarray(g), jnp.asarray(h),
+                     jnp.asarray(cnt), jnp.zeros((1, n), jnp.float32), layout,
+                     pad_rows=256)
+    return layout, binned, np.stack([g, h, cnt, np.ones_like(g)], 1), work
+
+
+def _reference_hist(binned, channels, rows, b, quant):
+    """ops/histogram.py's einsum over the selected rows: f32 at HIGHEST
+    precision, or int8 codes into int32."""
+    from lightgbm_tpu.ops.histogram import _xla_histogram
+    ch = channels[rows].astype(np.int8 if quant else np.float32)
+    return np.asarray(_xla_histogram(jnp.asarray(binned[rows]),
+                                     jnp.asarray(ch), b))
+
+
+def _assert_hist(hist, ref, quant):
+    hist = np.asarray(hist)
+    if quant:
+        assert hist.dtype == np.int32
+        np.testing.assert_array_equal(hist, ref)
+    else:
+        np.testing.assert_array_equal(hist[:, :, 2:], ref[:, :, 2:])
+        np.testing.assert_allclose(hist[:, :, :2], ref[:, :, :2], atol=2e-2)
+
+
+@pytest.mark.parametrize("mode", [0, 1])
+@pytest.mark.parametrize("b,quant,packed4,bs", LANE_HIST_CASES)
+def test_lane_histogram_against_reference(rng, b, quant, packed4, bs, mode):
+    """The histogram half's build (rows along lanes) against the XLA
+    reference: a segment whose start leaves ``phi`` head rows in its first
+    block (and ``psi`` in the right stream's), whose smaller child fills
+    whole blocks and a masked tail. Counts exact, grad/hess within the
+    hi/lo-bf16 tolerance, quantized sums exact."""
+    n, f, start, count, feat, thr = 1000, 5, 37, 901, 1, (b - 1) // 3
+    layout, binned, channels, work = _lane_rows(rng, n, f, b, quant, packed4)
+    seg = np.arange(start, start + count)
+    left = seg[binned[seg, feat] <= thr]
+    right = seg[binned[seg, feat] > thr]
+    rows = seg if mode else (left if len(left) <= len(right) else right)
+    assert start % 32 and (start + len(left)) % 32 and len(rows) > bs
+    _, _, hist = fused_split(
+        work, jnp.zeros_like(work), jnp.asarray(mode, i32),
+        jnp.asarray(start, i32), jnp.asarray(count, i32),
+        jnp.asarray(len(left), i32), jnp.asarray(feat, i32),
+        jnp.asarray(thr, i32), jnp.asarray(0, i32), jnp.asarray(0, i32),
+        jnp.asarray(0, i32), jnp.zeros((8,), jnp.uint32), layout, b, bs, 8,
+        interpret=True, num_rows=n, quant=quant, mbatch=2)
+    _assert_hist(hist, _reference_hist(binned, channels, rows, b, quant),
+                 quant)

@@ -92,13 +92,18 @@ def test_env_override_clamped_to_vmem_cap():
 
 
 # ------------------------------------------- ADVICE #4: docstring accuracy
-def test_hist_matmuls_docstring_matches_implementation():
+def test_hist_contract_docstring_matches_implementation():
+    """The one-hot's build as the code has it since PR 33 (``hist_matmuls``
+    through PR 32): a feature's bin row against a sublane iota, no lane
+    gather or broadcast, and no account of MXU tile loads as the cost."""
     src = open(os.path.join(
         REPO, "lightgbm_tpu", "ops", "fused_split.py")).read()
-    doc = re.search(r"def hist_matmuls.*?\"\"\"(.*?)\"\"\"", src,
+    assert "def hist_matmuls" not in src
+    doc = re.search(r"def hist_contract.*?\"\"\"(.*?)\"\"\"", src,
                     re.DOTALL).group(1)
     assert "constant-index lane gather" not in doc
-    assert "per-feature compare" in doc
+    assert "jnp.repeat" not in doc and "tile loads a row" not in doc
+    assert "bin row" in doc and "sublane iota" in doc
 
 
 # --------------------------------------------- bench backend-init retry

@@ -305,18 +305,22 @@ def test_fused_block_cap_accounts_for_ring_depth():
 @pytest.mark.parametrize("cols, f, b, k, block", [
     (128, 28, 256, 2, 384),      # higgs: 14 groups, 10,752 rows of one-hot
     (128, 28, 64, 2, 384),       # 63 bins: 4 groups
-    (256, 137, 256, 2, 192),     # 69 groups: the largest that ran clean
-    (256, 220, 256, 2, 96),      # 110 groups: 192 ran 183 ns a parent row
-    (256, 220, 256, 1, 192),
-    (128, 28, 256, 8, 224),      # depth 8 at 256 and 384 ran 82 and 96 ns
+    (256, 220, 256, 1, 256),     # istella: 110 groups, 28,160 rows: the bound
+    (256, 137, 256, 1, 256),     # 69 groups, whole lane tiles from 192
+    (256, 137, 256, 2, 128),     # 204 rows fit: the lane tiles below
+    (256, 220, 256, 2, 128),
+    (256, 220, 256, 4, 64),      # under a lane tile: multiples of 32
+    (128, 28, 256, 8, 128),      # 251 rows fit
     (128, 28, 256, 4, 384),
     (2048, 2000, 64, 2, 32),     # 250 groups: the floor
 ])
 def test_fused_block_cap_bounds_the_rows_of_one_hot_a_flush_unrolls(
         cols, f, b, k, block):
-    """The flush's text and stack grow with feature groups x depth x
-    block; past ``_FLUSH_ONEHOT_ROWS`` every streamed row pays (PERF.md,
-    PR 30). The cells that ran clean keep the block they had."""
+    """The flush's text grows with feature groups x depth x block; past
+    8 MB of it every streamed row pays (PERF.md, PR 30), and
+    ``_FLUSH_ONEHOT_ROWS`` is the largest flush that has run clean. From
+    128 rows up a block is whole lane tiles (PR 33): the histogram holds
+    a block's rows along lanes."""
     from lightgbm_tpu.engines.registry import clamp_fused_block
     from lightgbm_tpu.ops.fused_split import (_FLUSH_ONEHOT_ROWS,
                                               _hist_packing)
@@ -328,8 +332,23 @@ def test_fused_block_cap_bounds_the_rows_of_one_hot_a_flush_unrolls(
     _, f_pad, group = _hist_packing(f, b)
     assert (block == 32
             or -(-f_pad // group) * k * block <= _FLUSH_ONEHOT_ROWS)
+    assert block < 128 or block % 128 == 0
     # without the features the cap is the record's alone, as it was
     assert fused_block_cap(cols, k) >= block
+
+
+@pytest.mark.parametrize("cols, block", [
+    (128, 384),     # the stream cap's own block, whole lane tiles already
+    (256, 256),     # 192 at the cap: the next tile, 15 x 256 x 256 B fits
+    (384, 128),
+    (512, 128),     # 96 at the cap
+    (640, 64),      # 128 rows would pass what the cap held: multiples of 32
+])
+def test_fused_block_cap_takes_whole_lane_tiles(cols, block):
+    from lightgbm_tpu.ops.fused_split import (_STREAM_BYTES_A_CELL,
+                                              _VMEM_STREAM_BYTES)
+    assert fused_block_cap(cols, 1) == block
+    assert _STREAM_BYTES_A_CELL * block * cols <= _VMEM_STREAM_BYTES
 
 
 # ------------------------------------------------------ steady-state guard

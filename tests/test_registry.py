@@ -271,17 +271,18 @@ def test_resolve_without_shape_keeps_explicit_layout():
 @pytest.mark.parametrize("features, bins, cols, cfg, env, want", [
     (28, 255, 128, {}, "", (384, 2)),         # higgs keeps its block and depth
     (28, 63, 128, {}, "", (384, 2)),
-    (137, 255, 256, {}, "", (192, 2)),        # 69 groups: at the bound
-    # 110 groups: depth 2 would be cut to block 96; the same 192 rows a
-    # flush at depth 1 are the same sums through a block twice as large
-    (220, 255, 256, {}, "", (192, 1)),
+    # 69 and 110 groups: depth 2 would be cut to block 128; the same 256
+    # rows a flush at depth 1 are the same sums through a block twice as
+    # large (whole lane tiles: PR 33)
+    (137, 255, 256, {}, "", (256, 1)),
+    (220, 255, 256, {}, "", (256, 1)),
     # a depth somebody named is kept, and the block pays for it
-    (220, 255, 256, {"tpu_hist_mbatch": 2}, "", (96, 2)),
-    (220, 255, 256, {"tpu_hist_mbatch": 4}, "", (32, 4)),
+    (220, 255, 256, {"tpu_hist_mbatch": 2}, "", (128, 2)),
+    (220, 255, 256, {"tpu_hist_mbatch": 4}, "", (64, 4)),
     # so is a block from the environment, inside the same bound
     (220, 255, 256, {}, "96", (96, 2)),
-    (220, 255, 256, {}, "384", (96, 2)),
-    (28, 255, 128, {"tpu_hist_mbatch": 8}, "", (224, 8)),
+    (220, 255, 256, {}, "384", (128, 2)),
+    (28, 255, 128, {"tpu_hist_mbatch": 8}, "", (128, 8)),
 ])
 def test_fit_fused_flush_trades_the_default_depth_for_the_block(
         features, bins, cols, cfg, env, want):
@@ -303,7 +304,7 @@ CELLS = {
     "higgs_b63_train": (registry.DatasetShape(10_500_000, 28, 63), 128,
                         ("fused_lane", 384, 2)),
     "istella_train": (registry.DatasetShape(7_325_625, 220, 255), 256,
-                      ("fused_lane", 192, 1)),
+                      ("fused_lane", 256, 1)),
 }
 
 

@@ -17,9 +17,10 @@ described-device executable cannot be read back from the persistent
 compilation cache, so the cache is switched off around the compiles.
 
 Tier-1 keeps the standalone histogram kernel (split and int8 at higgs
-width, a few seconds each) and ONE fused case (higgs, dual, at the depth
-the registry resolves for a fused entry, ~13 s); the other fused variants
-and the whole step programs are ``slow``
+width, a few seconds each) and the three cells' fused kernels at the block
+and depth the registry fits (higgs ~9 s and its text under the cliff, 63
+bins ~5 s, 220 features ~21 s); the other fused variants and the whole
+step programs are ``slow``
 (run them before spending chip time on a change to a kernel, its clamp, or
 the step: ``pytest tests/test_tpu_compile.py -m 'slow or not slow'``).
 """
@@ -162,23 +163,53 @@ def _fused_compile(one_chip, f, b, rows=1 << 20, packed4=False, **kw):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     i32 = arr((), jnp.int32)
-    _compiled_with_kernel(fused_split.lower(
+    return _compiled_with_kernel(fused_split.lower(
         arr((n, c), jnp.uint8), arr((n, c), jnp.uint8), i32, i32, i32, i32,
         i32, i32, i32, i32, i32, arr((8,), jnp.uint32), layout=layout,
         num_bins=b, block_size=bs, smaller_left=i32, side=i32,
         num_rows=rows, **kw))
 
 
+# past 8 MB or so of kernel text every streamed row pays 60-180 ns on the
+# chip; every shape at 7.5 MB and under has run clean (PERF.md section 6,
+# PR 30)
+CLEAN_TEXT_BYTES = 7_500_000
+
+
+def _fitted(features, bins, rows):
+    """(layout, block, depth) the registry fits a fused entry on a TPU
+    with nothing set."""
+    layout = RowLayout(num_features=features, num_extra=HIGGS_EXTRAS)
+    res = registry.resolve(
+        {}, platform="tpu",
+        shape=registry.DatasetShape(rows, features, bins - 1, "serial"))
+    assert res.entry_id == "fused_lane"
+    assert res.sources["hist_mbatch"] == "fused"
+    return (layout,) + registry.fit_fused_flush(res, layout.num_cols, bins,
+                                                features)
+
+
 def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
     """The headline kernel as higgs trains it: 128-byte records, 256 bins,
     dual residency, block clamped to 384, at the depth the registry
-    resolves for a fused entry on a TPU with nothing set."""
-    res = registry.resolve(
-        {}, platform="tpu",
-        shape=registry.DatasetShape(HIGGS_ROWS, 28, 255, "serial"))
-    assert res.entry_id == "fused_lane"
-    assert res.sources["hist_mbatch"] == "fused"
-    _fused_compile(one_chip, f=28, b=256, mbatch=res.hist_mbatch)
+    resolves for a fused entry on a TPU with nothing set. Its text stays
+    on the clean side of the cliff: 1.97 MB with the histogram's rows along
+    lanes (3.51 MB before PR 33)."""
+    _, bs, depth = _fitted(28, 256, HIGGS_ROWS)
+    assert (bs, depth) == (384, 2)
+    compiled = _fused_compile(one_chip, f=28, b=256, mbatch=depth,
+                              block_size=bs)
+    text = compiled.memory_analysis().generated_code_size_in_bytes
+    assert text < CLEAN_TEXT_BYTES, text
+
+
+def test_fused_kernel_compiles_for_v5e_at_63_bins(one_chip,
+                                                  no_persistent_cache):
+    """`higgs_b63_train`'s kernel: eight features of 64 bins a matmul
+    group, concatenated along sublanes at a stride of 64."""
+    _, bs, depth = _fitted(28, 64, HIGGS_ROWS)
+    assert (bs, depth) == (384, 2)
+    _fused_compile(one_chip, f=28, b=64, mbatch=depth, block_size=bs)
 
 
 def test_fused_kernel_compiles_for_v5e_at_220_features(one_chip,
@@ -187,15 +218,15 @@ def test_fused_kernel_compiles_for_v5e_at_220_features(one_chip,
     256-byte records at 256 bins. At the fused default depth 2 and the
     record's own block of 192 its 110 unrolled feature groups wanted 23 MB
     of scoped VMEM where the compiler gives 16 MB, and PR 30's parent was
-    refused at its first step; what the registry fits for that many
-    groups (depth 1 at the same 192 rows a flush) compiles."""
-    layout = RowLayout(num_features=220, num_extra=HIGGS_EXTRAS)
-    res = registry.resolve(
-        {}, platform="tpu",
-        shape=registry.DatasetShape(7_325_625, 220, 255, "serial"))
-    bs, depth = registry.fit_fused_flush(res, layout.num_cols, 256, 220)
-    assert (bs, depth) == (192, 1)
-    _fused_compile(one_chip, f=220, b=256, mbatch=depth, block_size=bs)
+    refused at its first step. What the registry fits for that many
+    groups compiles: depth 1 at a block of whole lane tiles (256 since
+    PR 33: at 192 the half-empty tile lost the masked weight load)."""
+    _, bs, depth = _fitted(220, 256, 7_325_625)
+    assert (bs, depth) == (256, 1)
+    compiled = _fused_compile(one_chip, f=220, b=256, mbatch=depth,
+                              block_size=bs)
+    text = compiled.memory_analysis().generated_code_size_in_bytes
+    assert text < CLEAN_TEXT_BYTES, text
 
 
 @pytest.mark.slow

@@ -8,6 +8,8 @@ analyzer — or of the fixed code — fails loudly.
 import os
 import textwrap
 
+import pytest
+
 import lightgbm_tpu
 from lightgbm_tpu.analysis.tpulint import (DEFAULT_ALLOWLIST, apply_allowlist,
                                            check_allowlist_staleness,
@@ -621,25 +623,23 @@ def test_r004_sublane_layout_bins_bound(tmp_path):
     assert not clean
 
 
-def test_r004_sublane_ring_budget_charged(tmp_path):
-    """The sublane layout's row-major channel slots pad to 128 lanes —
-    a block size that fits the lane ring must still be rejected when the
-    call selects sublane and the padded slots blow the budget."""
-    lane_ok = lint_snippet(tmp_path, """
+@pytest.mark.parametrize("layout", ["lane", "sublane"])
+def test_r004_ring_budget_charged_under_both_layouts(tmp_path, layout):
+    """Both layouts stage the same operands since PR 33 (each block
+    transposed, as i32 rows, and its [8, bs] channels): a constant
+    (block, depth) pair whose ring fits the budget passes under either,
+    and one whose ring does not is rejected under either."""
+    call = """
         def caller(work, scratch, args, n):
-            return fused_split(work, scratch, *args, block_size=384,
+            return fused_split(work, scratch, *args, block_size=%d,
                                num_rows=n, mbatch=8,
-                               hist_layout="lane")
-    """, name="lane_ring.py")
-    assert not [f for f in lane_ok if "VMEM" in f.message]
-    sub = lint_snippet(tmp_path, """
-        def caller(work, scratch, args, n):
-            return fused_split(work, scratch, *args, block_size=384,
-                               num_rows=n, mbatch=8,
-                               hist_layout="sublane")
-    """, name="sub_ring.py")
-    r4 = [f for f in sub if f.rule == "R004" and "VMEM" in f.message]
-    assert len(r4) == 1, [f.render() for f in sub]
+                               hist_layout="%s")
+    """
+    fits = lint_snippet(tmp_path, call % (256, layout), name="ring_ok.py")
+    assert not [f for f in fits if "VMEM" in f.message]
+    over = lint_snippet(tmp_path, call % (384, layout), name="ring_over.py")
+    r4 = [f for f in over if f.rule == "R004" and "VMEM" in f.message]
+    assert len(r4) == 1, [f.render() for f in over]
 
 
 def test_r004_engine_kwargs_outside_registry(tmp_path):
