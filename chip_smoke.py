@@ -15,7 +15,11 @@ of stdout is ``{"ok": true, "device": {...}}`` with the device as JAX
 reports it. Without a TPU it exits 2 and prints no result.
 
 Every timing printed here is a SMOKE FIGURE (one cold run, no repeats), not a
-benchmark row; it goes into no BENCH_* file.
+benchmark row; it goes into no BENCH_* file. The four-chip phase stays at
+its 4,194,304 rows: what bounds a data-parallel job (2^24 - 1 rows a shard
+for the f32 shard-local counts, 2^31 - 1 in all for the int32 row ids and
+the counts summed across shards) is measured at size by the benchmark's cell
+``criteo_dp4_train`` (40,000,000 rows over four chips), not here.
 
 Rehearsal on the CPU (no chip time; ``--rehearsal`` is the explicit opt-in
 that swaps the Mosaic kernel for Pallas interpret mode and shrinks rows,

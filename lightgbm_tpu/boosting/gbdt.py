@@ -813,12 +813,14 @@ class GBDT:
                 and mesh_axis_sizes(self.mesh)[1] == 1
                 and not (self.objective is not None
                          and self.objective.renew_leaves)))
-        # exact-count ceiling: histogram count channels ride f32, exact for
-        # integers < 2^24; the partition-critical counts are SHARD-LOCAL
-        # under the data-parallel learner (n_left_loc from the shard's own
-        # histogram), so the bound applies per shard, not globally. Global
-        # psum-ed counts only feed constraints (min_data) and the
-        # smaller-side election, where +-2^-24 relative is harmless.
+        # exact-count ceiling: a shard's own histogram count channels ride
+        # f32, exact for integers < 2^24, and drive its partition offsets
+        # (n_left_loc), so the bound applies per shard, not globally. The
+        # counts that cross shards (a leaf's and a node's global count, the
+        # root's totals, what min_data_in_leaf is held against) are summed
+        # as int32 (ops/grower_compact.py): the model text's leaf_count and
+        # internal_count are exact below 2^31 rows, which the benchmark's
+        # replay holds them to (benchmarks/correct.py, leaf_count_wrong).
         n_shards = (mesh_axis_sizes(self.mesh)[0]
                     if self.mesh is not None and self.tree_learner == "data"
                     else 1)
@@ -1317,13 +1319,20 @@ class GBDT:
         [scores(K), objective label, objective weight?, original row id]."""
         obj = self.objective
         n = self.num_data
-        if n >= (1 << 24):
-            # f32 raw-count histograms drive the partition offsets and f32
-            # row ids drive the metric permutation; both are exact only
-            # below 2^24 rows (ops/compact.py)
+        n_shards = (len(self.mesh.devices.ravel())
+                    if self.mesh is not None else 1)
+        rows_a_shard = -(-n // n_shards)
+        if rows_a_shard >= (1 << 24):
+            # what is still f32: the shard-local raw-count histograms that
+            # drive the partition offsets, exact only below 2^24 rows a
+            # shard (ops/compact.py). Row ids and the counts that cross
+            # shards are integers and bound nothing below 2^31.
             raise RuntimeError(
-                "tpu_grower=compact supports up to 2^24 rows; use "
-                "tree_learner=data to shard rows or tpu_grower=masked")
+                f"tpu_grower=compact supports up to 2^24 - 1 rows a shard "
+                f"(f32 shard-local counts drive the partition offsets); "
+                f"this run holds {rows_a_shard} a shard over "
+                f"{n_shards}. Use tree_learner=data over more devices or "
+                "tpu_grower=masked")
         k = self.num_tree_per_iteration
         has_w = obj.weight is not None
         # extras: [scores(K), grads(K-1 extra pairs for multiclass), label,
@@ -1424,44 +1433,67 @@ class GBDT:
         # the fused kernel's aligned block writes may overrun a segment end
         # by up to one block + one alignment tile
         pad = max(gp.part_block, gp.hist_block, gp.fused_block + 32)
-        parts = [self.train_score]
-        if gcols:
-            parts.append(jnp.zeros((gcols, n), jnp.float32))
-        parts.append(obj.label[None, :])
-        if has_w:
-            parts.append(obj.weight[None, :])
-        parts.append(jnp.arange(n, dtype=jnp.float32)[None, :])
-        extras = jnp.concatenate(parts, axis=0)
-        zeros = jnp.zeros((n,), jnp.float32)
         # padded rows (mesh row-count alignment) start permanently out of
         # bag: zero count weight, zero gradients
-        cnt0 = (np.asarray(self._valid_row_mask, np.float32)
-                if getattr(self, "_valid_row_mask", None) is not None
-                else jnp.ones((n,), jnp.float32))
+        valid = getattr(self, "_valid_row_mask", None)
+
+        def pack(binned, scores, label, weight, cnt, first_row):
+            """One device's rows as packed records, in place on that
+            device: [rows + pad, C] u8. The carried row id is the row's
+            index in the dataset, an int32 from ``first_row`` up."""
+            rows = binned.shape[0]
+            zeros = jnp.zeros((rows,), jnp.float32)
+            parts = [scores]
+            if gcols:
+                parts.append(jnp.zeros((gcols, rows), jnp.float32))
+            parts.append(label[None, :])
+            if weight is not None:
+                parts.append(weight[None, :])
+            if cnt is None:
+                cnt = jnp.ones((rows,), jnp.float32)
+            rid = first_row + jnp.arange(rows, dtype=jnp.int32)
+            return pack_rows(binned, zeros, zeros, cnt,
+                             jnp.concatenate(parts, axis=0), layout,
+                             pad_rows=pad, row_id=rid)
+
         if self.mesh is not None:
             # per-shard layout: each shard's rows sit in a contiguous block
             # followed by its own `pad` overrun rows, so the per-shard
-            # partition walks never touch a neighbour shard
-            from ..parallel.mesh import row_sharding_2d
-            S = len(self.mesh.devices.ravel())
+            # partition walks never touch a neighbour shard. Each device
+            # packs the rows it already holds: no array of all rows is
+            # ever on one device.
+            from jax.sharding import PartitionSpec as P
+            from ..parallel.mesh import DATA_AXIS, row_sharding
+            S = n_shards
             nl = n // S
-            flat = pack_rows(self.binned, zeros, zeros,
-                             jnp.asarray(cnt0, jnp.float32), extras, layout,
-                             pad_rows=0)
-            c = flat.shape[1]
-            work = jnp.pad(flat.reshape(S, nl, c),
-                           ((0, 0), (0, pad), (0, 0))).reshape(-1, c)
-            work = jax.device_put(work, row_sharding_2d(self.mesh))
+            row1, row2 = P(DATA_AXIS), P(DATA_AXIS, None)
+
+            def pack_shard(binned, scores, label, weight, cnt):
+                work = pack(binned, scores, label, weight, cnt,
+                            jax.lax.axis_index(DATA_AXIS) * nl)
+                return work, jnp.zeros_like(work)
+
+            rows_of = functools.partial(jax.device_put,
+                                        device=row_sharding(self.mesh))
+            with span("shard_rows"):
+                work, scratch = jax.jit(jax.shard_map(
+                    pack_shard, mesh=self.mesh,
+                    in_specs=(row2, P(None, DATA_AXIS), row1,
+                              row1 if has_w else None,
+                              row1 if valid is not None else None),
+                    out_specs=(row2, row2), check_vma=False))(
+                    self.binned, self.train_score, rows_of(obj.label),
+                    rows_of(obj.weight) if has_w else None, valid)
             shards = {"S": S, "nl": nl, "pad_rows": pad}
         else:
-            work = pack_rows(self.binned, zeros, zeros,
-                             jnp.asarray(cnt0, jnp.float32), extras, layout,
-                             pad_rows=pad)
+            work = pack(self.binned, self.train_score, obj.label,
+                        obj.weight, valid, 0)
+            scratch = jnp.zeros_like(work)
             shards = {"S": 1, "nl": n, "pad_rows": pad}
         self._compact = {
             "layout": layout,
             "work": work,
-            "scratch": jnp.zeros_like(work),
+            "scratch": scratch,
             "step": None,
             "epoch": 0,        # bumped per grown tree; keys the perm cache
             "perm_epoch": -1,
@@ -1478,16 +1510,9 @@ class GBDT:
         c = self._compact
         if c.get("rank_grad_fn") is None:
             obj = self.objective
-            layout = c["layout"]
-            S, nl, pr = c["S"], c["nl"], c["pad_rows"]
-            nm = self.num_data
-            off = layout.extra_off + 4 * self._cx_rowid
 
             def fn(work, scores_cur, by_length=None):
-                from ..ops.compact import _u8_to_f32
-                rows = (work.reshape(S, nl + pr, -1)[:, :nl]
-                        .reshape(S * nl, -1) if S > 1 else work[:nm])
-                rid = _u8_to_f32(rows[:, off:off + 4]).astype(jnp.int32)
+                rid = self._compact_row_ids(work)
                 s_orig = jnp.zeros_like(scores_cur).at[:, rid].set(scores_cur)
                 with (obj.bound_layout(by_length) if by_length is not None
                       else contextlib.nullcontext()):
@@ -1514,16 +1539,28 @@ class GBDT:
             return work.reshape(S, nl + pr, -1)[:, :nl].reshape(S * nl, -1)
         return work[:self.num_data]
 
+    def _compact_extra(self, work, i):
+        """The four bytes of extra column ``i`` of the row records in
+        current order, [N, 4] u8, per-shard pad rows stripped (the columns
+        are cut first: the records whole are gigabytes)."""
+        c = self._compact
+        S, nl, pr = c["S"], c["nl"], c["pad_rows"]
+        off = c["layout"].extra_off + 4 * i
+        col = work[:, off:off + 4]
+        if S > 1:
+            return col.reshape(S, nl + pr, 4)[:, :nl].reshape(S * nl, 4)
+        return col[:self.num_data]
+
+    def _compact_row_ids(self, work):
+        """The carried row ids in current order, [N] int32: each row's
+        index in the dataset (the record's last extra column)."""
+        from ..ops.compact import _u8_to_i32
+        return _u8_to_i32(self._compact_extra(work, self._cx_rowid))
+
     def _compact_cols(self, work, *extra_idx):
         """Unpack selected extra f32 columns from the work array."""
         from ..ops.compact import _u8_to_f32
-        layout = self._compact["layout"]
-        rows = self._compact_rows(work)
-        out = []
-        for i in extra_idx:
-            off = layout.extra_off + 4 * i
-            out.append(_u8_to_f32(rows[:, off:off + 4]))
-        return out
+        return [_u8_to_f32(self._compact_extra(work, i)) for i in extra_idx]
 
     def _build_compact_step_fn(self):
         """One fused jitted step per tree on the compact path: recompute
@@ -1532,7 +1569,7 @@ class GBDT:
         single XLA program, zero host syncs. The work/scratch buffers are
         donated (updated in place)."""
         from jax import lax
-        from ..ops.compact import _f32_to_u8, _u8_to_f32
+        from ..ops.compact import _f32_to_u8, _u8_to_f32, _u8_to_i32
 
         obj = self.objective
         renew = obj.renew_leaves
@@ -1683,7 +1720,8 @@ class GBDT:
                 # mesh row-count padding: pad rows (row id >= n_real) must
                 # stay permanently out of bag even when a fresh bag draws
                 # them — their label/score bytes are meaningless
-                w_col = w_col * (col(work, rid_off) < n_real_g)
+                w_col = w_col * (_u8_to_i32(
+                    work[:n, rid_off:rid_off + 4]) < n_real_g)
             label = col(work, lbl_off)
             weight = col(work, w_off) if w_off is not None else None
             class_grads = []
@@ -1837,18 +1875,40 @@ class GBDT:
                     donate_argnums=(0, 1))
                 if os.environ.get("LGBM_TPU_COMM_ACCOUNTING", "") == "1":
                     jitted = self._comm_capture(jitted, f"compact_step_k{k}")
+                elif k == 0:
+                    self._count_collectives(jitted, args)
                 fns[k] = jitted
             return fns[k](*args)
 
         return dispatch
+
+    def _count_collectives(self, jitted, args) -> None:
+        """What every ``iteration`` event of a data-parallel run carries
+        beside the update's counters (``_obs_iteration_tick``): the
+        shards, a shard's rows, and the collective instructions of the
+        step's compiled text with their bytes as
+        ``analysis/hlo.collective_bytes`` counts them, each instruction
+        once whatever loop it stands in. Read once, when the step is
+        built: the compile is the one the first call would make (jit
+        keeps the lowering and its executable), the rest host integers."""
+        from ..analysis.hlo import collective_bytes
+        if any(isinstance(a, jax.core.Tracer)
+               for a in jax.tree_util.tree_leaves(args)):
+            return          # the step traced into a caller's program
+        found = collective_bytes(jitted.lower(*args).compile().as_text())
+        c = self._compact
+        self._mesh_counters = {
+            "shards": c["S"], "rows_per_shard": c["nl"],
+            "collectives": found["count"],
+            "collective_bytes": found["total"]}
 
     def _compact_perm(self) -> np.ndarray:
         """Current row permutation (original index per position), cached per
         grown tree — used to reorder host-side metric arrays."""
         c = self._compact
         if c["perm_epoch"] != c["epoch"]:
-            (rid,) = self._compact_cols(c["work"], self._cx_rowid)
-            c["perm"] = np.asarray(rid).astype(np.int64)
+            c["perm"] = np.asarray(
+                self._compact_row_ids(c["work"])).astype(np.int64)
             c["perm_epoch"] = c["epoch"]
         return c["perm"]
 
@@ -1924,7 +1984,17 @@ class GBDT:
         # host-cached vector is not
         fresh = getattr(strat, "last_fresh", mask is not None)
         if mask is None:
-            mask = jnp.ones((n,), jnp.float32)
+            if self.mesh is None:
+                mask = jnp.ones((n,), jnp.float32)
+            else:
+                # every row in bag: made once, a shard's rows on its own
+                # device (a fresh [n] array an iteration would be made on
+                # one device and sent round the mesh every time)
+                if c.get("all_in_bag") is None:
+                    from ..parallel.mesh import row_sharding
+                    c["all_in_bag"] = jnp.ones(
+                        (n,), jnp.float32, device=row_sharding(self.mesh))
+                mask = c["all_in_bag"]
             fresh = self.iter_ == 0 or fresh
         if getattr(strat, "_amplify", None) is not None:
             mask = mask * strat._amplify
@@ -2391,6 +2461,9 @@ class GBDT:
         # what a ranking objective's layout by query length makes the
         # gradient program compute (objectives.py): fixed at init
         counters.update(getattr(self.objective, "rank_counters", {}))
+        # a data-parallel step's shards and collectives: fixed when the
+        # step is built (_count_collectives)
+        counters.update(getattr(self, "_mesh_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),
                     **counters)

@@ -48,7 +48,10 @@ into sites that block on the device) and ``d2h_bytes``; the closing
 ``iteration`` event reports them (``GBDT._obs_iteration_tick``), and
 with them what a ranking objective's layout makes the gradient program
 compute: ``rank_slots`` (padded [queries, length] slots over all length
-classes), ``rank_docs``, ``rank_slots_per_doc``, ``rank_classes``.
+classes), ``rank_docs``, ``rank_slots_per_doc``, ``rank_classes``; and
+what a data-parallel step is made of: ``shards``, ``rows_per_shard``,
+``collectives`` and ``collective_bytes`` (the collective instructions of
+the step's compiled text and their bytes, each instruction once).
 
 Span taxonomy (every name a device program or tick site carries):
 
@@ -76,7 +79,9 @@ Span taxonomy (every name a device program or tick site carries):
                           objective, ``rank_layout`` (the queries grouped
                           into length classes, each class's index, gains
                           and inverse max DCG: objectives.py)
-``compact_setup``         ``_setup_compact_state`` (first update)
+``compact_setup``         ``_setup_compact_state`` (first update); under a
+                          mesh its child ``shard_rows``: every device packs
+                          the rows it holds into its own records
 ``build_step``            ``_build_compact_step_fn`` / ``_build_step_fn``
 ``iteration``             all of ``Booster.update()``; children ``bag``,
                           ``gradient`` (``rank_grads`` where the program
@@ -109,7 +114,8 @@ _TRACE_MODES = ("full", "annotations")
 #: loop (module docstring: which host spans record)
 ALWAYS_ON = frozenset((
     "import", "construct", "find_bins", "binning", "to_device",
-    "booster_init", "rank_layout", "compact_setup", "build_step",
+    "booster_init", "rank_layout", "compact_setup", "shard_rows",
+    "build_step",
     "iteration", "bag", "gradient", "rank_grads", "step_dispatch",
     "valid_scores", "flush_trees"))
 

@@ -20,7 +20,7 @@ SPAN_TAXONOMY = (
     "partition", "checkpoint_write", "predict_warmup", "serve_tick",
     "featurize", "contrib",
     "import", "construct", "find_bins", "to_device", "booster_init",
-    "rank_layout", "compact_setup", "build_step",
+    "rank_layout", "compact_setup", "shard_rows", "build_step",
     "iteration", "bag", "rank_grads", "step_dispatch", "valid_scores",
     "flush_trees",
 )

@@ -25,9 +25,12 @@ Row records pack into a single uint8 matrix ``[N, C]``:
                     their amplification — persists across trees so a bag
                     drawn in one row order stays the same *set of rows* after
                     later permutations, like the reference's bag_data_indices)
-    [F+12, ..+4E)   E extra f32 columns carried through the permutation
+    [F+12, ..+4E)   E extra 4-byte columns carried through the permutation
                     (scores, label, weight — anything that must stay
-                    row-aligned across trees)
+                    row-aligned across trees); the trainer's last one is
+                    the row's original index, an int32: its bytes are
+                    never read as an f32 (a small integer's bits are an
+                    f32 denormal, which the TPU flushes to zero)
 
 f32 fields move through the one-hot compaction matmul as 4 exact uint8
 columns (bf16 represents 0..255 exactly; each output row receives exactly one
@@ -114,6 +117,16 @@ def _u8_to_f32(x: jnp.ndarray) -> jnp.ndarray:
     return lax.bitcast_convert_type(x, jnp.float32)
 
 
+def _i32_to_u8(x: jnp.ndarray) -> jnp.ndarray:
+    """[N] i32 -> [N, 4] u8 (exact bitcast)."""
+    return lax.bitcast_convert_type(x.astype(jnp.int32), jnp.uint8)
+
+
+def _u8_to_i32(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., 4] u8 -> [...] i32 (exact bitcast)."""
+    return lax.bitcast_convert_type(x, jnp.int32)
+
+
 def pack_rows(
     binned: jnp.ndarray,     # [N, F] uint8
     grad: jnp.ndarray,       # [N] f32
@@ -122,12 +135,17 @@ def pack_rows(
     extras: jnp.ndarray,     # [E, N] f32 carried columns
     layout: RowLayout,
     pad_rows: int,
+    row_id: jnp.ndarray = None,   # [N] i32: the record's last extra column
 ) -> jnp.ndarray:
     """Pack per-row arrays into the work matrix, padded by ``pad_rows``
     garbage rows so blocked dynamic slices never clamp at the array end.
 
     With ``layout.packed4`` a full-width [N, F] bin matrix nibble-packs
-    here (an already-packed [N, ceil(F/2)] matrix passes through)."""
+    here (an already-packed [N, ceil(F/2)] matrix passes through).
+
+    ``row_id`` fills the last of the layout's ``num_extra`` columns with
+    the four bytes of an int32 (``extras`` then holds the E - 1 before
+    it); ``_u8_to_i32`` reads it back."""
     n = binned.shape[0]
     if layout.packed4 and binned.shape[1] == layout.num_features:
         if layout.num_features % 2:
@@ -139,9 +157,11 @@ def pack_rows(
         _f32_to_u8(hess),
         _f32_to_u8(cnt.astype(jnp.float32)),
     ]
-    if layout.num_extra:
+    if extras.shape[0]:
         e = _f32_to_u8(extras.T.astype(jnp.float32))  # [N, E, 4]
-        parts.append(e.reshape(n, 4 * layout.num_extra))
+        parts.append(e.reshape(n, 4 * extras.shape[0]))
+    if row_id is not None:
+        parts.append(_i32_to_u8(row_id))
     work = jnp.concatenate(parts, axis=1)
     c = layout.num_cols
     pad_c = c - work.shape[1]

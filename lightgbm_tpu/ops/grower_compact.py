@@ -55,7 +55,9 @@ class CompactState(NamedTuple):
     # per-leaf histograms are stored FLAT [L, F, B*4]: a trailing dim of 4
     # would be tiled to 128 lanes in HBM (f32 T(8,128) on the minor dims),
     # inflating the cache 32x — 17.7GB at F=529. Views reshape per split.
-    leaf_hist: jnp.ndarray   # [L, F, B*4] per-leaf GLOBAL histograms
+    leaf_hist: tuple         # per-leaf GLOBAL histograms, [L, F, B*4]; under
+    #                          a mesh axis ([L, F, B*2] f32 sums, [L, F, B*2]
+    #                          i32 counts) unless quantized
     leaf_hist_loc: jnp.ndarray  # [L, F, B*4] shard-local (data-parallel;
     #                             dummy [1,1,1] on the serial path)
     leaf_start: jnp.ndarray  # [L] i32 shard-local segment starts
@@ -109,6 +111,24 @@ class CompactState(NamedTuple):
     cegb_used: jnp.ndarray     # [F] bool (CEGB coupled costs paid once)
 
 
+def reduce_over_shards(local: jnp.ndarray, axis_name: str,
+                       scatter: bool = False) -> tuple:
+    """A shard-local histogram [F, B, 4] summed over the mesh axis, as a
+    tuple. The f32 one crosses as two arrays, (grad and hess sums
+    [F, B, 2] f32, the two count channels [F, B, 2] int32): a shard's own
+    counts are exact in f32 below 2^24 rows, their sum over the shards is
+    not, so they are cast before the collective and summed as integers.
+    An int32 one (quantized gradients) crosses whole. ``scatter``: every
+    shard keeps the sum of its own F / S features (``lax.psum_scatter``)
+    in place of the whole."""
+    parts = ((local,) if jnp.issubdtype(local.dtype, jnp.integer)
+             else (local[..., :2], local[..., 2:].astype(jnp.int32)))
+    if scatter:
+        return tuple(lax.psum_scatter(a, axis_name, scatter_dimension=0,
+                                      tiled=True) for a in parts)
+    return tuple(lax.psum(a, axis_name) for a in parts)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("layout", "params", "n_real"))
 def grow_tree_compact(
@@ -155,7 +175,16 @@ def grow_tree_compact(
     data_parallel_tree_learner.cpp:223-300) — instead of all-reducing the
     full [F, B, 4] histogram to every shard. Requires efb_virtual == 0 and
     mono_intermediate off (their scans need cross-feature histogram
-    access)."""
+    access).
+
+    Under a mesh axis every count that crosses shards is an int32: the
+    reduced histogram is the pair (grad and hess sums [F, B, 2] f32, the
+    two count channels [F, B, 2] i32), a shard's own counts (f32, exact
+    below 2^24 rows a shard) cast to integers before the collective, and
+    a leaf's and a node's count, the root's totals and what the scan
+    holds against ``min_data_in_leaf`` stay integers to the tree's
+    ``leaf_count`` and ``internal_count``: exact below 2^31 rows in all.
+    Without an axis the histogram and its counts are one f32 array."""
     n = n_real
     L = params.num_leaves
     B = params.num_bins
@@ -197,8 +226,15 @@ def grow_tree_compact(
     def dq_h(x):
         return x.astype(jnp.float32) * h_scale if quant else x
 
+    W = params.bitset_words
+    zero = jnp.asarray(0, i32)
+    ax = params.axis_name
+    # counts across shards are integers (docstring); a serial run's are
+    # the f32 channels of its one histogram
+    cdtype = i32 if ax else jnp.float32
+
     def dq_c(x):    # count channels: exact integer -> f32 cast
-        return x.astype(jnp.float32) if quant else x
+        return x.astype(jnp.float32) if quant and not ax else x
 
     if mono_types is None:
         mono_types = jnp.zeros((F_scan,), jnp.int8)
@@ -213,10 +249,6 @@ def grow_tree_compact(
     if extra_key is None:
         extra_key = jax.random.PRNGKey(6)
     big = jnp.float32(3.4e38)
-
-    W = params.bitset_words
-    zero = jnp.asarray(0, i32)
-    ax = params.axis_name
 
     # ---- feature-scattered histogram reduction (data-parallel) ----
     scatter = params.hist_scatter > 1
@@ -250,18 +282,20 @@ def grow_tree_compact(
     else:
         F_h = F
 
+    tmap = jax.tree_util.tree_map
+
+    def hist_cat(parts):
+        """Reduced histograms joined along the feature axis."""
+        return tmap(lambda *a: jnp.concatenate(a, axis=0), *parts)
+
     def reduce_hist(local):
         """[F, B, 4] shard-local -> globally-summed histogram (full copy,
-        or this shard's [F_loc, B, 4] feature slice under hist_scatter)."""
+        or this shard's [F_loc, B, .] feature slice under hist_scatter)."""
         if not ax:
-            return local
-        with span("collective_reduce"):
-            if scatter:
-                padded = jnp.pad(local, ((0, f_pad_sc), (0, 0), (0, 0))) \
-                    if f_pad_sc else local
-                return lax.psum_scatter(padded, ax, scatter_dimension=0,
-                                        tiled=True)
-            return lax.psum(local, ax)
+            return (local,)
+        if scatter and f_pad_sc:
+            local = jnp.pad(local, ((0, f_pad_sc), (0, 0), (0, 0)))
+        return _reduce_group(local)
 
     def sync_split(sp):
         """All-gather the per-shard best-split candidates and return the
@@ -283,16 +317,20 @@ def grow_tree_compact(
         if params.efb_virtual:
             # scan axis = stored columns + one virtual row per bundled
             # original feature (io/efb.py); exact in int32 when quantized
-            hist = extend_hist_efb(hist, efb, params.efb_virtual,
-                                   params.efb_bmax)
+            hist = tmap(lambda a: extend_hist_efb(
+                a, efb, params.efb_virtual, params.efb_bmax), hist)
         qs = quant_scales if quant else None
+        # the reduced histogram's integer counts, where it has them
+        counts = (None if not ax else hist[0][..., 2:] if quant
+                  else hist[1])
+        hist = hist[0]
         if scatter:
             sp = best_split(hist, pg, ph, pc, *meta_sl,
                             _fslice(_pad_f(fm, False)), sp_params,
                             mono_sl, cmn, cmx, po, depth,
                             (_fslice(_pad_f(cegb_pen, 0.0))
                              if cegb_pen is not None else None),
-                            ek, contri_sl, quant_scales=qs)
+                            ek, contri_sl, quant_scales=qs, counts=counts)
             # local winner -> global feature id, then the tiny cross-shard
             # candidate exchange picks one winner bit-identically everywhere
             sp = sp._replace(feature=shard_i * F_loc + sp.feature)
@@ -300,7 +338,7 @@ def grow_tree_compact(
         else:
             sp = best_split(hist, pg, ph, pc, *feat_info, fm, sp_params,
                             mono_types, cmn, cmx, po, depth, cegb_pen, ek,
-                            feature_contri, quant_scales=qs)
+                            feature_contri, quant_scales=qs, counts=counts)
         if params.efb_virtual:
             # a bundled winner routes as a ready-made bitset on its column
             sp = apply_efb_bitset(sp, efb, F, B)
@@ -362,10 +400,7 @@ def grow_tree_compact(
 
     def _reduce_group(part):
         with span("collective_reduce"):
-            if scatter:
-                return lax.psum_scatter(part, ax, scatter_dimension=0,
-                                        tiled=True)
-            return lax.psum(part, ax)
+            return reduce_over_shards(part, ax, scatter)
 
     def _grouped_reduce(local):
         """reduce_hist with one collective per feature group (the
@@ -381,7 +416,7 @@ def grow_tree_compact(
         else:
             for lo, hi in _gb:
                 parts.append(_reduce_group(local[lo:hi]))
-        return jnp.concatenate(parts, axis=0)
+        return hist_cat(parts)
 
     def reduce_any(local):
         return _grouped_reduce(local) if G else reduce_hist(local)
@@ -423,7 +458,7 @@ def grow_tree_compact(
                 .at[jnp.asarray(all_cols, i32)].set(loc_cat)
         else:
             loc_full = loc_cat
-        return loc_full, jnp.concatenate(parts_red, axis=0)
+        return loc_full, hist_cat(parts_red)
 
     # ---- root ----
     if params.fused_block:
@@ -450,16 +485,16 @@ def grow_tree_compact(
     # under hist_scatter the shard's slice may be all padding, so the
     # totals come from the LOCAL histogram + a tiny scalar psum instead
     if scatter:
-        sums = jnp.stack([root_loc[0, :, 0].sum(), root_loc[0, :, 1].sum(),
-                          root_loc[0, :, 2].sum()])
-        sums = lax.psum(sums, ax)
-        root_g = dq_g(sums[0])
-        root_h = dq_h(sums[1])
-        root_c = dq_c(sums[2])
+        # a shard's own count is exact in its f32 channel; the shards'
+        # counts are summed as integers
+        root_g, root_h = lax.psum(jnp.stack(
+            [root_loc[0, :, 0].sum(), root_loc[0, :, 1].sum()]), ax)
+        root_c = lax.psum(root_loc[0, :, 2].sum().astype(i32), ax)
     else:
-        root_g = dq_g(root_hist[0, :, 0].sum())
-        root_h = dq_h(root_hist[0, :, 1].sum())
-        root_c = dq_c(root_hist[0, :, 2].sum())
+        root_g, root_h = (root_hist[0][0, :, ch].sum() for ch in (0, 1))
+        root_c = (root_hist[-1][0, :, 0].sum() if ax and not quant
+                  else root_hist[0][0, :, 2].sum())
+    root_g, root_h, root_c = dq_g(root_g), dq_h(root_h), dq_c(root_c)
     from .grower import node_feature_mask
     root_fm = node_feature_mask(
         feat_mask, jnp.zeros((F_scan,), bool), inter_sets,
@@ -479,8 +514,8 @@ def grow_tree_compact(
         num_nodes=jnp.asarray(0, i32),
         work=work,
         scratch=scratch,
-        leaf_hist=jnp.zeros((L, F_h, B * 4), hdtype).at[0]
-        .set(root_hist.reshape(F_h, B * 4)),
+        leaf_hist=tmap(lambda a: jnp.zeros((L, F_h, a[0].size), a.dtype)
+                       .at[0].set(a.reshape(F_h, -1)), root_hist),
         leaf_hist_loc=(jnp.zeros((L, F, B * 4), hdtype).at[0]
                        .set(root_loc.reshape(F, B * 4)) if ax
                        else jnp.zeros((1, 1, 1), hdtype)),
@@ -510,17 +545,17 @@ def grow_tree_compact(
         leaf_depth=jnp.zeros((L,), i32),
         node_grad=jnp.zeros((L - 1,), jnp.float32),
         node_hess=jnp.zeros((L - 1,), jnp.float32),
-        node_cnt=jnp.zeros((L - 1,), jnp.float32),
+        node_cnt=jnp.zeros((L - 1,), cdtype),
         leaf_grad=jnp.zeros((L,), jnp.float32).at[0].set(root_g),
         leaf_hess=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
-        leaf_cnt=jnp.zeros((L,), jnp.float32).at[0].set(root_c),
+        leaf_cnt=jnp.zeros((L,), cdtype).at[0].set(root_c),
         bs_gain=jnp.full((L,), _NEG_INF, jnp.float32).at[0].set(sp0.gain),
         bs_feature=jnp.zeros((L,), i32).at[0].set(sp0.feature),
         bs_bin=jnp.zeros((L,), i32).at[0].set(sp0.bin),
         bs_default_left=jnp.zeros((L,), bool).at[0].set(sp0.default_left),
         bs_left_grad=jnp.zeros((L,), jnp.float32).at[0].set(sp0.left_grad),
         bs_left_hess=jnp.zeros((L,), jnp.float32).at[0].set(sp0.left_hess),
-        bs_left_cnt=jnp.zeros((L,), jnp.float32).at[0].set(sp0.left_count),
+        bs_left_cnt=jnp.zeros((L,), cdtype).at[0].set(sp0.left_count),
         bs_left_rows=jnp.zeros((L,), i32).at[0].set(
             sp0.left_rows.astype(i32)),
         bs_bitset=jnp.zeros((L, W), jnp.uint32).at[0].set(sp0.cat_bitset),
@@ -606,7 +641,7 @@ def grow_tree_compact(
         rg, rh, rc = pg - lg, ph - lh, pc - lc
         node_grad = st.node_grad.at[node].set(jnp.where(applied, pg, 0.0))
         node_hess = st.node_hess.at[node].set(jnp.where(applied, ph, 0.0))
-        node_cnt = st.node_cnt.at[node].set(jnp.where(applied, pc, 0.0))
+        node_cnt = st.node_cnt.at[node].set(jnp.where(applied, pc, 0))
         d_child = st.leaf_depth[best_leaf] + 1
         leaf_grad = st.leaf_grad.at[best_leaf].set(jnp.where(applied, lg, pg))
         leaf_grad = leaf_grad.at[new_leaf].set(
@@ -757,7 +792,8 @@ def grow_tree_compact(
         # one streamed pass over the SMALLER child only; the larger child
         # is parent - smaller (reference: SubtractHistogramForLeaf,
         # cuda_histogram_constructor.cu:723); exact in int32 when quantized
-        parent_hist = st.leaf_hist[best_leaf].reshape(F_h, B, 4)
+        parent_hist = tmap(lambda a: a[best_leaf].reshape(F_h, B, -1),
+                           st.leaf_hist)
         if params.fused_block:
             hist_small_loc = hist_small_fused
             hist_small = reduce_any(hist_small_loc)
@@ -767,14 +803,19 @@ def grow_tree_compact(
                                 m_eff - n_left_eff)
             hist_small_loc, hist_small = seg_hist_reduced(
                 work, s_small, m_small)
-        hist_large = parent_hist - hist_small
-        hist_left = jnp.where(left_smaller, hist_small, hist_large)
-        hist_right = jnp.where(left_smaller, hist_large, hist_small)
-        leaf_hist = st.leaf_hist.at[best_leaf].set(
-            jnp.where(applied, hist_left, parent_hist).reshape(F_h, B * 4))
-        leaf_hist = leaf_hist.at[new_leaf].set(
-            jnp.where(applied, hist_right.reshape(F_h, B * 4),
-                      leaf_hist[new_leaf]))
+        hist_large = tmap(jnp.subtract, parent_hist, hist_small)
+        hist_left = tmap(lambda a, b: jnp.where(left_smaller, a, b),
+                         hist_small, hist_large)
+        hist_right = tmap(lambda a, b: jnp.where(left_smaller, a, b),
+                          hist_large, hist_small)
+        leaf_hist = tmap(
+            lambda cache, left, parent: cache.at[best_leaf].set(
+                jnp.where(applied, left, parent).reshape(F_h, -1)),
+            st.leaf_hist, hist_left, parent_hist)
+        leaf_hist = tmap(
+            lambda cache, right: cache.at[new_leaf].set(
+                jnp.where(applied, right.reshape(F_h, -1), cache[new_leaf])),
+            leaf_hist, hist_right)
         if ax:
             large_loc = parent_loc - hist_small_loc
             left_loc = jnp.where(left_smaller, hist_small_loc, large_loc)
@@ -1002,7 +1043,8 @@ def grow_tree_compact(
 
                 def do(_):
                     sp = leaf_best(
-                        leaf_hist[i].reshape(F_h, B, 4), leaf_grad[i],
+                        tmap(lambda a: a[i].reshape(F_h, B, -1), leaf_hist),
+                        leaf_grad[i],
                         leaf_hess[i], leaf_cnt[i], leaf_depth[i],
                         leaf_fmask[i], cmn_a[i], cmx_a[i], leaf_pout[i],
                         pen_cur,

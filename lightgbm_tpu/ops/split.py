@@ -298,8 +298,16 @@ def best_split(
     extra_key: Optional[jnp.ndarray] = None,    # PRNG key (extra_trees)
     feature_contri: Optional[jnp.ndarray] = None,  # [F] gain multipliers
     quant_scales: Optional[tuple] = None,       # (g_scale, h_scale) f32
+    counts: Optional[jnp.ndarray] = None,       # [F, B, 2] i32 (count-weight, raw-count)
 ) -> SplitResult:
     """Find the best (feature, threshold, direction) for one leaf.
+
+    ``counts``: the two count channels as int32, in the histogram's place
+    (which may then hold grad and hess alone). ``parent_count`` is an
+    int32 too, and every count below stays one: prefix sums, the other
+    child's count, ``left_count`` and ``left_rows`` are exact at any row
+    count below 2^31, where an f32 holds integers only below 2^24 (the
+    data-parallel compact grower sums counts across shards as integers).
 
     ``quant_scales``: the histogram holds int32 quantized-gradient code sums
     (ops/histogram.py int8 path); the per-bin sums dequantize HERE — leaf
@@ -313,10 +321,14 @@ def best_split(
     f, b, k = hist.shape
     g = hist[:, :, 0]
     h = hist[:, :, 1]
-    c = hist[:, :, 2]
-    # raw (unweighted) row counts drive the compact grower's physical
-    # partition; histograms without the channel fall back to the weighted one
-    r = hist[:, :, 3] if k > 3 else c
+    if counts is not None:
+        c, r = counts[:, :, 0], counts[:, :, 1]
+    else:
+        c = hist[:, :, 2]
+        # raw (unweighted) row counts drive the compact grower's physical
+        # partition; histograms without the channel fall back to the
+        # weighted one
+        r = hist[:, :, 3] if k > 3 else c
     cg = jnp.cumsum(g, axis=1)
     ch = jnp.cumsum(h, axis=1)
     cc = jnp.cumsum(c, axis=1)
@@ -340,8 +352,8 @@ def best_split(
     below = t_iota < nan_bin[:, None]
     left_g2 = cg + jnp.where(below, nan_g, 0.0)
     left_h2 = ch + jnp.where(below, nan_h, 0.0)
-    left_c2 = cc + jnp.where(below, nan_c, 0.0)
-    left_r2 = cr + jnp.where(below, nan_r, 0.0)
+    left_c2 = cc + jnp.where(below, nan_c, 0)
+    left_r2 = cr + jnp.where(below, nan_r, 0)
 
     parent_gain = leaf_gain(parent_grad, parent_hess, p)
     gain_shift = parent_gain + p.min_gain_to_split
@@ -502,11 +514,10 @@ def _sorted_cat_split(g, h, c, r, is_cat, num_bins, feat_mask, parent_grad,
     sh = jnp.take_along_axis(h, order, axis=1)
     sc = jnp.take_along_axis(c, order, axis=1)
     sr = jnp.take_along_axis(r, order, axis=1)
-    zpad = jnp.zeros((f, 1), jnp.float32)
-    cg = jnp.concatenate([zpad, jnp.cumsum(sg, axis=1)], axis=1)  # [F, B+1]
-    ch = jnp.concatenate([zpad, jnp.cumsum(sh, axis=1)], axis=1)
-    cc = jnp.concatenate([zpad, jnp.cumsum(sc, axis=1)], axis=1)
-    cr = jnp.concatenate([zpad, jnp.cumsum(sr, axis=1)], axis=1)
+    def csum0(x):     # [F, B+1] prefix sums from 0, in x's own dtype
+        return jnp.pad(jnp.cumsum(x, axis=1), ((0, 0), (1, 0)))
+
+    cg, ch, cc, cr = csum0(sg), csum0(sh), csum0(sc), csum0(sr)
 
     tot_idx = used_bin[:, None]                                       # [F, 1]
     max_num_cat = jnp.minimum(mct, (used_bin + 1) // 2)               # [F]
@@ -531,7 +542,7 @@ def _sorted_cat_split(g, h, c, r, is_cat, num_bins, feat_mask, parent_grad,
     in_range = ((ts[None, :] <= used_bin[:, None])
                 & (ts[None, :] <= max_num_cat[:, None])
                 & sort_mode[:, None])                                 # [F, T]
-    step_cnt = jnp.diff(lc_t, axis=1, prepend=0.0)                    # [F, T, 2]
+    step_cnt = jnp.diff(lc_t, axis=1, prepend=0)                      # [F, T, 2]
 
     # stateful gating scan over t (cnt_cur_group accumulation + break flags)
     def gate(state, inputs):
@@ -547,11 +558,11 @@ def _sorted_cat_split(g, h, c, r, is_cat, num_bins, feat_mask, parent_grad,
         alive = jnp.logical_not(dead) & ok_t[:, None]
         evald = alive & left_ok & jnp.logical_not(brk) & \
             (grp >= p.min_data_per_group)
-        grp = jnp.where(evald, 0.0, grp)
+        grp = jnp.where(evald, 0, grp)
         dead = dead | (alive & brk)
         return (grp, dead), evald
 
-    state0 = (jnp.zeros((f, 2), jnp.float32), jnp.zeros((f, 2), bool))
+    state0 = (jnp.zeros((f, 2), c.dtype), jnp.zeros((f, 2), bool))
     _, evald = lax.scan(
         gate, state0,
         (jnp.moveaxis(step_cnt, 1, 0), jnp.moveaxis(lc_t, 1, 0),
