@@ -1504,32 +1504,59 @@ class GBDT:
     def _rank_grads_fn(self):
         """Jitted: bounded objective gradients for non-row-elementwise
         objectives (lambdarank), returned in the compact grower's CURRENT
-        permuted row order. One device scatter/gather pair by the carried
-        row-id column — no host round trip (reference: the rank objective
-        always sees original query-contiguous rows, rank_objective.hpp:25)."""
+        permuted row order, by the carried row-id column — no host round
+        trip (reference: the rank objective always sees original
+        query-contiguous rows, rank_objective.hpp:25)."""
         c = self._compact
         if c.get("rank_grad_fn") is None:
             obj = self.objective
-
-            def fn(work, scores_cur, by_length=None):
-                rid = self._compact_row_ids(work)
-                s_orig = jnp.zeros_like(scores_cur).at[:, rid].set(scores_cur)
-                with (obj.bound_layout(by_length) if by_length is not None
-                      else contextlib.nullcontext()):
-                    g, h = obj.get_gradients(s_orig[0])
-                return g[rid], h[rid]
-
             # position-bias objectives update host state (pos_biases) inside
             # get_gradients — run those eagerly, never under jit
             eager = (getattr(obj, "is_stochastic", False)
                      or getattr(obj, "positions", None) is not None)
+            # an objective that computes in an order of its own takes the
+            # carried row ids and composes them with its index; one with
+            # state in row order (position biases) or no order of its own
+            # (rank_xendcg) gets its rows scattered to row order and its
+            # gradients gathered back
+            in_order = not eager and hasattr(obj, "gradients_in_order")
+
+            def fn(work, scores_cur, by_length=None):
+                rid = self._compact_row_ids(work)
+                if in_order:
+                    with (obj.bound_layout(by_length)
+                          if by_length is not None
+                          else contextlib.nullcontext()):
+                        return obj.gradients_in_order(scores_cur[0], rid)
+                s_orig = jnp.zeros_like(scores_cur).at[:, rid].set(scores_cur)
+                g, h = obj.get_gradients(s_orig[0])
+                return g[rid], h[rid]
+
             c["rank_grad_fn"] = fn if eager else jax.jit(fn)
             # the jitted program takes the objective's layout (the queries
             # by length class) as an argument, not as its constants
-            c["rank_grad_layout"] = (
-                obj.layout_arrays()
-                if not eager and hasattr(obj, "layout_arrays") else None)
+            c["rank_grad_layout"] = obj.layout_arrays() if in_order else None
+            if in_order:
+                self._count_rank_moves(c)
         return c["rank_grad_fn"]
+
+    def _count_rank_moves(self, c) -> None:
+        """What the gradient program moves by index, read from its jaxpr
+        when it is built (``analysis/jaxpr.indexed_moves``; the trace is
+        the one the first call would make, jit keeps it): the objective's
+        ``rank_counters``, which every ``iteration`` event carries, gain
+        the program's gathers, scatters and sorts and the indexed accesses
+        a document."""
+        from ..analysis.jaxpr import indexed_moves
+        counters = self.objective.rank_counters
+        moves = indexed_moves(jax.make_jaxpr(c["rank_grad_fn"])(
+            c["work"], self.train_score, c["rank_grad_layout"]))
+        docs = counters["rank_docs"]
+        moved = sum(m["accesses"] for m in moves)
+        counters.update(
+            {f"rank_{op}s": sum(m["op"] == op for m in moves)
+             for op in ("gather", "scatter", "sort")},
+            rank_moved_per_doc=moved / docs if docs else 0.0)
 
     def _compact_rows(self, work):
         """The row records in current order, per-shard pad rows stripped."""

@@ -524,9 +524,9 @@ def _length_class(sizes: np.ndarray) -> np.ndarray:
 
 class _QueryClass(NamedTuple):
     """The queries of one length class, padded to the longest of them."""
-    index: jax.Array          # [Qc, Mc] int32 row of each slot, -1 = pad
     gain: jax.Array           # [Qc, Mc] float32 label gain, 0 in the pad
     inv_max_dcg: jax.Array    # [Qc] float32
+    length: jax.Array         # [Qc] int32 documents; the slots after are pad
     queries: np.ndarray       # [Qc] the queries' numbers, ascending
 
 
@@ -555,17 +555,30 @@ def _queries_by_length(boundaries: np.ndarray, num_data: int):
     return out, row_slot
 
 
+def _read_both(grad, hess, index):
+    """``grad[index], hess[index]`` by one gather of two-wide rows: a TPU
+    gathers an index at a time, and reads an index's two floats for less
+    than one float twice (PERF.md section 6, PR 36)."""
+    both = jnp.stack([grad, hess], axis=1)[index]
+    return both[:, 0], both[:, 1]
+
+
 class LambdarankNDCG(Objective):
+    """LambdaRank with |ΔNDCG| weighting (reference: LambdarankNDCG,
+    rank_objective.hpp:138-320).
+
+    The queries are grouped by length into classes (``_CLASS_WIDTH``), a
+    class's queries padded to its longest: the class's slots, [Qc, Mc].
+    The classes' slots laid end to end, and one more for the rows of no
+    query, are slot order, in which the gradients are computed
+    (``slot_gradients``): per class a sort of each query by score that
+    carries the label gains, the [T, Mc] pair block of the truncation
+    level's top positions against every position, and a sort back that
+    carries the gradients. Rows reach the slots and the gradients come
+    back by ``row_slot``, composed with whatever order the caller holds
+    its rows in (``gradients_in_order``)."""
+
     row_elementwise = False
-    """LambdaRank with |ΔNDCG| weighting.
-
-    The reference computes per-query lambda gradients with a sorted-document scan
-    (rank_objective.hpp:138-320; on device via bitonic sort in
-    cuda_rank_objective.cu). Here queries are padded to a [Q, M] matrix, scores
-    are sorted per query with ``jnp.argsort`` (XLA sort), and the full M×M pair
-    matrix is evaluated with masks — MXU/VPU-friendly, no data-dependent shapes.
-    """
-
     name = "lambdarank"
     is_ranking = True
 
@@ -625,7 +638,9 @@ class LambdarankNDCG(Objective):
     def _layout_queries(self, boundaries, row_gain, num_data):
         """The queries by length class (``_queries_by_length``), each
         class with its inverse max DCG per query (reference:
-        lambdarank_ndcg init), and the counters of the layout."""
+        lambdarank_ndcg init); the two indexes between row order and
+        slot order, the row weights in slot order, and the counters of
+        the layout."""
         classes, row_slot = _queries_by_length(boundaries, num_data)
         self.query_classes = []
         slots = 0
@@ -639,11 +654,22 @@ class LambdarankNDCG(Objective):
                           * disc[None, :], axis=1)
             inv = np.where(mdcg > 0, 1.0 / np.maximum(mdcg, 1e-300), 0.0)
             self.query_classes.append(_QueryClass(
-                jnp.asarray(idx), jnp.asarray(gain, jnp.float32),
-                jnp.asarray(inv, jnp.float32), qs))
+                jnp.asarray(gain, jnp.float32), jnp.asarray(inv, jnp.float32),
+                jnp.asarray(valid.sum(axis=1), jnp.int32), qs))
             chunk, n_chunks = self._chunks(idx.shape[0])
             slots += chunk * n_chunks * idx.shape[1]
         self.row_slot = jnp.asarray(row_slot)
+        # the row each slot holds: -1 in a class's pad and in the last
+        # slot, which is every row's of no query
+        slot_row = np.concatenate(
+            [idx.reshape(-1) for _, idx in classes]
+            + [np.full(1, -1, np.int32)])
+        self.slot_row = jnp.asarray(slot_row)
+        self.slot_weight = None
+        if self.weight is not None:
+            self.slot_weight = jnp.asarray(np.where(
+                slot_row >= 0, _np(self.weight)[np.maximum(slot_row, 0)],
+                0.0), jnp.float32)
         docs = int(boundaries[-1])
         #: what the gradient program computes against what there is: the
         #: update's ``iteration`` event carries these (gbdt)
@@ -653,28 +679,30 @@ class LambdarankNDCG(Objective):
             "rank_classes": len(self.query_classes)}
 
     def layout_arrays(self):
-        """The layout's device arrays as one pytree, for a jitted caller
-        to hand in as an argument (``bound_layout``)."""
-        return ([(c.index, c.gain, c.inv_max_dcg)
-                 for c in self.query_classes], self.row_slot)
+        """The layout's device arrays that ``gradients_in_order`` reads,
+        as one pytree, for a jitted caller to hand in as an argument
+        (``bound_layout``)."""
+        return ([(c.gain, c.inv_max_dcg, c.length)
+                 for c in self.query_classes], self.row_slot,
+                self.slot_weight)
 
     @contextlib.contextmanager
     def bound_layout(self, arrays):
-        """``get_gradients`` inside reads ``arrays`` (``layout_arrays``'
-        pytree, traced) in place of the arrays held. Held arrays traced
-        into a program are its constants: tens of MB in its text, and
-        another program, compiled anew, for every order the same queries
-        come in."""
-        classes, row_slot = arrays
-        held = self.query_classes, self.row_slot
+        """``gradients_in_order`` inside reads ``arrays``
+        (``layout_arrays``' pytree, traced) in place of the arrays held.
+        Held arrays traced into a program are its constants: tens of MB
+        in its text, and another program, compiled anew, for every order
+        the same queries come in."""
+        classes, row_slot, slot_weight = arrays
+        held = self.query_classes, self.row_slot, self.slot_weight
         self.query_classes = [
-            c._replace(index=i, gain=g, inv_max_dcg=d)
-            for c, (i, g, d) in zip(held[0], classes)]
-        self.row_slot = row_slot
+            c._replace(gain=g, inv_max_dcg=d, length=n)
+            for c, (g, d, n) in zip(held[0], classes)]
+        self.row_slot, self.slot_weight = row_slot, slot_weight
         try:
             yield
         finally:
-            self.query_classes, self.row_slot = held
+            self.query_classes, self.row_slot, self.slot_weight = held
 
     # queries processed in chunks of this many per pair-tensor block; the
     # block is [CHUNK, T, M] floats — memory stays bounded for MS-LTR-scale
@@ -689,8 +717,9 @@ class LambdarankNDCG(Objective):
         n_chunks = max(1, -(-q // cls._QUERY_CHUNK))
         return -(-q // n_chunks), n_chunks
 
-    def _query_chunk_grads(self, s, g, mask, inv_max_dcg):
-        """Lambda gradients for one chunk of padded queries [Qc, M].
+    def _query_chunk_grads(self, s, g, length, inv_max_dcg):
+        """Lambda gradients for one chunk of padded queries [Qc, M]:
+        ``s`` holds -inf and ``g`` 0 past each query's ``length``.
 
         The reference enumerates pairs (i, j) over SORTED positions with
         i < truncation_level and j > i (rank_objective.hpp:222-257) — a
@@ -699,22 +728,28 @@ class LambdarankNDCG(Objective):
         t = min(self.truncation_level, m)
         sig = self.sigmoid
 
-        order = jnp.argsort(-s, axis=1)                      # [Qc, M]
-        rank_of = jnp.argsort(order, axis=1)
-        s_s = jnp.take_along_axis(s, order, axis=1)
-        g_s = jnp.take_along_axis(g, order, axis=1)
-        m_s = jnp.take_along_axis(mask, order, axis=1)
+        # one stable sort by descending score carries the gains along and
+        # yields each sorted position's document (``jnp.argsort`` is this
+        # sort of (key, iota); a gather by its result costs the TPU an
+        # element at a time). The pad's -inf sorts last.
+        pos = jax.lax.broadcasted_iota(jnp.int32, (qc, m), 1)
+        neg_s, g_s, order = jax.lax.sort(
+            (-s, g, pos), dimension=1, is_stable=True, num_keys=1)
+        s_s = -neg_s
+        m_s = pos < length[:, None]
         disc = 1.0 / jnp.log2(jnp.arange(m, dtype=jnp.float32) + 2.0)  # [M]
 
         # pair block [Qc, T, M]: i = sorted position < T, j = any position > i
-        s_i = s_s[:, :t, None]
+        # (the top T cut first: a slice and a new axis in one index is a
+        # gather to jnp)
+        s_i = s_s[:, :t][:, :, None]
         s_j = s_s[:, None, :]
-        g_i = g_s[:, :t, None]
+        g_i = g_s[:, :t][:, :, None]
         g_j = g_s[:, None, :]
-        d_i = disc[None, :t, None]
+        d_i = disc[:t][None, :, None]
         d_j = disc[None, None, :]
         upper = jnp.arange(t)[:, None] < jnp.arange(m)[None, :]
-        pair_valid = (m_s[:, :t, None] & m_s[:, None, :]
+        pair_valid = (m_s[:, :t][:, :, None] & m_s[:, None, :]
                       & (g_i != g_j) & upper[None])
         delta_ndcg = jnp.abs((g_i - g_j) * (d_i - d_j)) \
             * inv_max_dcg[:, None, None]
@@ -726,10 +761,8 @@ class LambdarankNDCG(Objective):
             # delta_pair_NDCG by score distance",
             # rank_objective.hpp:242-244): applied when the query's best
             # and worst scores differ
-            n_valid = jnp.sum(m_s.astype(jnp.int32), axis=1)
             best = s_s[:, 0]
-            worst = jnp.take_along_axis(
-                s_s, jnp.maximum(n_valid - 1, 0)[:, None], axis=1)[:, 0]
+            worst = jnp.min(jnp.where(m_s, s_s, jnp.inf), axis=1)
             delta_ndcg = jnp.where(
                 (best != worst)[:, None, None],
                 delta_ndcg / (0.01 + jnp.abs(ds_high)), delta_ndcg)
@@ -754,60 +787,79 @@ class LambdarankNDCG(Objective):
             grad_sorted = grad_sorted * scale[:, None]
             hess_sorted = hess_sorted * scale[:, None]
 
-        # back to document order within the query
-        grad_q = jnp.take_along_axis(grad_sorted, rank_of, axis=1)
-        hess_q = jnp.take_along_axis(hess_sorted, rank_of, axis=1)
+        # back to document order within the query: sorted by the document
+        # of each position, both ride along (no two keys are equal: a
+        # stable sort would carry a fourth operand to break ties)
+        _, grad_q, hess_q = jax.lax.sort(
+            (order, grad_sorted, hess_sorted), dimension=1, is_stable=False,
+            num_keys=1)
         return grad_q, hess_q
 
-    def _class_grads(self, score, cls):
+    def _class_grads(self, s, cls):
         """Per-slot lambda gradients of one length class, [Qc * Mc] each,
-        in chunks of ``_QUERY_CHUNK`` queries."""
-        idx, g = cls.index, cls.gain
-        mask = idx >= 0
-        q, m = idx.shape
-        s = jnp.where(mask, score[jnp.maximum(idx, 0)], -jnp.inf)  # [Qc, Mc]
-
+        from its slots' scores [Qc, Mc], in chunks of ``_QUERY_CHUNK``
+        queries."""
+        q, m = s.shape
+        g, length, imd = cls.gain, cls.length, cls.inv_max_dcg
         chunk, n_chunks = self._chunks(q)
         q_pad = chunk * n_chunks - q
         if q_pad:
             s = jnp.pad(s, ((0, q_pad), (0, 0)), constant_values=-jnp.inf)
             g = jnp.pad(g, ((0, q_pad), (0, 0)))
-            mask_p = jnp.pad(mask, ((0, q_pad), (0, 0)))
-            imd = jnp.pad(cls.inv_max_dcg, (0, q_pad))
-        else:
-            mask_p = mask
-            imd = cls.inv_max_dcg
-
-        def one_chunk(args):
-            sc, gc, mc, imdc = args
-            return self._query_chunk_grads(sc, gc, mc, imdc)
+            length = jnp.pad(length, (0, q_pad))
+            imd = jnp.pad(imd, (0, q_pad))
 
         grad_q, hess_q = jax.lax.map(
-            one_chunk,
+            lambda args: self._query_chunk_grads(*args),
             (s.reshape(n_chunks, chunk, m), g.reshape(n_chunks, chunk, m),
-             mask_p.reshape(n_chunks, chunk, m),
-             imd.reshape(n_chunks, chunk)))
+             length.reshape(n_chunks, chunk), imd.reshape(n_chunks, chunk)))
         return (grad_q.reshape(-1, m)[:q].reshape(-1),
                 hess_q.reshape(-1, m)[:q].reshape(-1))
+
+    def slot_gradients(self, s_slots):
+        """Gradients in slot order, [slots + 1] each, row weights applied,
+        from the scores in slot order: each class a static slice, -inf in
+        its pad; the last slot, of the rows of no query, gets zeros
+        whatever it holds."""
+        per_class, base = [], 0
+        for c in self.query_classes:
+            q, m = c.gain.shape
+            per_class.append(self._class_grads(
+                s_slots[base:base + q * m].reshape(q, m), c))
+            base += q * m
+        zero = jnp.zeros((1,), s_slots.dtype)
+        grad = jnp.concatenate([g for g, _ in per_class] + [zero])
+        hess = jnp.concatenate([h for _, h in per_class] + [zero])
+        if self.slot_weight is not None:
+            grad, hess = grad * self.slot_weight, hess * self.slot_weight
+        return grad, hess
+
+    def gradients_in_order(self, score, rows):
+        """``get_gradients`` for a caller that holds its rows in another
+        order (the compact grower's): ``score[i]`` is the score of row
+        ``rows[i]``, every row once, and so are the gradients returned.
+        One composed index carries scores to the slots and both gradients
+        back; row order is never made. Position-bias state lives in row
+        order and is not read here."""
+        slot = self.row_slot[rows]
+        s_slots = jnp.full(self.slot_row.shape, -jnp.inf, score.dtype).at[
+            slot].set(score)
+        return _read_both(*self.slot_gradients(s_slots), slot)
 
     def get_gradients(self, score):
         if self.positions is not None:
             # ranking math sees position-debiased scores (reference:
             # rank_objective.hpp:70 score + pos_biases_[positions_[j]])
             score = score + self.pos_biases[self.positions]
-        # every class's slots laid end to end and a zero after the last,
-        # for the rows of no query; each row reads its own slot
-        per_class = [self._class_grads(score, c) for c in self.query_classes]
-        zero = jnp.zeros((1,), score.dtype)
-        grad = jnp.concatenate(
-            [g for g, _ in per_class] + [zero])[self.row_slot]
-        hess = jnp.concatenate(
-            [h for _, h in per_class] + [zero])[self.row_slot]
-        grad, hess = self._weighted(grad, hess)
+        # rows are in dataset order: each slot reads its row, and each
+        # row its slot's gradients (already times the row's weight)
+        s_slots = jnp.where(self.slot_row >= 0,
+                            score[jnp.maximum(self.slot_row, 0)], -jnp.inf)
+        grad, hess = _read_both(*self.slot_gradients(s_slots), self.row_slot)
         if self.positions is not None:
             # Newton step on the per-position bias factors (reference:
             # UpdatePositionBiasFactors, rank_objective.hpp:296-331, fed the
-            # weight-multiplied lambdas — hence after _weighted)
+            # weight-multiplied lambdas)
             p_ids = self.positions
             d1 = jnp.zeros((self.num_position_ids,)).at[p_ids].add(-grad)
             d2 = jnp.zeros((self.num_position_ids,)).at[p_ids].add(-hess)
