@@ -141,23 +141,24 @@ def _discretize_gradients(grad, hess, key, num_bins: int, stochastic: bool,
     ``axis_name``: under shard_map the max-abs scale must be GLOBAL (pmax)
     — per-shard scales would make the psum-ed int histograms sum codes on
     different grids."""
-    gmax = jnp.max(jnp.abs(grad))
-    hmax = jnp.max(jnp.abs(hess))
-    if axis_name is not None:
-        gmax = jax.lax.pmax(gmax, axis_name)
-        hmax = jax.lax.pmax(hmax, axis_name)
-    g_scale = jnp.maximum(gmax / (num_bins // 2), 1e-30)
-    h_scale = jnp.maximum(
-        hmax if const_hess else hmax / num_bins, 1e-30)
-    if stochastic:
-        kg, kh = jax.random.split(key)
-        ug = jax.random.uniform(kg, grad.shape)
-        uh = jax.random.uniform(kh, hess.shape)
-        qg = jnp.trunc(grad / g_scale + jnp.sign(grad) * ug)
-        qh = jnp.trunc(hess / h_scale + uh)
-    else:
-        qg = jnp.trunc(grad / g_scale + jnp.sign(grad) * 0.5)
-        qh = jnp.trunc(hess / h_scale + 0.5)
+    with span("quant_discretize"):
+        gmax = jnp.max(jnp.abs(grad))
+        hmax = jnp.max(jnp.abs(hess))
+        if axis_name is not None:
+            gmax = jax.lax.pmax(gmax, axis_name)
+            hmax = jax.lax.pmax(hmax, axis_name)
+        g_scale = jnp.maximum(gmax / (num_bins // 2), 1e-30)
+        h_scale = jnp.maximum(
+            hmax if const_hess else hmax / num_bins, 1e-30)
+        if stochastic:
+            kg, kh = jax.random.split(key)
+            ug = jax.random.uniform(kg, grad.shape)
+            uh = jax.random.uniform(kh, hess.shape)
+            qg = jnp.trunc(grad / g_scale + jnp.sign(grad) * ug)
+            qh = jnp.trunc(hess / h_scale + uh)
+        else:
+            qg = jnp.trunc(grad / g_scale + jnp.sign(grad) * 0.5)
+            qh = jnp.trunc(hess / h_scale + 0.5)
     return qg, qh, g_scale, h_scale
 
 
@@ -769,10 +770,6 @@ class GBDT:
             log.warning("linear_tree is not supported with distributed "
                         "tree learners; training constant leaves")
         self._use_quant = bool(cfg.get("use_quantized_grad", False))
-        # set for real in _build_compact_step_fn (the int pipeline is
-        # compact-only); defaulting here keeps introspection safe on the
-        # masked path
-        self._quant_narrow_active = False
         self._quant_bins = int(cfg.get("num_grad_quant_bins", 4))
         self._quant_renew = bool(cfg.get("quant_train_renew_leaf", False))
         self._quant_stochastic = bool(cfg.get("stochastic_rounding", True))
@@ -1012,6 +1009,17 @@ class GBDT:
                                          jnp.asarray(vals[1], jnp.int32)))
         return self._budget_cache[1]
 
+    def _note_quant_path(self, int_hist: bool, renew: bool) -> None:
+        """What every ``iteration`` event of a quantized run carries
+        beside the update's counters: ``quant_hist`` (1 where the step
+        histograms the int8 codes into int32 sums, 0 where it takes the
+        dequantising f32 shim), ``quant_bins`` and ``quant_renew`` (1
+        where leaves are renewed from the true gradients). Host integers,
+        noted when the step is built; nothing for an f32 run."""
+        self._quant_counters = {
+            "quant_hist": int(int_hist), "quant_bins": self._quant_bins,
+            "quant_renew": int(renew)} if self._use_quant else {}
+
     def _build_step_fn(self):
         """One fused, jitted train step per tree: mask gradients, grow, renew,
         shrink, update the train score — a single XLA program, zero host syncs
@@ -1039,6 +1047,8 @@ class GBDT:
         quant_stoch = self._quant_stochastic
         const_hess = bool(getattr(obj, "is_constant_hessian", False))
         feature_contri = self._feature_contri
+        # the masked grower histograms the dequantised codes in f32
+        self._note_quant_path(False, quant_renew)
 
         def step(binned, score_k, grad_k, hess_k, mask, feat_mask,
                  shrinkage, bynode_key, cegb_used, true_grad_k, true_hess_k,
@@ -1073,15 +1083,17 @@ class GBDT:
             if quant_renew:
                 # re-fit leaf outputs from the TRUE gradient sums
                 # (reference: RenewIntGradTreeOutput, gbdt.cpp)
-                tg = true_grad_k * mask
-                th = true_hess_k * mask
-                sums_g = jnp.zeros((max_leaves,)).at[row_leaf].add(tg)
-                sums_h = jnp.zeros((max_leaves,)).at[row_leaf].add(th)
-                from ..ops.split import leaf_output as _lo
-                live = jnp.arange(max_leaves) < tree.num_leaves
-                tree = tree._replace(leaf_value=jnp.where(
-                    live, _lo(sums_g, sums_h, grower_params.split_params()),
-                    tree.leaf_value))
+                with span("quant_renew"):
+                    tg = true_grad_k * mask
+                    th = true_hess_k * mask
+                    sums_g = jnp.zeros((max_leaves,)).at[row_leaf].add(tg)
+                    sums_h = jnp.zeros((max_leaves,)).at[row_leaf].add(th)
+                    from ..ops.split import leaf_output as _lo
+                    live = jnp.arange(max_leaves) < tree.num_leaves
+                    tree = tree._replace(leaf_value=jnp.where(
+                        live,
+                        _lo(sums_g, sums_h, grower_params.split_params()),
+                        tree.leaf_value))
             if renew:
                 residual = obj.label - score_k
                 w = mask if row_weight is None else mask * row_weight
@@ -1713,7 +1725,12 @@ class GBDT:
             # overhead eats the halved channel count at B <= 64 while
             # int8 already beats the f32 einsum outright. Narrow is the
             # measured opt-in until a backend's sweep row says otherwise.
-        self._quant_narrow_active = bool(quant_int and gp.quant_narrow)
+        # the booster's GrowerParams say what the step runs (an engine
+        # note reads quant_hist there), and every iteration event too
+        self.grower_params = self.grower_params._replace(
+            quant_hist=gp.quant_hist, quant_max=gp.quant_max,
+            quant_narrow=gp.quant_narrow)
+        self._note_quant_path(quant_int, quant_renew)
         const_hess = bool(getattr(obj, "is_constant_hessian", False))
         feature_contri = self._feature_contri
         efb = self._efb
@@ -1835,26 +1852,28 @@ class GBDT:
                 # TRUE gradients from carried label/score columns, summed
                 # per contiguous leaf segment via cumsum differences
                 # (reference: RenewIntGradTreeOutput)
-                tg, th = _bound_gradients(
-                    obj, k_total, scores_of(work),
-                    col(work, lbl_off),
-                    col(work, w_off) if w_off is not None else None)
-                wq = col(work, layout.cnt_off)
-                tgk = tg[k] * wq
-                thk = th[k] * wq
-                csg = jnp.concatenate([jnp.zeros(1), jnp.cumsum(tgk)])
-                csh = jnp.concatenate([jnp.zeros(1), jnp.cumsum(thk)])
-                ends = jnp.minimum(leaf_start + leaf_nrows, n)
-                sums_g = csg[ends] - csg[jnp.minimum(leaf_start, n)]
-                sums_h = csh[ends] - csh[jnp.minimum(leaf_start, n)]
-                if mesh is not None:
-                    from ..parallel.mesh import DATA_AXIS
-                    sums_g = jax.lax.psum(sums_g, DATA_AXIS)
-                    sums_h = jax.lax.psum(sums_h, DATA_AXIS)
-                from ..ops.split import leaf_output as _lo
-                live = jnp.arange(max_leaves) < tree.num_leaves
-                leaf_value = jnp.where(
-                    live, _lo(sums_g, sums_h, gp.split_params()), leaf_value)
+                with span("quant_renew"):
+                    tg, th = _bound_gradients(
+                        obj, k_total, scores_of(work),
+                        col(work, lbl_off),
+                        col(work, w_off) if w_off is not None else None)
+                    wq = col(work, layout.cnt_off)
+                    tgk = tg[k] * wq
+                    thk = th[k] * wq
+                    csg = jnp.concatenate([jnp.zeros(1), jnp.cumsum(tgk)])
+                    csh = jnp.concatenate([jnp.zeros(1), jnp.cumsum(thk)])
+                    ends = jnp.minimum(leaf_start + leaf_nrows, n)
+                    sums_g = csg[ends] - csg[jnp.minimum(leaf_start, n)]
+                    sums_h = csh[ends] - csh[jnp.minimum(leaf_start, n)]
+                    if mesh is not None:
+                        from ..parallel.mesh import DATA_AXIS
+                        sums_g = jax.lax.psum(sums_g, DATA_AXIS)
+                        sums_h = jax.lax.psum(sums_h, DATA_AXIS)
+                    from ..ops.split import leaf_output as _lo
+                    live = jnp.arange(max_leaves) < tree.num_leaves
+                    leaf_value = jnp.where(
+                        live, _lo(sums_g, sums_h, gp.split_params()),
+                        leaf_value)
             lv = jnp.where(tree.num_nodes > 0, leaf_value, 0.0) * shrinkage
             tree = tree._replace(
                 leaf_value=lv,
@@ -2491,6 +2510,9 @@ class GBDT:
         # a data-parallel step's shards and collectives: fixed when the
         # step is built (_count_collectives)
         counters.update(getattr(self, "_mesh_counters", {}))
+        # which histogram path a quantized step runs, and whether it
+        # renews its leaves: fixed when the step is built
+        counters.update(getattr(self, "_quant_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),
                     **counters)

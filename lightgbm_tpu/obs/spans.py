@@ -51,7 +51,10 @@ compute: ``rank_slots`` (padded [queries, length] slots over all length
 classes), ``rank_docs``, ``rank_slots_per_doc``, ``rank_classes``; and
 what a data-parallel step is made of: ``shards``, ``rows_per_shard``,
 ``collectives`` and ``collective_bytes`` (the collective instructions of
-the step's compiled text and their bytes, each instruction once).
+the step's compiled text and their bytes, each instruction once); and
+which path a quantized-gradient step runs: ``quant_hist`` (1: int8 codes
+into int32 histograms; 0: the dequantising f32 shim), ``quant_bins``,
+``quant_renew`` (1: leaves renewed from the true gradients).
 
 Span taxonomy (every name a device program or tick site carries):
 
@@ -65,6 +68,12 @@ Span taxonomy (every name a device program or tick site carries):
 ``collective_reduce``     psum/psum_scatter of histograms over the mesh
 ``split_scan``            best-split scan over the histogram bins
 ``partition``             row partition / routing after a split
+``quant_discretize``      use_quantized_grad: the gradients' scales and
+                          their integer codes (boosting/gbdt.py, in the
+                          step; on the host where the masked grower's
+                          shim runs ahead of it)
+``quant_renew``           quant_train_renew_leaf: every leaf's value from
+                          the true gradients of its rows, in the step
 ``checkpoint_write``      io/checkpoint.write_snapshot atomic tick
 ``predict_warmup``        one serving-ladder rung warm (basic.py)
 ``serve_tick``            one coalescer micro-batch device dispatch

@@ -17,8 +17,8 @@ from typing import Optional, Tuple
 #: takes host events of that name as its window.
 SPAN_TAXONOMY = (
     "binning", "gradient", "hist_build", "collective_reduce", "split_scan",
-    "partition", "checkpoint_write", "predict_warmup", "serve_tick",
-    "featurize", "contrib",
+    "partition", "quant_discretize", "quant_renew", "checkpoint_write",
+    "predict_warmup", "serve_tick", "featurize", "contrib",
     "import", "construct", "find_bins", "to_device", "booster_init",
     "rank_layout", "compact_setup", "shard_rows", "build_step",
     "iteration", "bag", "rank_grads", "step_dispatch", "valid_scores",

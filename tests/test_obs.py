@@ -294,6 +294,12 @@ def test_taxonomy_trace_metrics_acceptance(tmp_path):
               lgb.Dataset(X[:200], label=(y[:200] > 0).astype(np.float32),
                           group=[50, 30, 120]), num_boost_round=1)
 
+    # quantized gradients: the discretizer and the leaf renewal in the step
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1,
+               "tpu_grower": "compact", "min_data_in_leaf": 5,
+               "use_quantized_grad": True, "quant_train_renew_leaf": True},
+              lgb.Dataset(X[:200], label=y[:200]), num_boost_round=1)
+
     # `import` is stamped once, as the package loads: before the reset
     missing = set(spans.SPAN_TAXONOMY) - spans.seen_spans() - {"import"}
     assert not missing, f"taxonomy spans never entered: {missing}"

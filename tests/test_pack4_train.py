@@ -198,7 +198,7 @@ class TestNarrowedQuantized:
         X, y = _higgs_like(1200, 6, seed=21)
         q = {"use_quantized_grad": True, "num_grad_quant_bins": 8}
         b = _train(X, y, dict(q, tpu_quant_hist_bits=8), n_iter=2)
-        assert not b._gbdt._quant_narrow_active   # warned, 32-bit engine
+        assert not b._gbdt.grower_params.quant_narrow   # warned, 32-bit engine
 
     def test_hist_bits_in_leaf_thresholds(self):
         # reference semantics: narrow while count * qmax fits 2^15
@@ -211,11 +211,11 @@ class TestNarrowedQuantized:
         b32 = _train(X, y, dict(q, tpu_quant_hist_bits=32))
         b16 = _train(X, y, dict(q, tpu_quant_hist_bits=16))
         b_auto = _train(X, y, dict(q))
-        assert b16._gbdt._quant_narrow_active
-        assert not b32._gbdt._quant_narrow_active
+        assert b16._gbdt.grower_params.quant_narrow
+        assert not b32._gbdt.grower_params.quant_narrow
         # auto keeps the int8 engine (narrow is the measured opt-in —
         # the sweep shows its radix-capped chunks lose at B <= 64)
-        assert not b_auto._gbdt._quant_narrow_active
+        assert not b_auto._gbdt.grower_params.quant_narrow
         np.testing.assert_array_equal(b32.predict(X), b16.predict(X))
         np.testing.assert_array_equal(b32.predict(X), b_auto.predict(X))
 
@@ -348,7 +348,7 @@ def test_steady_state_guard_with_narrowed_quant():
     bst = lgb.Booster(params, ds)
     for _ in range(2):
         bst.update()
-    assert bst._gbdt._quant_narrow_active
+    assert bst._gbdt.grower_params.quant_narrow
     with guards.steady_state_guard("5 narrowed iterations") as cc:
         for _ in range(5):
             bst.update()
