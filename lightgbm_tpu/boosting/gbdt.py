@@ -1020,6 +1020,18 @@ class GBDT:
             "quant_hist": int(int_hist), "quant_bins": self._quant_bins,
             "quant_renew": int(renew)} if self._use_quant else {}
 
+    def _note_hist_levels(self, fused: bool) -> None:
+        """``hist_levels`` of every ``iteration`` event, noted when the
+        step is built: 2 where the step's fused kernel contracts a
+        two-level one-hot (bin = 64 hi + lo: more than 64 bins a
+        feature), 1 where its one-hot spans the whole stride, 0 where
+        the fused kernel is off (ops/fused_split.hist_levels)."""
+        from ..ops.fused_split import hist_levels
+        gp = self.grower_params
+        self._hist_counters = {"hist_levels": hist_levels(
+            self._compact["layout"].num_features, gp.num_bins,
+            gp.hist_layout) if fused else 0}
+
     def _build_step_fn(self):
         """One fused, jitted train step per tree: mask gradients, grow, renew,
         shrink, update the train score — a single XLA program, zero host syncs
@@ -1049,6 +1061,7 @@ class GBDT:
         feature_contri = self._feature_contri
         # the masked grower histograms the dequantised codes in f32
         self._note_quant_path(False, quant_renew)
+        self._note_hist_levels(False)
 
         def step(binned, score_k, grad_k, hess_k, mask, feat_mask,
                  shrinkage, bynode_key, cegb_used, true_grad_k, true_hess_k,
@@ -1731,6 +1744,7 @@ class GBDT:
             quant_hist=gp.quant_hist, quant_max=gp.quant_max,
             quant_narrow=gp.quant_narrow)
         self._note_quant_path(quant_int, quant_renew)
+        self._note_hist_levels(gp.fused_block > 0)
         const_hess = bool(getattr(obj, "is_constant_hessian", False))
         feature_contri = self._feature_contri
         efb = self._efb
@@ -2513,6 +2527,8 @@ class GBDT:
         # which histogram path a quantized step runs, and whether it
         # renews its leaves: fixed when the step is built
         counters.update(getattr(self, "_quant_counters", {}))
+        # how many levels the fused kernel's one-hot has (0: kernel off)
+        counters.update(getattr(self, "_hist_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),
                     **counters)

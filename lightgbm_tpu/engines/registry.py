@@ -469,7 +469,7 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
     rounded + re-guarded, never trusted raw."""
     if not block:
         return 0
-    from ..ops.fused_split import _hist_packing, fused_block_cap
+    from ..ops.fused_split import _hist_flush_shape, fused_block_cap
     vmem_cap_bs = fused_block_cap(num_cols, mbatch,
                                   hist_layout=hist_layout,
                                   num_features=num_features,
@@ -478,8 +478,11 @@ def clamp_fused_block(block: int, num_cols: int, mbatch: int,
     if env_override:
         # perf experiments; rounded + re-guarded, never trusted raw
         bs = validated_fused_block_env(env_override, num_cols, vmem_cap_bs)
-    stride, f_pad, _ = _hist_packing(num_features, num_bins)
-    f_hist_bytes = f_pad * stride * 32
+    # [8 levels, f_pad * width] words (two-level flush: features pad to
+    # whole pairs); levels x width is the bin stride
+    levels, width, f_pad, _ = _hist_flush_shape(num_features, num_bins,
+                                                hist_layout)
+    f_hist_bytes = f_pad * levels * width * 32
     if f_hist_bytes > 6 << 20:
         log.warning("fused kernel disabled: histogram accumulator "
                     f"needs {f_hist_bytes >> 20}MB VMEM; using the "

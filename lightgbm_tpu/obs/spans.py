@@ -54,7 +54,9 @@ what a data-parallel step is made of: ``shards``, ``rows_per_shard``,
 the step's compiled text and their bytes, each instruction once); and
 which path a quantized-gradient step runs: ``quant_hist`` (1: int8 codes
 into int32 histograms; 0: the dequantising f32 shim), ``quant_bins``,
-``quant_renew`` (1: leaves renewed from the true gradients).
+``quant_renew`` (1: leaves renewed from the true gradients); and
+``hist_levels``, the levels of the one-hot the step's fused kernel
+contracts (2: bin = 64 hi + lo; 1: the whole stride; 0: the kernel is off).
 
 Span taxonomy (every name a device program or tick site carries):
 

@@ -56,8 +56,21 @@ whole walk:
     units: F / 3 cycles a histogrammed row whatever its bins (9.1 ns on
     higgs at 255 bins, 10.7 at 63, 58 at 220 features; 5.2, 1.7 and 37.5
     since), the XLU 94% full and the MXU 18%; the flush of 2 x 384 rows
-    was 7,379 bundles and is 4,005, with no lane broadcast, select, value
-    pack or spill in it (hist_contract; PERF.md section 6, PR 33);
+    was 7,379 bundles and 4,005 after, with no lane broadcast, select,
+    value pack or spill in it (hist_contract; PERF.md section 6, PR 33);
+  * what a histogrammed row then cost was the one-hot's transposed weight
+    loads, F x stride x bs / 2,048 pushes a block at 0.52 a cycle, each
+    loaded tile used for one 8-row matmul. Where a feature's stride is
+    128 or 256 bins the flush contracts a TWO-LEVEL one-hot (PR 38): bin
+    = 64 hi + lo, only the 64-wide one-hot of ``lo`` is loaded (two
+    features a 128-lane tile, a quarter of the pushes at 256 bins) and
+    the channel rows stream stacked by ``hi``, [16 G, bs] a pair of
+    features with G = stride / 64; the accumulator is [8 G, F_pad x 64]
+    and fused_split() undoes the order. The same products in the same
+    sums: the flush of 2 x 384 rows of 28 x 256 bins is 1,283 bundles,
+    scheduled at the push rate itself. A stride of 64 or less (63 bins,
+    packed nibbles, the sublane arm) is one level, the code of PR 33
+    operand for operand (hist_contract; PERF.md section 6, PR 38);
   * `mode=1` turns the kernel into a plain segment histogram (used for the
     root), skipping all partition work.
 
@@ -168,9 +181,11 @@ def fused_ring_bytes(block_size: int, num_cols: int, mbatch: int,
     RowLayout.packed4 — the packed layout halves this term, it does not
     escape the accounting; the kernel stages only the columns the
     histogram reads, this charges them all), the [8, bs] channel operand
-    (bf16 padded to 16 sublanes / int8 to 32), and the one-hot of one
-    feature group (<= 512 bins, bf16, which covers the int8 layout too).
-    Both ``hist_layout`` values stage the same operands."""
+    (bf16 padded to 16 sublanes / int8 to 32; the two-level flush stages
+    it uncast, f32 / i32 on 8 sublanes: the same bytes), and the one-hot
+    of one feature group (<= 512 bins, bf16, which covers the int8 layout
+    and the two-level flush's 128 rows of one-hot and 64 streamed rows a
+    pair). Both ``hist_layout`` values stage the same operands."""
     del hist_layout
     elt = 1 if quant else 2
     bins = 4 * mbatch * block_size * num_cols
@@ -220,7 +235,12 @@ _MAX_GROUP_TILES = 8
 #   110 x 1 x 256 = 28,160: 3.69 (istella)      69 x 2 x 256 = 35,328: 5.71
 #   14 x 8 x 384 = 43,008: 6.62; 12.61          110 x 2 x 256 = 56,320: 8.94
 # so 7.5 MB is near 48,000 rows now. The bound is the largest flush that
-# has RUN clean in this form (fused_block_cap), not that estimate.
+# has RUN clean in this form (fused_block_cap), not that estimate. The
+# two-level flush (PR 38) builds a quarter of those rows at 256 bins and
+# its text is half (1.02 MB for 1.97, 1.79 for istella's 3.69); the bound
+# and the rows it counts (groups x depth x block at _hist_packing's group)
+# stay, so every shape sums the rows a flush that it summed before: what
+# the freed text allows is the next change's to measure.
 _FLUSH_ONEHOT_ROWS = 28_160
 
 # sp scalar-prefetch vector layout (i32[16])
@@ -266,6 +286,38 @@ def _hist_packing(f: int, b: int):
     return stride, f_pad, group
 
 
+# bins one level of the flush's one-hot spans where a feature has more: a
+# bin is 64 hi + lo, and two features' 64 lo bins fill one 128-lane tile
+_LO_BINS = 64
+
+
+def _hist_flush_shape(f: int, b: int, hist_layout: str = "lane"):
+    """The flush's contraction, read off the bin stride: (levels ``G``,
+    one-hot width per feature, padded feature count, matmul group width
+    in features).
+
+    A stride of 64 or less is one level, ``G`` = 1: a feature's one-hot
+    spans its stride and the group is _hist_packing's. A stride of 128 or
+    256 is contracted in two levels, bin = 64 hi + lo with ``G`` = stride
+    / 64 values of hi: the one-hot spans the 64 values of lo, a group is
+    the two features of one 128-lane tile, and the feature count pads to
+    whole pairs (hist_contract). The sublane arm, whose one-hot streams,
+    is one level at any stride."""
+    stride, f_pad, group = _hist_packing(f, b)
+    if stride <= _LO_BINS or hist_layout == "sublane":
+        return 1, stride, f_pad, group
+    return stride // _LO_BINS, _LO_BINS, _round_up(f, 2), 2
+
+
+def hist_levels(f: int, b: int, hist_layout: str = "lane") -> int:
+    """Levels of the one-hot the fused kernel's flush contracts for ``f``
+    features of ``b`` bins: 2 where a bin is split 64 hi + lo (a stride
+    of 128 or 256: more than 64 bins a feature, or a count _hist_packing
+    pads to a whole tile), 1 where the one-hot spans the whole stride
+    (the ``hist_levels`` counter of an ``iteration`` event)."""
+    return 2 if _hist_flush_shape(f, b, hist_layout)[0] > 1 else 1
+
+
 def _hist_rows(layout: RowLayout) -> int:
     """Byte columns of a row record the histogram reads (the bins and the
     gradient, hessian and count words), rounded up to whole int8 tiles:
@@ -305,7 +357,8 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     F = layout.num_features
     C = layout.num_cols
     B = num_bins
-    BS_, F_pad, _ = _hist_packing(F, B)   # BS_: bin stride per feature
+    # G: levels of hi; OW: one-hot width per feature; both off the stride
+    G, OW, F_pad, group_w = _hist_flush_shape(F, B, hist_layout)
     packed4 = layout.packed4
     i32 = jnp.int32
 
@@ -441,7 +494,7 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     HR = pendT.shape[1]
     lane1 = lax.broadcasted_iota(i32, (1, bs), 1)
     sub8 = lax.broadcasted_iota(i32, (8, bs), 0)
-    sub_b = lax.broadcasted_iota(i32, (BS_, bs), 0)
+    sub_b = lax.broadcasted_iota(i32, (OW, bs), 0)
     eye_h = (lax.broadcasted_iota(i32, (HR, C), 0)
              == lax.broadcasted_iota(i32, (HR, C), 1)).astype(jnp.int8)
     # quant: int8 one-hot x int8 packed channels -> int32 (exact, 2x MXU
@@ -450,7 +503,10 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     acc_t = jnp.int32 if quant else jnp.float32
     oh_src = jnp.int32 if quant else jnp.float32   # the one-hot, uncast
     one, zero = jnp.ones((), oh_src), jnp.zeros((), oh_src)
-    _, _, group_w = _hist_packing(F, B)   # matmul group width (features)
+    # a staged channel operand (fused_split sizes the ring): cast where it
+    # is assembled at one level; at two it stays uncast, f32 / i32, until
+    # group_product has stacked it by hi
+    ch_staged = pendch.dtype
 
     def stage_block(t, rows_u8):
         """Transpose a [bs, C] u8 block's first HR byte columns into
@@ -466,6 +522,11 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     def assemble_chT(t, mask):
         """Masked rows of staged slot ``t`` -> the [8, bs] channel operand,
         born transposed: every operation runs on [1, bs] rows.
+
+        The operand is cast here (``ch_staged``) where the flush is one
+        level; the two-level flush stacks it by ``hi`` first and casts
+        the stack, so it is staged as f32 / i32 words holding the same
+        values (hist_contract).
 
         f32 mode (bf16 output): (grad-hi, hess-hi, in-bag, raw, grad-lo,
         hess-lo, 0, 0) — the hi/lo split recovers ~f32 accuracy.
@@ -500,15 +561,55 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
             ch = jnp.where(sub8 == k, c, ch)
         if quant:
             # f32 -> int8 is exact: codes are integers with |code| <= 127
-            return ch.astype(i32).astype(jnp.int8)
-        return ch.astype(jnp.bfloat16)
+            return ch.astype(i32).astype(ch_staged)
+        return ch.astype(ch_staged)
+
+    def group_product(chT, *rows):
+        """Partial sums of one matmul group of features over one staged
+        block (hist_contract): ``chT`` its [8, bs] channel operand as
+        staged, ``rows`` the features' [1, bs] bin rows (None: a pad
+        feature). [8 G, wc*OW]; bin-major [wc*OW, 8] on the sublane arm."""
+        ohT = jnp.concatenate(
+            [jnp.zeros((OW, bs), oh_src) if b is None else
+             jnp.where(sub_b == (b if G == 1 else b & (OW - 1)), one, zero)
+             for b in rows], axis=0).astype(cht)               # [wc*OW, bs]
+        if G > 1:
+            # row r of a feature's [8 G, bs] streamed rows holds the
+            # channels of the block's rows whose hi is r // 8
+            hi_of_row = lax.broadcasted_iota(i32, (8 * G, bs), 0) >> 3
+            chG = jnp.concatenate([chT] * G, axis=0)
+            none = jnp.zeros_like(chG)
+            chT = jnp.concatenate(
+                [none if b is None else
+                 jnp.where(hi_of_row == (b >> (OW.bit_length() - 1)),
+                           chG, none) for b in rows],
+                axis=0).astype(cht)                            # [16 G, bs]
+        lhs, rhs = (ohT, chT) if hist_layout == "sublane" else (chT, ohT)
+        part = lax.dot_general(
+            lhs, rhs, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=acc_t)
+        if G > 1:
+            # the pair's diagonal blocks: feature 0's rows on lanes
+            # 0-63, feature 1's on 64-127
+            first = lax.broadcasted_iota(i32, (8 * G, 2 * OW), 1) < OW
+            part = jnp.where(first, part[:8 * G], part[8 * G:])
+        return part
+
+    if G > 1:
+        # traced once a kernel and inlined at every pair of every flush
+        # (Pallas lowers a jit call in place): the two-level body is 2.5
+        # times the one-level body's equations, and the step's trace and
+        # lowering are in every run's set-up (PERF.md section 6, PR 38)
+        group_product = jax.jit(group_product)
 
     def hist_contract(slots):
         """One-hot contraction of staged blocks against their channel
         operands, accumulated into hist_ref. ``slots``: (ring slot,
-        [8, bs] channel operand) pairs.
+        [8, bs] channel operand) pairs. group_product builds the two
+        operands of one group of features and one block and multiplies
+        them; this is the loop over groups and blocks, and what it does.
 
-        A feature's one-hot is born transposed, [BS_, bs]: its bin row
+        A feature's one-hot is born transposed, [OW, bs]: its bin row
         against a sublane iota, one compare a register and no lane
         broadcast. A group's features concatenate along sublanes (aligned
         at every stride _hist_packing produces; grouping bounds the
@@ -519,36 +620,56 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         ``vmatpush.bf16.xpose.msk``). Cast a feature at a time, as through
         PR 32, the pieces take the f32 tiling and are unpacked and packed
         again on their way to the MXU: 36% of the old flush's VALU work.
-        The one-hot stays the MXU's weights and the channels its 8
-        streamed rows, as before. What a histogram row costs now IS its
-        weight tiles: F x B x bs / 2,048 transposed pushes a block, which
-        the chip takes at 0.52 a cycle (5.2 ns a row on higgs, 1.7 at 63
-        bins, 37.5 at 220 features; they were 9.1, 10.7 and 58.4). Each
-        block's partial sums fold before the one add into the accumulator,
-        so a flush of K blocks rounds as one (module docstring).
+        The one-hot is the operand the MXU LOADS, as its weights, and the
+        channels are the rows that STREAM through them. What a histogram
+        row costs is the push count: one-hot rows x bs / 2,048 transposed
+        pushes a block (bf16), which the chip takes at 0.52 a cycle
+        whatever the compiler schedules.
+
+        One level (``G`` = 1: a stride of 64 bins or less): the one-hot
+        spans the feature's stride and the [8, bs] channel operand streams
+        as it was staged, the same for every feature: F x stride x bs /
+        2,048 pushes a block (1.7 ns a row at 28 x 64 bins).
+
+        Two levels (a stride of 128 or 256: ``G`` = 2 or 4): bin = 64 hi
+        + lo, and H[c, 64 hi + lo] = sum over rows of (ch[c] . [hi_row =
+        hi]) x [lo_row = lo]. Only the 64-wide one-hot of ``lo`` is
+        loaded, two features a 128-lane tile: F x 64 x bs / 2,048 pushes,
+        a quarter of the one-level count at 256 bins (through PR 37 every
+        feature loaded all its 256 bins for one 8-row matmul a tile: 5.2
+        ns a row on higgs, 37.5 at 220 features). The streamed operand
+        takes the other level: a feature's rows are the channels where
+        the row's ``hi`` is k and 0 elsewhere, k = 0..G-1 stacked to
+        [8 G, bs] (one select against the staged operand tiled G times),
+        the pair's two features stacked again to [16 G, bs] and cast
+        ONCE, like the weights. The product is [16 G, 128]; its two
+        diagonal blocks (feature 0's rows on lanes 0-63, feature 1's on
+        64-127) are the pair's histograms and one select keeps them, so
+        the accumulator is [8 G, F_pad x 64], row 8 k + c holding channel
+        c of the bins 64 k .. 64 k + 63 (fused_split undoes it). Every
+        nonzero term of every sum is the one-level form's ``ch x 1`` at
+        the same row, the rest exact zeros.
+
+        Each block's partial sums fold before the one add into the
+        accumulator, so a flush of K blocks rounds as one (module
+        docstring).
 
         hist_layout="sublane" (tpu_hist_layout, B <= 64) swaps the roles
         of the same two operands: the one-hot streams and the channels
         are the weights, so the output lands BIN-major [group, 8]."""
-        sublane = hist_layout == "sublane"
         fc = 0
         while fc < F_pad:
             wc = min(group_w, F_pad - fc)
             red = None
             for t, chT in slots:
-                ohT = jnp.concatenate(
-                    [jnp.where(sub_b == bin_row(t, fc + j), one, zero)
-                     if fc + j < F else jnp.zeros((BS_, bs), oh_src)
-                     for j in range(wc)], axis=0).astype(cht)  # [wc*BS_, bs]
-                lhs, rhs = (ohT, chT) if sublane else (chT, ohT)
-                part = lax.dot_general(
-                    lhs, rhs, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=acc_t)
+                part = group_product(
+                    chT, *(bin_row(t, f) if f < F else None
+                           for f in range(fc, fc + wc)))
                 red = part if red is None else red + part
-            if sublane:
-                hist_ref[fc * BS_:(fc + wc) * BS_, :] += red   # [wc*BS_, 8]
+            if hist_layout == "sublane":
+                hist_ref[fc * OW:(fc + wc) * OW, :] += red     # [wc*OW, 8]
             else:
-                hist_ref[:, fc * BS_:(fc + wc) * BS_] += red   # [8, wc*BS_]
+                hist_ref[:, fc * OW:(fc + wc) * OW] += red     # [8G, wc*OW]
             fc += wc
 
     def hist_flush(n_valid):
@@ -574,18 +695,19 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
             # timing bisect probes: the pre-batching behavior, slot 0 only
             stage_block(0, rows_u8)
             if hist_debug == "assembly":
-                hist_ref[:, 0:128] += lax.dot_general(
-                    assemble_chT(0, mask), jnp.ones((128, bs), jnp.bfloat16),
+                hist_ref[0:8, 0:128] += lax.dot_general(
+                    assemble_chT(0, mask).astype(jnp.bfloat16),
+                    jnp.ones((128, bs), jnp.bfloat16),
                     dimension_numbers=(((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
             elif hist_debug == "matmul":
-                hist_contract([(0, jnp.ones((8, bs), jnp.bfloat16))])
+                hist_contract([(0, jnp.ones((8, bs), ch_staged))])
             elif hist_debug == "matmul2":
                 # data-dependent but trivially cheap channels: defeats
                 # constant folding/hoisting so the matmuls' true cost is
                 # measured
                 hist_contract([(0, (pendT[0, 0:8, :] + 1)
-                                .astype(jnp.bfloat16))])
+                                .astype(ch_staged))])
             else:   # "sync"
                 hist_contract([(0, assemble_chT(0, mask))])
             return
@@ -950,7 +1072,10 @@ def fused_split(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One fused split. Returns (work', scratch', hist_smaller [F, B, 4]);
     the histogram is int32 when ``quant`` (quantized-gradient codes,
-    int8 x int8 -> int32 contraction — see assemble_chT).
+    int8 x int8 -> int32 contraction — see assemble_chT). The kernel's
+    own accumulator is [8 G, F_pad x OW] (_hist_flush_shape: G levels of
+    hi where a feature's stride is over 64 bins); it is undone here, in
+    the one fusion after the call, and no caller sees it.
 
     ``mbatch`` (env/param ``tpu_hist_mbatch``) is the depth of the
     histogram pending ring: K staged row blocks are contracted together,
@@ -1010,7 +1135,7 @@ def fused_split(
     if block_size % _A:
         raise ValueError(f"block_size must be a multiple of {_A}")
     B = num_bins
-    BS_, F_pad, _ = _hist_packing(F, B)
+    G, OW, F_pad, _ = _hist_flush_shape(F, B, hist_layout)
     i32 = jnp.int32
 
     n_rows = work.shape[0]
@@ -1065,7 +1190,12 @@ def fused_split(
         hist_debug = ""     # bisect probes assume the lane accumulator
     mbatch = max(1, min(int(mbatch), 16))   # 8*mbatch <= 128 MXU rows
     hist_t = jnp.int32 if quant else jnp.float32
-    ch_t = jnp.int8 if quant else jnp.bfloat16
+    # the staged channel operand: cast as assembled at one level, kept
+    # uncast until the flush has stacked it by hi at two (hist_contract)
+    if G == 1:
+        ch_t = jnp.int8 if quant else jnp.bfloat16
+    else:
+        ch_t = hist_t
     kernel = functools.partial(
         _fused_kernel, layout=layout, num_bins=B, bs=bs, bitset_words=W,
         interpret=interpret, dual=dual,
@@ -1099,7 +1229,8 @@ def fused_split(
                  else pltpu.VMEM((2, bs, C), jnp.uint8)),   # auxbuf
                 # pending ring: K staged blocks, TRANSPOSED (the byte
                 # columns the histogram reads as [HR, bs] i32 rows), and
-                # their [8, bs] channel operands (hist_accum)
+                # their [8, bs] channel operands (hist_accum), cast
+                # already where the flush is one level
                 pltpu.VMEM((mbatch, _hist_rows(layout), bs),
                            jnp.int32),                    # pendT
                 pltpu.VMEM((mbatch, 8, bs), ch_t),        # pendch
@@ -1109,9 +1240,9 @@ def fused_split(
         out_shape=[
             jax.ShapeDtypeStruct(work.shape, work.dtype),
             jax.ShapeDtypeStruct(scratch.shape, scratch.dtype),
-            (jax.ShapeDtypeStruct((F_pad * BS_, 8), hist_t)
+            (jax.ShapeDtypeStruct((F_pad * OW, 8), hist_t)
              if hist_layout == "sublane"
-             else jax.ShapeDtypeStruct((8, F_pad * BS_), hist_t)),
+             else jax.ShapeDtypeStruct((8 * G, F_pad * OW), hist_t)),
         ],
         input_output_aliases={2: 0, 3: 1},
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
@@ -1120,10 +1251,19 @@ def fused_split(
     )(sp, cat_bitset, work, scratch)
 
     if hist_layout == "sublane":
-        # bin-major accumulator: [F*BS_, 8] -> [F, B, 4] with no transpose
-        hb = hist8.reshape(F_pad, BS_, 8)[:F, :B, :]
+        # bin-major accumulator: [F*OW, 8] -> [F, B, 4] with no transpose
+        hb = hist8.reshape(F_pad, OW, 8)[:F, :B, :]
         hist = hb[:, :, :4] + hb[:, :, 4:]
     else:
-        hist8 = hist8.reshape(8, F_pad, BS_)[:, :F, :B]
+        # row 8 k + c, lane 64 f + lo (hist_contract) -> [8, F, bins]: the
+        # G levels side by side along the bins, as a concatenate and not a
+        # transpose of the [G, 8, F, OW] view. XLA lays a transposed view
+        # out as a bitcast and hands the odd layout on: under shard_map the
+        # grower's [leaves, F, B x 4] histogram pool took it and was copied
+        # whole at every split, 0.21 s an iteration on four chips (PERF.md
+        # section 6, PR 38). From [8, F, B] on this is the one-level code
+        hist8 = jnp.concatenate(
+            [hist8[8 * k:8 * (k + 1)].reshape(8, F_pad, OW)
+             for k in range(G)], axis=2)[:, :F, :B]        # [8, F, B]
         hist = jnp.transpose(hist8[:4] + hist8[4:], (1, 2, 0))  # [F, B, 4]
     return work_o, scr_o, hist

@@ -247,13 +247,23 @@ class TestFusedSplit:
 
 
 # ------------------------------------------- the histogram's lane build
-# (bins, quant, packed4, block): every bin stride _hist_packing produces
-# (16, 64, 128-padded, 256), both channel layouts, nibble-packed bins, a
-# block of whole lane tiles and one that leaves a tile part empty
+# (bins, quant, packed4, block, features, depth): every bin stride
+# _hist_packing produces (16, 64, 128-padded, 256), both channel layouts,
+# nibble-packed bins, a block of whole lane tiles and one that leaves a
+# tile part empty
 LANE_HIST_CASES = (
-    [(b, q, False, bs) for b in (16, 64, 100, 256) for q in (False, True)
+    [(b, q, False, bs, 5, 2) for b in (16, 64, 100, 256) for q in (False, True)
      for bs in (128, 192)]
-    + [(16, False, True, 128), (16, True, True, 128), (16, False, True, 192)])
+    + [(16, False, True, 128, 5, 2), (16, True, True, 128, 5, 2),
+       (16, False, True, 192, 5, 2)]
+    # the two-level flush (bin = 64 hi + lo): a stride of 256 (G = 4) with
+    # its last bin empty and full, a stride of 128 (G = 2); a last pair
+    # half empty (5, 7 features) and whole pairs; one and two lane tiles a
+    # block; the flush of one staged block and of two
+    + [(b, q, False, bs, f, k) for b in (255, 256, 100) for q in (False, True)
+       for bs, f, k in ((128, 5, 1), (256, 4, 1), (256, 7, 2), (128, 6, 2))]
+    # 8 bins or fewer pad to a whole tile, a stride of 128: two levels too
+    + [(8, False, False, 128, 5, 2), (8, True, True, 128, 5, 2)])
 
 
 def _lane_rows(rng, n, f, b, quant, packed4):
@@ -292,14 +302,17 @@ def _assert_hist(hist, ref, quant):
 
 
 @pytest.mark.parametrize("mode", [0, 1])
-@pytest.mark.parametrize("b,quant,packed4,bs", LANE_HIST_CASES)
-def test_lane_histogram_against_reference(rng, b, quant, packed4, bs, mode):
+@pytest.mark.parametrize("b,quant,packed4,bs,f,depth", LANE_HIST_CASES)
+def test_lane_histogram_against_reference(rng, b, quant, packed4, bs, f,
+                                          depth, mode):
     """The histogram half's build (rows along lanes) against the XLA
     reference: a segment whose start leaves ``phi`` head rows in its first
     block (and ``psi`` in the right stream's), whose smaller child fills
     whole blocks and a masked tail. Counts exact, grad/hess within the
-    hi/lo-bf16 tolerance, quantized sums exact."""
-    n, f, start, count, feat, thr = 1000, 5, 37, 901, 1, (b - 1) // 3
+    hi/lo-bf16 tolerance, quantized sums exact: at more than 64 bins those
+    int32 sums are the bit-for-bit check that the two-level contraction
+    sums the one-level form's products."""
+    n, start, count, feat, thr = 1000, 37, 901, 1, (b - 1) // 3
     layout, binned, channels, work = _lane_rows(rng, n, f, b, quant, packed4)
     seg = np.arange(start, start + count)
     left = seg[binned[seg, feat] <= thr]
@@ -312,6 +325,6 @@ def test_lane_histogram_against_reference(rng, b, quant, packed4, bs, mode):
         jnp.asarray(len(left), i32), jnp.asarray(feat, i32),
         jnp.asarray(thr, i32), jnp.asarray(0, i32), jnp.asarray(0, i32),
         jnp.asarray(0, i32), jnp.zeros((8,), jnp.uint32), layout, b, bs, 8,
-        interpret=True, num_rows=n, quant=quant, mbatch=2)
+        interpret=True, num_rows=n, quant=quant, mbatch=depth)
     _assert_hist(hist, _reference_hist(binned, channels, rows, b, quant),
                  quant)

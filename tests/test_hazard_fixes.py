@@ -93,9 +93,12 @@ def test_env_override_clamped_to_vmem_cap():
 
 # ------------------------------------------- ADVICE #4: docstring accuracy
 def test_hist_contract_docstring_matches_implementation():
-    """The one-hot's build as the code has it since PR 33 (``hist_matmuls``
-    through PR 32): a feature's bin row against a sublane iota, no lane
-    gather or broadcast, and no account of MXU tile loads as the cost."""
+    """The flush as the code has it since PR 38 (``hist_matmuls`` through
+    PR 32; one level at every stride through PR 37): a feature's bin row
+    against a sublane iota, no lane gather or broadcast; the one-hot is
+    the operand the MXU loads and the channels stream; at more than 64
+    bins a bin is 64 hi + lo, the one-hot spans ``lo`` and the streamed
+    rows are stacked by ``hi``; the cost is the push count."""
     src = open(os.path.join(
         REPO, "lightgbm_tpu", "ops", "fused_split.py")).read()
     assert "def hist_matmuls" not in src
@@ -104,6 +107,18 @@ def test_hist_contract_docstring_matches_implementation():
     assert "constant-index lane gather" not in doc
     assert "jnp.repeat" not in doc and "tile loads a row" not in doc
     assert "bin row" in doc and "sublane iota" in doc
+    # two levels, and which operand is which
+    assert "bin = 64 hi" in doc and "Two levels" in doc and "One level" in doc
+    assert re.search(r"one-hot is the operand the MXU LOADS", doc)
+    assert re.search(r"channels are the rows that STREAM", doc)
+    assert "[16 G, bs]" in doc and "[8 G, F_pad x 64]" in doc
+    # the cost is the transposed pushes, not the compiler's bundles
+    assert "push count" in doc and "/ 2,048" in doc
+    # and the code reads G off the stride: no key, no probe, no override
+    body = src[src.index("def group_product"):src.index("def hist_flush")]
+    assert "if G > 1" in body and "os.environ" not in body
+    assert ("G, OW, F_pad, group_w = _hist_flush_shape(F, B, hist_layout)"
+            in src)
 
 
 # --------------------------------------------- bench backend-init retry

@@ -1,0 +1,98 @@
+"""``hist_levels`` rides every ``iteration`` event: how many levels the
+one-hot of the fused kernel's histogram flush has in the step that ran (2:
+bin = 64 hi + lo, more than 64 bins a feature; 1: the whole stride; 0: the
+fused kernel is off), and the benchmark's ``kernel.hist_levels`` reads it
+from the flight ring through the ``update_loop`` reducer that is there."""
+import json
+
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.obs import flight
+from lightgbm_tpu.ops.fused_split import hist_levels
+
+from benchmarks import run as bench_run
+
+METRIC = "kernel.hist_levels"
+CELLS = ["higgs_train", "higgs_b63_train", "istella_train",
+         "criteo_dp4_train", "higgs_quant_train"]
+
+
+@pytest.mark.parametrize("features,bins,want", [
+    (28, 256, 2), (28, 255, 2), (220, 256, 2), (67, 256, 2),   # stride 256
+    (28, 100, 2), (28, 65, 2), (5, 128, 2),                    # stride 128
+    (28, 63, 2),      # an awkward count under 64 pads to a stride of 128
+    (28, 64, 1), (28, 32, 1), (28, 16, 1), (28, 48, 1), (28, 8, 2)])
+def test_levels_are_read_off_the_bin_stride(features, bins, want):
+    assert hist_levels(features, bins) == want
+
+
+def ticks(max_bin, **more):
+    rng = np.random.RandomState(11)
+    x = rng.randn(1500, 6).astype(np.float32)
+    y = (x[:, 0] - 0.5 * x[:, 3] + 0.4 * rng.randn(1500) > 0).astype(float)
+    params = dict({"objective": "binary", "num_leaves": 7,
+                   "max_bin": max_bin, "min_data_in_leaf": 20,
+                   "verbosity": -1, "tpu_grower": "compact",
+                   "tpu_fused_interpret": True, "tpu_fused_block": 128},
+                  **more)
+    flight.recorder().clear()
+    lgb.train(params, lgb.Dataset(x, label=y, params=params), 2)
+    return [e for e in flight.recorder().events()
+            if e["event"] == "iteration"]
+
+
+@pytest.mark.parametrize("max_bin,more,want", [
+    (255, {}, 2),
+    (255, {"use_quantized_grad": True}, 2),
+    (63, {}, 1),
+    (255, {"tpu_fused": "off"}, 0),
+    (63, {"tpu_fused": "off"}, 0),
+    (255, {"tpu_grower": "masked"}, 0),
+])
+def test_hist_levels_rides_every_iteration_event(max_bin, more, want):
+    got = ticks(max_bin, **more)
+    assert len(got) == 2
+    assert [e["hist_levels"] for e in got] == [want, want]
+
+
+# ------------------------------------------------- the benchmark's metric
+def hand_made_run(**counters):
+    tick = dict({"dispatches": 1, "host_syncs": 1}, **counters)
+    return {
+        "iterations": 2,
+        "spans": [("update", 0.0, 1.0), ("update", 1.0, 2.0)],
+        "records": {"spans": [("iteration", 0.0, 1.0, None, 5)],
+                    "compiles": [],
+                    "iterations": [dict(tick, t1=0.9), dict(tick, t1=1.9)]},
+    }
+
+
+@pytest.mark.parametrize("levels", [2, 1, 0])
+def test_the_metric_reads_the_counter_through_the_harness(levels):
+    got = bench_run.per_layer_metrics([METRIC],
+                                      hand_made_run(hist_levels=levels))
+    assert got[METRIC]["value"] == levels
+    assert got[METRIC]["unit"] == "count"
+
+
+def test_a_program_without_the_counter_leaves_the_metric_out():
+    # the parent of PR 38 has no such counter: its side reads nothing
+    assert bench_run.per_layer_metrics([METRIC], hand_made_run()) == {}
+
+
+def test_the_benchmark_lists_the_metric_for_all_five_cells():
+    with open(bench_run.ROOT + "/BENCHMARK.json") as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": METRIC, "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "train_s_per_iter", "workloads": CELLS}
+    with open(bench_run.ROOT + "/benchmarks/metrics/" + METRIC + ".json") as f:
+        spec = json.load(f)
+    assert spec["reducer"] == "update_loop"
+    assert spec["args"] == {"what": "hist_levels"}
+    for key in ("name", "unit", "better", "layer", "source", "moves"):
+        assert spec[key] == entry[key], key

@@ -308,6 +308,38 @@ CELLS = {
 }
 
 
+# all five cells of the benchmark: what `registry.fit_fused_flush` gives
+# each is held where PR 33 and PR 30 measured it, whatever the flush costs
+# (PR 38's two-level one-hot left block and depth alone: same rows, same
+# groups, same sums)
+FLUSH_OF_CELL = {
+    "higgs_train": ({}, registry.DatasetShape(10_500_000, 28, 255), 128,
+                    (384, 2)),
+    "higgs_b63_train": ({}, registry.DatasetShape(10_500_000, 28, 63), 128,
+                        (384, 2)),
+    "istella_train": ({}, registry.DatasetShape(7_325_625, 220, 255), 256,
+                      (256, 1)),
+    "criteo_dp4_train": ({"tree_learner": "data"},
+                         registry.DatasetShape(40_000_000, 67, 255, "data"),
+                         128, (384, 2)),
+    "higgs_quant_train": ({"use_quantized_grad": True},
+                          registry.DatasetShape(10_500_000, 28, 255,
+                                                quant=True), 128, (384, 2)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(FLUSH_OF_CELL))
+def test_fit_fused_flush_holds_the_five_cells_block_and_depth(monkeypatch,
+                                                              cell):
+    monkeypatch.delenv("LGBM_TPU_HIST_MBATCH", raising=False)
+    monkeypatch.delenv("LGBM_TPU_FUSED_BS", raising=False)
+    params, shape, cols, want = FLUSH_OF_CELL[cell]
+    res = registry.resolve(Config(params), shape=shape, platform="tpu")
+    assert res.entry_id == "fused_lane"
+    assert registry.fit_fused_flush(res, cols, shape.num_bins + 1,
+                                    shape.features) == want
+
+
 def _craft_old_cache(home, platform, shape):
     """An ``autotune.json`` at the path the start-up sweep used to keep
     (``~/.cache/lightgbm_tpu``), naming sublane / pallas / depth 16 under
