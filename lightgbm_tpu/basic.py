@@ -636,16 +636,21 @@ class Booster:
             # BEFORE sample_step: the sampling overhead (barrier wait
             # for a slow peer, the rank-0 KV gather) must not inflate
             # the metrics stream's iteration wall
-            rank_stats = getattr(self._gbdt, "_rank_stats", None)
-            if rank_stats is not None and rank_stats.due(self._gbdt.iter_):
-                import jax
-                bump("host_syncs")
-                jax.block_until_ready(self._gbdt.train_score)
-                elapsed = time.perf_counter() - t0
-                rank_stats.sample_step(self._gbdt.iter_, elapsed)
-            else:
-                elapsed = time.perf_counter() - t0
-            self._gbdt._obs_iteration_tick(elapsed)
+            # span `update_tick`: the update's own telemetry, the last
+            # thing in it (its seconds are in no `phase_s`: the tick
+            # writes that table before this span closes)
+            with span("update_tick"):
+                rank_stats = getattr(self._gbdt, "_rank_stats", None)
+                if rank_stats is not None and rank_stats.due(
+                        self._gbdt.iter_):
+                    import jax
+                    bump("host_syncs")
+                    jax.block_until_ready(self._gbdt.train_score)
+                    elapsed = time.perf_counter() - t0
+                    rank_stats.sample_step(self._gbdt.iter_, elapsed)
+                else:
+                    elapsed = time.perf_counter() - t0
+                self._gbdt._obs_iteration_tick(elapsed)
         # a stop detected by a mid-training flush (e.g. in reset_parameter)
         pending, self._pending_finish = self._pending_finish, False
         return finished or pending

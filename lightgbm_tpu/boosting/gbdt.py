@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import json
 import os
 import threading
 import time
@@ -34,7 +35,8 @@ import numpy as np
 from ..io.dataset import BinnedDataset
 from ..metrics import Metric
 from ..objectives import Objective
-from ..obs.spans import bump, span, update_counters
+from ..obs.spans import (SlowUpdates, bump, span, update_counters,
+                         update_phases)
 from ..ops.compact import RowLayout, pack_rows, segments_to_leaf_vectors
 from ..ops.grower import (GrowerParams, TreeArrays, depth_rung, grow_tree,
                           leaf_rung)
@@ -500,6 +502,7 @@ class GBDT:
         # metrics stream when tpu_metrics_path is set
         from .. import obs as _obs
         self._metrics_stream = _obs.configure(config)
+        self._slow_updates = SlowUpdates()
 
         if train_set is not None:
             self._setup_train(train_set)
@@ -2022,28 +2025,18 @@ class GBDT:
                   if self._cx_weight is not None else None)
         return c["grad_fn"](self.train_score, label, weight)
 
-    def _train_one_iter_compact(self) -> bool:
-        """Compact-path iteration (same contract as train_one_iter)."""
-        self._boost_from_average()
+    def _compact_shared_args(self, strat, mask):
+        """What the trees of one compact iteration share among the step's
+        arguments: (bag mask over the work rows, whether the bag is
+        fresh, feature mask)."""
         c = self._compact
-        if c["step"] is None:
-            with span("build_step"):
-                c["step"] = self._build_compact_step_fn()
-        strat = self.sample_strategy
-        n = self.num_data      # bag vectors align with work rows (incl. pad)
-
-        # GOSS ranks rows by gradient magnitude; compute in current order
-        g = h = None
-        if strat.is_hessian_change:
-            g, h = self._dispatch("gradient", self._compact_gradients)
-        with span("bag"):
-            mask = strat.bag_mask(self.iter_, g, h)
         # fresh == the strategy actually drew a new bag this iteration; a
         # reused (cached) bag must come from the stored sample-weight column,
         # which rode the partitions and is in the current row order — the
         # host-cached vector is not
         fresh = getattr(strat, "last_fresh", mask is not None)
         if mask is None:
+            n = self.num_data  # bag vectors align with work rows (incl. pad)
             if self.mesh is None:
                 mask = jnp.ones((n,), jnp.float32)
             else:
@@ -2058,10 +2051,25 @@ class GBDT:
             fresh = self.iter_ == 0 or fresh
         if getattr(strat, "_amplify", None) is not None:
             mask = mask * strat._amplify
+        return mask, fresh, self._feature_mask()
 
-        feat_mask = self._feature_mask()
-        first_iter = self.num_total_trees < self.num_tree_per_iteration
-        k_total = self.num_tree_per_iteration
+    def _train_one_iter_compact(self) -> bool:
+        """Compact-path iteration (same contract as train_one_iter)."""
+        self._boost_from_average()
+        c = self._compact
+        if c["step"] is None:
+            with span("build_step"):
+                c["step"] = self._build_compact_step_fn()
+        strat = self.sample_strategy
+
+        # GOSS ranks rows by gradient magnitude; compute in current order
+        g = h = None
+        if strat.is_hessian_change:
+            g, h = self._dispatch("gradient", self._compact_gradients)
+        # span `bag`: only where the strategy draws or reuses a bag
+        with (span("bag") if strat.samples(self.iter_)
+              else contextlib.nullcontext()):
+            mask = strat.bag_mask(self.iter_, g, h)
         ext_args = ()
         if getattr(self, "_ext_grads", False):
             # lambdarank-style coupled gradients: computed once per
@@ -2070,21 +2078,35 @@ class GBDT:
             ext_args = tuple(self._dispatch(
                 "rank_grads", rank_grads, c["work"], self.train_score,
                 c["rank_grad_layout"]))
+        first_iter = self.num_total_trees < self.num_tree_per_iteration
+        k_total = self.num_tree_per_iteration
         for k in range(k_total):
-            # trees after the first in an iteration reuse the stored bag
-            # (same bag for all trees of one iteration, like the reference)
-            use_stored = not (fresh and k == 0)
+            # span `step_args`, one per tree: everything the host does
+            # for the step's arguments, so that `step_dispatch` times the
+            # call alone (the first tree's holds the iteration's shared
+            # ones: the bag's mask and the feature mask)
+            with span("step_args"):
+                if k == 0:
+                    mask, fresh, feat_mask = self._compact_shared_args(
+                        strat, mask)
+                # trees after the first in an iteration reuse the stored
+                # bag (same bag for all trees of one iteration, like the
+                # reference)
+                use_stored = not (fresh and k == 0)
+                args = (
+                    c["work"], c["scratch"], self.train_score, mask,
+                    jnp.asarray(use_stored), feat_mask,
+                    jnp.float32(self.shrinkage_rate),
+                    jax.random.fold_in(self._bynode_key,
+                                       self.num_total_trees),
+                    self._cegb_state(),
+                    jax.random.fold_in(self._quant_key, self.iter_),
+                    jax.random.fold_in(self._extra_key,
+                                       self.num_total_trees),
+                    *self._step_budget_args(), *ext_args)
             (tree, work, scratch, scores,
              self._cegb_used) = self._dispatch(
-                "step_dispatch", c["step"],
-                c["work"], c["scratch"], self.train_score, mask,
-                jnp.asarray(use_stored), feat_mask,
-                jnp.float32(self.shrinkage_rate),
-                jax.random.fold_in(self._bynode_key, self.num_total_trees),
-                self._cegb_state(),
-                jax.random.fold_in(self._quant_key, self.iter_),
-                jax.random.fold_in(self._extra_key, self.num_total_trees),
-                *self._step_budget_args(), *ext_args, k=k)
+                "step_dispatch", c["step"], *args, k=k)
             c["work"], c["scratch"] = work, scratch
             c["epoch"] += 1
             self.train_score = scores
@@ -2096,6 +2118,10 @@ class GBDT:
             # NOTE: appends do NOT invalidate the device-tree cache — the
             # bucketed cache append-pads new trees in (mid-train predict
             # used to re-stack the whole model every iteration)
+            # the pending list holds the tree now; without this frame's
+            # reference the flush releases its device arrays under
+            # `decode_trees`, not unnamed on the way out of here
+            del tree
 
         self.iter_ += 1
         if len(self._dev_trees) >= k_total * self.stop_check_freq:
@@ -2365,6 +2391,26 @@ class GBDT:
             mask[chosen] = True
         return jnp.asarray(mask)
 
+    def _masked_shared_args(self, mask, grad, hess):
+        """What the trees of one masked-path iteration share among the
+        step's arguments: (bag mask, feature mask, the gradients the
+        grower histograms, the true gradients)."""
+        if mask is None:
+            mask = jnp.ones((self.num_data,), jnp.float32)
+        if self._valid_row_mask is not None:
+            mask = mask * self._valid_row_mask
+        feat_mask = self._feature_mask()
+        true_grad, true_hess = grad, hess
+        if self._use_quant:
+            # one global-scale quantization per iteration over all classes
+            # (reference: DiscretizeGradients on the full k*N buffer)
+            grad, hess = _quantize_gradients(
+                grad, hess,
+                jax.random.fold_in(self._quant_key, self.iter_),
+                self._quant_bins, self._quant_stochastic,
+                bool(getattr(self.objective, "is_constant_hessian", False)))
+        return mask, feat_mask, grad, hess, true_grad, true_hess
+
     def train_one_iter(
         self,
         gradients: Optional[np.ndarray] = None,
@@ -2372,7 +2418,7 @@ class GBDT:
     ) -> bool:
         """Train trees for one iteration; True when training should stop
         (reference: GBDT::TrainOneIter, gbdt.cpp:344)."""
-        k, n = self.num_tree_per_iteration, self.num_data
+        k = self.num_tree_per_iteration
         if self._use_compact:
             if gradients is not None or hessians is not None:
                 if self._compact is not None:
@@ -2408,42 +2454,37 @@ class GBDT:
             # zero padding-row gradients before GOSS ranks them
             grad = grad * self._valid_row_mask[None, :]
             hess = hess * self._valid_row_mask[None, :]
-        with span("bag"):
-            mask = self.sample_strategy.bag_mask(self.iter_, grad, hess)
-            grad, hess = self.sample_strategy.scale_grad_hess(
-                mask, grad, hess)
-        if mask is None:
-            mask = jnp.ones((n,), jnp.float32)
-        if self._valid_row_mask is not None:
-            mask = mask * self._valid_row_mask
-
-        feat_mask = self._feature_mask()
-        first_iter = self.num_total_trees < self.num_tree_per_iteration
         if self._step_fn is None:
             with span("build_step"):
                 self._step_fn = self._build_step_fn()
-        true_grad, true_hess = grad, hess
-        if self._use_quant:
-            # one global-scale quantization per iteration over all classes
-            # (reference: DiscretizeGradients on the full k*N buffer)
-            grad, hess = _quantize_gradients(
-                grad, hess,
-                jax.random.fold_in(self._quant_key, self.iter_),
-                self._quant_bins, self._quant_stochastic,
-                bool(getattr(self.objective, "is_constant_hessian", False)))
-
+        strat = self.sample_strategy
+        # span `bag`: only where the strategy draws or reuses a bag
+        with (span("bag") if strat.samples(self.iter_)
+              else contextlib.nullcontext()):
+            mask = strat.bag_mask(self.iter_, grad, hess)
+            grad, hess = strat.scale_grad_hess(mask, grad, hess)
+        first_iter = self.num_total_trees < self.num_tree_per_iteration
         for cur_tree_id in range(k):
+            # span `step_args`, one per tree (the first tree's holds what
+            # the iteration's trees share), as on the compact path
+            with span("step_args"):
+                if cur_tree_id == 0:
+                    (mask, feat_mask, grad, hess, true_grad,
+                     true_hess) = self._masked_shared_args(mask, grad, hess)
+                args = (
+                    self.binned, self.train_score[cur_tree_id],
+                    grad[cur_tree_id], hess[cur_tree_id], mask, feat_mask,
+                    jnp.float32(self.shrinkage_rate),
+                    jax.random.fold_in(self._bynode_key,
+                                       self.num_total_trees),
+                    self._cegb_state(),
+                    true_grad[cur_tree_id], true_hess[cur_tree_id],
+                    jax.random.fold_in(self._extra_key,
+                                       self.num_total_trees),
+                    self._cegb_charged_state(), *self._step_budget_args())
             (tree, row_leaf, new_score, self._cegb_used,
              self._cegb_charged) = self._dispatch(
-                "step_dispatch", self._step_fn, self.binned,
-                self.train_score[cur_tree_id], grad[cur_tree_id],
-                hess[cur_tree_id], mask, feat_mask,
-                jnp.float32(self.shrinkage_rate),
-                jax.random.fold_in(self._bynode_key, self.num_total_trees),
-                self._cegb_state(),
-                true_grad[cur_tree_id], true_hess[cur_tree_id],
-                jax.random.fold_in(self._extra_key, self.num_total_trees),
-                self._cegb_charged_state(), *self._step_budget_args())
+                "step_dispatch", self._step_fn, *args)
             if self._linear:
                 split_ok = self._linear_tree_iter(
                     tree, row_leaf, true_grad[cur_tree_id],
@@ -2514,10 +2555,18 @@ class GBDT:
         the device — ``flush_trees`` does every ``stop_check_freq``-th
         update, and then ``seconds`` holds the step's device time too.
         ``dispatches``, ``host_syncs`` and ``d2h_bytes`` are the update's
-        counters (obs/spans.py); ``t1`` is now on the spans' clock."""
+        counters (obs/spans.py); ``t1`` is now on the spans' clock.
+        ``phase_s`` holds the seconds of every span closed inside this
+        update, by name, and ``cpu_s`` the CPU seconds the thread was
+        given in it (``spans.update_phases``). An update far slower than
+        the booster's recent ones writes a ``slow_iteration`` record and
+        a warning beside its event (``spans.SlowUpdates``)."""
         from ..analysis import guards
         from ..obs import flight
         counters = update_counters()
+        phase_s, cpu_s = update_phases()
+        slow = self._slow_updates.check(
+            self.iter_, seconds, counters["host_syncs"], phase_s, cpu_s)
         # what a ranking objective's layout by query length makes the
         # gradient program compute (objectives.py): fixed at init
         counters.update(getattr(self.objective, "rank_counters", {}))
@@ -2531,11 +2580,15 @@ class GBDT:
         counters.update(getattr(self, "_hist_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),
-                    **counters)
+                    phase_s=phase_s, cpu_s=cpu_s, **counters)
+        if slow is not None:
+            flight.note("slow_iteration", **slow)
+            log.warning("slow_iteration " + json.dumps(slow))
         stream = getattr(self, "_metrics_stream", None)
         if stream is not None:
             stream.emit("iteration", iteration=self.iter_,
-                        seconds=round(seconds, 6), **counters,
+                        seconds=round(seconds, 6), phase_s=phase_s,
+                        cpu_s=cpu_s, **counters,
                         compiles=guards.phase_compile_counts(),
                         cache=guards.global_cache_counts())
 
@@ -2612,7 +2665,6 @@ class GBDT:
     def _flush_trees_locked(self) -> bool:
         if not self._dev_trees:
             return False
-        k = self.num_tree_per_iteration
         trees = [t for t, _ in self._dev_trees]
         shrinks = [s for _, s in self._dev_trees]
         # one batched device_get of all pending trees; deliberately NOT a
@@ -2622,7 +2674,17 @@ class GBDT:
         # the device (the fetch waits for the step that grew the trees)
         with span("flush_trees"):
             bump("host_syncs")
-            if getattr(self, "_multiproc", False):
+            multiproc = getattr(self, "_multiproc", False)
+            if not multiproc:
+                # the copies are asked for ahead of the wait, as
+                # `device_get` alone would: they start when the step
+                # ends, not a host round trip an array after it
+                jax.copy_to_host_async(trees)
+            # span `step_wait`: the wait for the step alone; what is
+            # left of `flush_trees` is the copy of the trees' arrays
+            with span("step_wait"):
+                jax.block_until_ready(trees)
+            if multiproc:
                 # replicated device trees are not fully addressable across
                 # processes; pull the local replica of each array
                 host_trees = jax.tree.map(_to_host, trees)
@@ -2630,6 +2692,17 @@ class GBDT:
                 host_trees = jax.device_get(trees)
             bump("d2h_bytes", sum(
                 leaf.nbytes for leaf in jax.tree.leaves(host_trees)))
+        # the fetched trees' device arrays go with their last references:
+        # this frame's here, the pending list's in `_decode_trees`
+        del trees
+        with span("decode_trees"):
+            return self._decode_trees(host_trees, shrinks)
+
+    def _decode_trees(self, host_trees, shrinks) -> bool:
+        """The fetched trees into ``self.models``, and the pending device
+        trees released; True if training should stop (the last flushed
+        iteration had no splits at all)."""
+        k = self.num_tree_per_iteration
         # copy-on-write: mutate a private list and rebind once, so code
         # reading self.models WITHOUT the trees mutex (model text dumps,
         # leaf-value bounds) always sees a self-consistent list — either

@@ -36,6 +36,12 @@ class SampleStrategy:
         self.num_data = num_data
         self.metadata = metadata
 
+    def samples(self, iter_num: int) -> bool:
+        """Whether ``bag_mask(iter_num, ...)`` draws or reuses a bag (False:
+        it returns None, 'use all rows'). The update loop enters its
+        ``bag`` span only then."""
+        return False
+
     def bag_mask(self, iter_num: int, grad, hess) -> Optional[jax.Array]:
         """Return in-bag mask for this iteration, or None for 'use all rows'.
         ``grad``/``hess`` are [K, N] (needed by GOSS only)."""
@@ -74,9 +80,12 @@ class BaggingStrategy(SampleStrategy):
             self._row_query = jnp.asarray(rq)
             self._num_queries = len(qb) - 1
 
+    def samples(self, iter_num):
+        return self.enabled
+
     def bag_mask(self, iter_num, grad, hess):
         self.last_fresh = False
-        if not self.enabled:
+        if not self.samples(iter_num):
             return None
         if iter_num % self.freq != 0 and self._cached is not None:
             return self._cached
@@ -112,11 +121,14 @@ class GOSSStrategy(SampleStrategy):
         self.learning_rate = float(config.get("learning_rate", 0.1))
         self._amplify = None
 
-    def bag_mask(self, iter_num, grad, hess):
+    def samples(self, iter_num):
         # warm-up: no sampling for the first 1/learning_rate iterations
         # (reference: goss.hpp Bagging's early return)
+        return iter_num >= int(1.0 / max(self.learning_rate, 1e-12))
+
+    def bag_mask(self, iter_num, grad, hess):
         self.last_fresh = False
-        if iter_num < int(1.0 / max(self.learning_rate, 1e-12)):
+        if not self.samples(iter_num):
             self._amplify = None
             return None
         self.last_fresh = True

@@ -26,13 +26,16 @@ two, because the two interesting clocks live in different places:
   Perfetto. Host timing around ASYNC dispatch measures dispatch, not
   device work (tpulint R009 keeps naive timing out of jit-reachable
   code): of the update loop's spans only ``flush_trees`` blocks on the
-  device, and its seconds are the wait for the step, not host work.
+  device; its child ``step_wait`` is the wait for the step, not host
+  work, and what is left of it the copy of the trees' arrays.
 
 Which host spans record. The spans of set-up and of the update loop
 (:data:`ALWAYS_ON`) record whenever they are entered: the ring is on by
 default, ``Booster.update()`` writes its ``iteration`` event there anyway,
-and an update enters about five of them (a dict and a locked append each:
-microseconds against an iteration). Every other host span (the serving
+and an update enters seven of them (a dict and a locked append each:
+microseconds against an iteration; ``bag``, ``gradient`` and
+``rank_grads`` make it eight to ten where the run samples rows or calls
+a gradient program of its own). Every other host span (the serving
 ticks, ``checkpoint_write``) records only inside a
 :func:`trace_session` and is otherwise one shared no-op — a coalescer
 ticking thousands of times a second would turn the ring over and push
@@ -57,6 +60,22 @@ into int32 histograms; 0: the dequantising f32 shim), ``quant_bins``,
 ``quant_renew`` (1: leaves renewed from the true gradients); and
 ``hist_levels``, the levels of the one-hot the step's fused kernel
 contracts (2: bin = 64 hi + lo; 1: the whole stride; 0: the kernel is off).
+
+The update's seconds by phase ride the same event. Every span that
+closes inside an update adds its seconds to a per-update table beside
+the counters, under its own name (a parent's seconds hold its
+children's: ``flush_trees`` holds ``step_wait``; ``update_tick``, which
+closes after the event is written, is in no table); the closing event
+carries the table as ``phase_s`` and, as ``cpu_s``, the CPU seconds the
+updating thread was given (``time.thread_time()``): host-only reads. A
+phase whose wall seconds are far over the update's ``cpu_s`` was spent
+waiting (for the device in ``step_wait``; for a blocked launch, or for
+the scheduler, in ``step_dispatch`` or ``step_args``); ``cpu_s`` near
+``seconds`` is Python's own work. :class:`SlowUpdates` reads them where
+it matters: an update that took :data:`SLOW_FACTOR` times the median of
+the booster's recent updates writes one ``slow_iteration`` record and
+one warning with its ``phase_s``, ``cpu_s``, the compiles and the
+collector's passes since the update before it.
 
 Span taxonomy (every name a device program or tick site carries):
 
@@ -94,21 +113,34 @@ Span taxonomy (every name a device program or tick site carries):
                           mesh its child ``shard_rows``: every device packs
                           the rows it holds into its own records
 ``build_step``            ``_build_compact_step_fn`` / ``_build_step_fn``
-``iteration``             all of ``Booster.update()``; children ``bag``,
-                          ``gradient`` (``rank_grads`` where the program
-                          called is the ranking gradients' own, ahead of
-                          the compact step), ``step_dispatch`` (one per
-                          call of the jitted step), ``valid_scores``,
-                          ``flush_trees`` (the ``device_get`` of the
-                          pending trees: where the loop blocks)
+``iteration``             all of ``Booster.update()``; children ``bag``
+                          (only where the strategy draws or reuses a
+                          bag), ``gradient`` (``rank_grads`` where the
+                          program called is the ranking gradients' own,
+                          ahead of the compact step), ``step_args`` (one
+                          per tree: the step's arguments, from the bag's
+                          mask to the per-tree keys), ``step_dispatch``
+                          (one per call of the jitted step),
+                          ``valid_scores``, ``flush_trees`` (where the
+                          loop blocks: its child ``step_wait`` is the
+                          wait for the step that grew the pending trees,
+                          the rest the copy of their arrays to the
+                          host), ``decode_trees`` (the ``HostTree``
+                          objects, the copied list, the stop check),
+                          ``update_tick`` (the update's own telemetry,
+                          last in it: the sampled rank-stats probe and
+                          the closing ``iteration`` event)
 ========================  ==================================================
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
+import statistics
 import threading
 import time
-from typing import Dict, Iterator, Optional, Set
+from typing import Any, Deque, Dict, Iterator, List, Optional, Set, Tuple
 
 import jax
 
@@ -127,16 +159,39 @@ ALWAYS_ON = frozenset((
     "import", "construct", "find_bins", "binning", "to_device",
     "booster_init", "rank_layout", "compact_setup", "shard_rows",
     "build_step",
-    "iteration", "bag", "gradient", "rank_grads", "step_dispatch",
-    "valid_scores", "flush_trees"))
+    "iteration", "bag", "gradient", "rank_grads", "step_args",
+    "step_dispatch", "valid_scores", "flush_trees", "step_wait",
+    "decode_trees", "update_tick"))
 
 #: the update's counters (:func:`bump`), as its ``iteration`` event and
 #: the metrics stream's record carry them
 COUNTERS = ("dispatches", "host_syncs", "d2h_bytes")
 
-#: per-thread: the stack of open host spans and, inside Booster.update(),
-#: the booster's iter_ and the update's counters
+#: a slow update reports itself (:class:`SlowUpdates`): one that took at
+#: least SLOW_FACTOR times the median of the booster's previous updates
+#: with the same ``host_syncs`` count, the last SLOW_WINDOW of them, once
+#: there are SLOW_MIN
+SLOW_FACTOR = 3.0
+SLOW_WINDOW = 32
+SLOW_MIN = 8
+
+#: per-thread: the stack of open host spans (``stack``) and, inside
+#: Booster.update(), that update's state (``update``)
 _local = threading.local()
+
+
+class _Update:
+    """What the ``iteration`` span holds for the update it wraps: the
+    booster's iter_, the update's counters, its seconds by phase and the
+    thread's CPU clock as the update found it."""
+
+    __slots__ = ("iteration", "counters", "phases", "cpu0")
+
+    def __init__(self, iteration: int):
+        self.iteration = iteration
+        self.counters: Dict[str, int] = {}
+        self.phases: Dict[str, float] = {}
+        self.cpu0 = time.thread_time()
 
 _mu = threading.Lock()
 _enabled = 0                      # nesting count of enabling sessions
@@ -206,7 +261,9 @@ class _HostSpan:
     """Span entered on the host: ring record + phase-table entry +
     profiler annotation. ``iteration`` (given by ``Booster.update``
     alone) makes this the update's span: the spans inside it carry that
-    number, and :func:`bump` counts into it."""
+    number, :func:`bump` counts into it, and each span that closes
+    inside it adds its seconds to the update's own table
+    (:func:`update_phases`)."""
 
     __slots__ = ("_name", "_ann", "_t0", "_parent", "_iteration", "_outer")
 
@@ -217,9 +274,8 @@ class _HostSpan:
 
     def __enter__(self) -> "_HostSpan":
         if self._iteration is not None:
-            self._outer = (getattr(_local, "iteration", None),
-                           getattr(_local, "counters", None))
-            _local.iteration, _local.counters = int(self._iteration), {}
+            self._outer = getattr(_local, "update", None)
+            _local.update = _Update(int(self._iteration))
         stack = getattr(_local, "stack", None)
         if stack is None:
             stack = _local.stack = []
@@ -233,10 +289,14 @@ class _HostSpan:
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
         _local.stack.pop()
+        update = getattr(_local, "update", None)
         record(self._name, self._t0, t1, self._parent,
-               getattr(_local, "iteration", None))
+               update.iteration if update is not None else None)
         if self._iteration is not None:
-            _local.iteration, _local.counters = self._outer
+            _local.update = self._outer
+        elif update is not None:
+            update.phases[self._name] = (update.phases.get(self._name, 0.0)
+                                         + t1 - self._t0)
         return False
 
 
@@ -273,16 +333,75 @@ def bump(counter: str, n: int = 1) -> None:
     """Add ``n`` to one of the current update's counters. Outside
     ``Booster.update()`` there is no update to count for and nothing is
     counted: a flush from ``predict`` is not an update's sync."""
-    counters = getattr(_local, "counters", None)
-    if counters is not None:
-        counters[counter] = counters.get(counter, 0) + n
+    update = getattr(_local, "update", None)
+    if update is not None:
+        update.counters[counter] = update.counters.get(counter, 0) + n
 
 
 def update_counters() -> Dict[str, int]:
     """The counters of the update this thread is inside (zeros outside
     one): what ``GBDT._obs_iteration_tick`` writes into its event."""
-    counted = getattr(_local, "counters", None) or {}
+    update = getattr(_local, "update", None)
+    counted = update.counters if update is not None else {}
     return {name: counted.get(name, 0) for name in COUNTERS}
+
+
+def update_phases() -> Tuple[Dict[str, float], float]:
+    """(``phase_s``, ``cpu_s``) of the update this thread is inside: the
+    seconds of every span closed in it so far, by name, and the CPU
+    seconds the thread was given since the update began
+    (``time.thread_time()``: a thread that waits or is descheduled earns
+    none). Empty and 0.0 outside an update. Host-only reads."""
+    update = getattr(_local, "update", None)
+    if update is None:
+        return {}, 0.0
+    return ({name: round(s, 6) for name, s in update.phases.items()},
+            round(time.thread_time() - update.cpu0, 6))
+
+
+class SlowUpdates:
+    """One booster's watch for the update that stalls (module docstring).
+
+    ``check`` is called once an update, from its closing tick, with what
+    the tick already holds. An update is held against the median of the
+    booster's previous updates *with the same* ``host_syncs`` *count*:
+    one that flushes waits for the device and the others (under
+    ``stop_check_freq`` > 1) only dispatch. Where it took
+    :data:`SLOW_FACTOR` times that median, ``check`` returns the
+    ``slow_iteration`` record's fields: ``iteration``, ``seconds``,
+    ``median_s``, ``phase_s``, ``cpu_s``, ``compiles`` (lowerings and
+    backend compiles since the previous tick) and ``gc_collections``
+    (the collector's passes per generation since the previous tick).
+    Host-only reads; a median of at most :data:`SLOW_WINDOW` floats."""
+
+    def __init__(self) -> None:
+        self._recent: Dict[int, Deque[float]] = {}
+        # (lowerings, backend compiles, the collector's passes by
+        # generation) as the previous tick read them
+        self._last: Optional[List[int]] = None
+
+    def check(self, iteration: int, seconds: float, host_syncs: int,
+              phase_s: Dict[str, float], cpu_s: float
+              ) -> Optional[Dict[str, Any]]:
+        from ..analysis import guards
+        counts = guards.phase_compile_counts()
+        now = [counts["lowerings"], counts["backend_compiles"]] + [
+            g["collections"] for g in gc.get_stats()]
+        last, self._last = self._last or now, now
+        recent = self._recent.setdefault(
+            host_syncs, collections.deque(maxlen=SLOW_WINDOW))
+        median = statistics.median(recent) if len(recent) >= SLOW_MIN \
+            else None
+        recent.append(seconds)
+        if median is None or seconds < SLOW_FACTOR * median:
+            return None
+        return {
+            "iteration": iteration, "seconds": round(seconds, 6),
+            "median_s": round(median, 6), "phase_s": phase_s,
+            "cpu_s": cpu_s,
+            "compiles": {"lowerings": now[0] - last[0],
+                         "backend_compiles": now[1] - last[1]},
+            "gc_collections": [a - b for a, b in zip(now[2:], last[2:])]}
 
 
 def annotations_enabled() -> bool:

@@ -36,7 +36,7 @@ from typing import Any, Dict, List, Optional, Sequence
 ITERATION_COUNTERS = ("dispatches", "host_syncs", "d2h_bytes")
 
 #: event kinds surfaced in the "notable events" tail
-NOTABLE = ("fault_fire", "deadline", "retry", "crash",
+NOTABLE = ("slow_iteration", "fault_fire", "deadline", "retry", "crash",
            "training_interrupted", "swap_failed", "worker_restart",
            "snapshot_corrupt", "straggler", "rank_missing",
            "drift_detected", "drift_cleared", "slo_burn",
@@ -84,7 +84,8 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
             iter_seconds += float(rec.get("seconds", 0.0) or 0.0)
             per_iteration.append(
                 {key: rec.get(key) for key in
-                 ("iteration", "seconds") + ITERATION_COUNTERS})
+                 ("iteration", "seconds", "cpu_s", "phase_s")
+                 + ITERATION_COUNTERS})
             if isinstance(rec.get("compiles"), dict):
                 compiles = rec["compiles"]     # cumulative: keep the last
             if isinstance(rec.get("cache"), dict):
@@ -243,14 +244,22 @@ def _fmt_table(summary: Dict[str, Any]) -> str:
             if any(r.get(c) is not None for c in ITERATION_COUNTERS)]
     if rows:
         lines.append("")
-        lines.append(f"{'iteration':>9} {'seconds':>10} {'dispatches':>10} "
-                     f"{'host_syncs':>10} {'d2h_bytes':>10}")
+        # cpu_s: the CPU seconds the updating thread was given; phase_s:
+        # the update's seconds by span, the three longest (a parent's
+        # hold its children's: flush_trees holds step_wait)
+        lines.append(f"{'iteration':>9} {'seconds':>10} {'cpu_s':>10} "
+                     f"{'dispatches':>10} {'host_syncs':>10} "
+                     f"{'d2h_bytes':>10}  phase_s")
         for r in rows:
+            phases = sorted((r.get("phase_s") or {}).items(),
+                            key=lambda kv: -float(kv[1]))[:3]
             lines.append(
                 f"{r.get('iteration')!s:>9} "
                 f"{float(r.get('seconds') or 0.0):>10.4f} "
+                f"{float(r.get('cpu_s') or 0.0):>10.4f} "
                 + " ".join(f"{int(r.get(c) or 0):>10}"
-                           for c in ITERATION_COUNTERS))
+                           for c in ITERATION_COUNTERS)
+                + "  " + " ".join(f"{n}={float(v):.4f}" for n, v in phases))
     rs = summary.get("rank_stats")
     if rs:
         lines.append("")

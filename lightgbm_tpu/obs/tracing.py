@@ -21,8 +21,9 @@ SPAN_TAXONOMY = (
     "predict_warmup", "serve_tick", "featurize", "contrib",
     "import", "construct", "find_bins", "to_device", "booster_init",
     "rank_layout", "compact_setup", "shard_rows", "build_step",
-    "iteration", "bag", "rank_grads", "step_dispatch", "valid_scores",
-    "flush_trees",
+    "iteration", "bag", "rank_grads", "step_args", "step_dispatch",
+    "valid_scores", "flush_trees", "step_wait", "decode_trees",
+    "update_tick",
 )
 
 

@@ -266,6 +266,8 @@ def test_taxonomy_trace_metrics_acceptance(tmp_path):
     params = {
         "objective": "binary", "num_leaves": 7, "verbosity": -1,
         "tpu_grower": "compact", "tree_learner": "data", "num_shards": 2,
+        # `bag` is entered only where the strategy draws or reuses a bag
+        "bagging_fraction": 0.8, "bagging_freq": 2,
         "tpu_trace_mode": "annotations",
         "tpu_metrics_path": str(mpath),
         "tpu_checkpoint_dir": str(ckpt), "tpu_checkpoint_freq": 2,
@@ -311,6 +313,9 @@ def test_taxonomy_trace_metrics_acceptance(tmp_path):
     assert len(iters) == 5
     assert [r["iteration"] for r in iters] == [1, 2, 3, 4, 5]
     assert all(r["seconds"] >= 0 for r in iters)
+    # the update's seconds by phase and the thread's CPU seconds ride along
+    assert all({"step_args", "step_dispatch", "bag"} <= set(r["phase_s"])
+               and r["cpu_s"] >= 0 for r in iters)
     lows = [r["compiles"]["lowerings"] for r in iters]
     assert lows == sorted(lows) and lows[0] > 0      # cumulative
     assert "train_step" in iters[-1]["compiles"]["by_phase"]
