@@ -49,6 +49,7 @@ from ..ops.predict import (DEFAULT_LEVEL_DEPTH_CAP, StackedTrees,
                            predict_raw_scan, quantize_leaves,
                            route_one_tree, tree_bucket)
 from ..parallel.multihost import to_host as _to_host
+from ..ops.record_write import record_write
 from ..ops.renew import renew_leaf_quantile
 from ..utils import log
 from ..utils.rwlock import Mutex
@@ -1023,17 +1024,22 @@ class GBDT:
             "quant_hist": int(int_hist), "quant_bins": self._quant_bins,
             "quant_renew": int(renew)} if self._use_quant else {}
 
-    def _note_hist_levels(self, fused: bool) -> None:
-        """``hist_levels`` of every ``iteration`` event, noted when the
-        step is built: 2 where the step's fused kernel contracts a
-        two-level one-hot (bin = 64 hi + lo: more than 64 bins a
-        feature), 1 where its one-hot spans the whole stride, 0 where
-        the fused kernel is off (ops/fused_split.hist_levels)."""
+    def _note_fused_path(self, fused: bool) -> None:
+        """``hist_levels`` and ``record_write`` of every ``iteration``
+        event, noted when the step is built. ``hist_levels``: 2 where the
+        step's fused kernel contracts a two-level one-hot (bin = 64 hi +
+        lo: more than 64 bins a feature), 1 where its one-hot spans the
+        whole stride, 0 where the fused kernel is off
+        (ops/fused_split.hist_levels). ``record_write``: 1 where the
+        ``record_write`` kernel writes the per-row columns into the
+        records (ops/record_write.py, wherever the fused kernel runs), 0
+        where XLA's lane-slice update does."""
         from ..ops.fused_split import hist_levels
         gp = self.grower_params
         self._hist_counters = {"hist_levels": hist_levels(
             self._compact["layout"].num_features, gp.num_bins,
-            gp.hist_layout) if fused else 0}
+            gp.hist_layout) if fused else 0,
+            "record_write": int(fused)}
 
     def _build_step_fn(self):
         """One fused, jitted train step per tree: mask gradients, grow, renew,
@@ -1064,7 +1070,7 @@ class GBDT:
         feature_contri = self._feature_contri
         # the masked grower histograms the dequantised codes in f32
         self._note_quant_path(False, quant_renew)
-        self._note_hist_levels(False)
+        self._note_fused_path(False)
 
         def step(binned, score_k, grad_k, hess_k, mask, feat_mask,
                  shrinkage, bynode_key, cegb_used, true_grad_k, true_hess_k,
@@ -1747,7 +1753,7 @@ class GBDT:
             quant_hist=gp.quant_hist, quant_max=gp.quant_max,
             quant_narrow=gp.quant_narrow)
         self._note_quant_path(quant_int, quant_renew)
-        self._note_hist_levels(gp.fused_block > 0)
+        self._note_fused_path(gp.fused_block > 0)
         const_hess = bool(getattr(obj, "is_constant_hessian", False))
         feature_contri = self._feature_contri
         efb = self._efb
@@ -1829,18 +1835,28 @@ class GBDT:
                 g_k = col(work, gx_off + 4 * k)
                 h_k = col(work, gx_off + 4 * (k_total + k))
             # grad/hess/cnt, the K score columns, and (at k=0) the per-class
-            # gradient columns are CONTIGUOUS lanes — write them in ONE
-            # update (4 separate lane-slice updates cost ~27 ms each at 10.5M
-            # rows; one fused update costs the same as one of them)
+            # gradient columns are CONTIGUOUS lanes, written once a tree.
+            # As XLA's lane-slice update of the u8 records the write cost
+            # 35 ms an iteration at 10.5M rows (a row-major copy of the
+            # packed operand, a select, an update of the whole array: 2.5
+            # ns a row, ten times its roofline). Where the step runs the
+            # fused kernel, record_write streams each record through VMEM
+            # once and writes the same bytes in place (PERF.md, PR 40)
             cols = [g_k * w_col, h_k * w_col, w_col]
             # scores are authoritative outside the work array; write all K
             # columns fresh so they ride the partition correctly
             cols += [scores[j] for j in range(k_total)]
             cols += class_grads
-            packed = jnp.concatenate(
-                [_f32_to_u8(jnp.pad(v, (0, pad_n))) for v in cols], axis=1)
-            work = work.at[:, layout.grad_off:
-                           layout.grad_off + 4 * len(cols)].set(packed)
+            if gp.fused_block:
+                work = record_write(
+                    work, jnp.stack([jnp.pad(v, (0, pad_n)) for v in cols]),
+                    layout.grad_off, interpret=gp.fused_interpret)
+            else:
+                packed = jnp.concatenate(
+                    [_f32_to_u8(jnp.pad(v, (0, pad_n))) for v in cols],
+                    axis=1)
+                work = work.at[:, layout.grad_off:
+                               layout.grad_off + 4 * len(cols)].set(packed)
 
             (tree, row_leaf, work, scratch, leaf_start,
              leaf_nrows) = grow_tree_compact(
@@ -2576,7 +2592,8 @@ class GBDT:
         # which histogram path a quantized step runs, and whether it
         # renews its leaves: fixed when the step is built
         counters.update(getattr(self, "_quant_counters", {}))
-        # how many levels the fused kernel's one-hot has (0: kernel off)
+        # how many levels the fused kernel's one-hot has (0: kernel off),
+        # and whether record_write writes the step's per-row columns
         counters.update(getattr(self, "_hist_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),

@@ -4,7 +4,6 @@ bin = 64 hi + lo, more than 64 bins a feature; 1: the whole stride; 0: the
 fused kernel is off), and the benchmark's ``kernel.hist_levels`` reads it
 from the flight ring through the ``update_loop`` reducer that is there."""
 import json
-import threading
 
 import numpy as np
 import pytest
@@ -14,6 +13,7 @@ from lightgbm_tpu.obs import flight
 from lightgbm_tpu.ops.fused_split import hist_levels
 
 from benchmarks import run as bench_run
+from utils import OwnThreadRing
 
 METRIC = "kernel.hist_levels"
 CELLS = ["higgs_train", "higgs_b63_train", "istella_train",
@@ -27,23 +27,6 @@ CELLS = ["higgs_train", "higgs_b63_train", "istella_train",
     (28, 64, 1), (28, 32, 1), (28, 16, 1), (28, 48, 1), (28, 8, 2)])
 def test_levels_are_read_off_the_bin_stride(features, bins, want):
     assert hist_levels(features, bins) == want
-
-
-class OwnThreadRing(flight.FlightRecorder):
-    """A ring that keeps only what the thread that made it records. The
-    process-wide ring is shared with whatever the xdist worker ran before:
-    ``tests/test_checkpoint.py`` abandons a watchdog thread inside an
-    injected 120 s hang, which wakes up in a later test's time, finishes
-    its ``Booster.update()`` and writes that booster's ``iteration`` event
-    (a third tick here in the driver's run of PR 38)."""
-
-    def __init__(self):
-        super().__init__()
-        self._owner = threading.get_ident()
-
-    def record(self, event, **fields):
-        if threading.get_ident() == self._owner:
-            super().record(event, **fields)
 
 
 def ticks(monkeypatch, max_bin, **more):

@@ -26,6 +26,7 @@ the other fused variants and the whole step programs are ``slow``
 the step: ``pytest tests/test_tpu_compile.py -m 'slow or not slow'``).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -39,6 +40,7 @@ from lightgbm_tpu.engines import registry
 from lightgbm_tpu.ops.compact import RowLayout
 from lightgbm_tpu.ops.fused_split import fused_block_cap, fused_split
 from lightgbm_tpu.ops.pallas_histogram import pallas_histogram
+from lightgbm_tpu.ops.record_write import record_write
 
 HBM_BYTES = 16 << 30            # one v5e chip
 HIGGS_ROWS = 10_500_000
@@ -276,6 +278,39 @@ def test_fused_kernel_compiles_for_v5e_slow(name, one_chip,
     _fused_compile(one_chip, **FUSED_CASES_SLOW[name])
 
 
+# ------------------------------------------------------ record_write kernel
+@pytest.mark.parametrize("rows,num_cols,grad_off", [
+    (HIGGS_ROWS + 16_384, 128, 28),       # higgs's records: the whole tile
+    (7_325_625 + 16_384, 256, 220),       # istella's: the second tile alone
+], ids=["higgs", "istella"])
+def test_record_write_compiles_for_v5e(rows, num_cols, grad_off, one_chip,
+                                       no_persistent_cache):
+    """The step's per-row columns (g·w, h·w, w, the score) written into
+    the records at a cell's real size: streamed row blocks, the record
+    array aliased to the output (nothing copied, no temporary)."""
+    compiled = _compiled_with_kernel(jax.jit(
+        lambda w, c: record_write(w, c, grad_off), donate_argnums=0).lower(
+        jax.ShapeDtypeStruct((rows, num_cols), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((4, rows), jnp.float32, sharding=one_chip)))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * num_cols, mem   # tiled rows
+    assert mem.temp_size_in_bytes == 0, mem
+
+
+def _record_write_in_place(text, rows, num_cols):
+    """What the compiled step does with the ``u8[rows, num_cols]`` record
+    array where it writes the per-row columns: the ``record_write`` call,
+    and no update of the whole array (the parent's ``dynamic-update-slice``,
+    ``scatter`` under ``shard_map``) and no row-major copy of a u8 operand
+    over the rows (the parent's ``u8[rows, 16]``) or of the array."""
+    assert "record_write" in text
+    whole = re.findall(rf"= u8\[{rows},{num_cols}\]\S* "
+                       r"(dynamic-update-slice|scatter)\(", text)
+    assert not whole, whole
+    copies = re.findall(rf"= (u8\[{rows},\d+\]\S*) copy\(", text)
+    assert not copies, copies
+
+
 # --------------------------------------------------- whole train-step programs
 class _Captured(Exception):
     pass
@@ -333,7 +368,9 @@ STEP_PARAMS = {
 @pytest.mark.slow
 def test_compact_step_compiles_for_v5e_at_higgs_shape(
         monkeypatch, one_chip, no_persistent_cache):
-    """One whole serial train step at 10.5M x 28, 255 leaves, 255 bins."""
+    """One whole serial train step at 10.5M x 28, 255 leaves, 255 bins.
+    The per-row columns go into the records through ``record_write``:
+    no update of the whole record array, no row-major copy (PR 40)."""
     bst, args, kwargs = _abstract_step(monkeypatch, STEP_PARAMS, 1 << 16)
     g = bst._gbdt
     assert g._use_compact and g.grower_params.fused_block > 0
@@ -342,6 +379,7 @@ def test_compact_step_compiles_for_v5e_at_higgs_shape(
                          lambda v: one_chip)
     compiled = _compiled_with_kernel(
         g._build_compact_step_fn().lower(*abstract, **kwargs))
+    _record_write_in_place(compiled.as_text(), *abstract[0].shape)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -353,7 +391,8 @@ def test_data_parallel_step_compiles_for_four_v5e_chips(
         monkeypatch, four_chip_mesh, no_persistent_cache):
     """The same step under ``shard_map`` over a 4-chip mesh at 4M rows:
     the kernel partitions with the shards and the histogram collective is
-    in the program."""
+    in the program; a shard's records take ``record_write`` as on one chip
+    (the parent's ``scatter.454`` and its copy are gone)."""
     params = dict(STEP_PARAMS, tree_learner="data", tpu_mesh_shape="4")
     bst, args, kwargs = _abstract_step(monkeypatch, params, 1 << 18)
     g = bst._gbdt
@@ -365,7 +404,10 @@ def test_data_parallel_step_compiles_for_four_v5e_chips(
         if isinstance(v.sharding, NamedSharding) else None)
     k = kwargs.pop("k")
     step = g._build_compact_step_fn()
-    lowered = jax.jit(lambda *a: step(*a, k=k)).lower(*abstract)
+    # donating the records and the scratch as the step's own jit does:
+    # without it every kernel that writes them in place needs a copy
+    lowered = jax.jit(lambda *a: step(*a, k=k),
+                      donate_argnums=(0, 1)).lower(*abstract)
     # what the program asks for (tpu_hist_scatter=auto: reduce-scatter) ...
     assert "reduce_scatter" in lowered.as_text()
     compiled = _compiled_with_kernel(lowered)
@@ -373,8 +415,11 @@ def test_data_parallel_step_compiles_for_four_v5e_chips(
     # decomposes the reduce-scatter into all-reduce + slice
     text = compiled.as_text()
     assert "all-reduce" in text or "reduce-scatter" in text
-    # per-chip bytes; the outer jit here drops the step's buffer donation,
-    # so this over-counts what a real run holds
+    # a shard's records: the per-device shape of the row-sharded array
+    rows, num_cols = abstract[0].shape
+    _record_write_in_place(text, rows // len(four_chip_mesh.devices.flat),
+                           num_cols)
+    # per-chip bytes
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES, mem

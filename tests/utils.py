@@ -7,9 +7,12 @@ XLA-on-CPU test path stays fast.
 from __future__ import annotations
 
 import functools
+import threading
 
 import numpy as np
 from sklearn.datasets import make_blobs, make_classification, make_regression
+
+from lightgbm_tpu.obs import flight
 
 # small defaults: CPU XLA histograms are the slow path; TPU is the target
 FAST_PARAMS = {"max_bin": 31, "min_data_in_leaf": 5, "num_leaves": 15,
@@ -63,3 +66,20 @@ def train_test_split_simple(X, y, test_frac=0.25, seed=0):
     cut = int(n * (1 - test_frac))
     tr, te = idx[:cut], idx[cut:]
     return X[tr], y[tr], X[te], y[te]
+
+
+class OwnThreadRing(flight.FlightRecorder):
+    """A flight ring that keeps only what the thread that made it records.
+    The process-wide ring is shared with whatever the xdist worker ran
+    before: ``tests/test_checkpoint.py`` abandons a watchdog thread inside
+    an injected 120 s hang, which wakes up in a later test's time,
+    finishes its ``Booster.update()`` and writes that booster's
+    ``iteration`` event (a third tick in the driver's run of PR 38)."""
+
+    def __init__(self):
+        super().__init__()
+        self._owner = threading.get_ident()
+
+    def record(self, event, **fields):
+        if threading.get_ident() == self._owner:
+            super().record(event, **fields)
