@@ -1025,20 +1025,26 @@ class GBDT:
             "quant_renew": int(renew)} if self._use_quant else {}
 
     def _note_fused_path(self, fused: bool) -> None:
-        """``hist_levels`` and ``record_write`` of every ``iteration``
-        event, noted when the step is built. ``hist_levels``: 2 where the
-        step's fused kernel contracts a two-level one-hot (bin = 64 hi +
-        lo: more than 64 bins a feature), 1 where its one-hot spans the
-        whole stride, 0 where the fused kernel is off
-        (ops/fused_split.hist_levels). ``record_write``: 1 where the
-        ``record_write`` kernel writes the per-row columns into the
-        records (ops/record_write.py, wherever the fused kernel runs), 0
-        where XLA's lane-slice update does."""
-        from ..ops.fused_split import hist_levels
+        """``hist_levels``, ``carry_bytes`` and ``record_write`` of every
+        ``iteration`` event, noted when the step is built. ``hist_levels``:
+        2 where the step's fused kernel contracts a two-level one-hot (bin
+        = 64 hi + lo: more than 64 bins a feature), 1 where its one-hot
+        spans the whole stride, 0 where the fused kernel is off
+        (ops/fused_split.hist_levels). ``carry_bytes``: the bytes of VMEM
+        a carried row's byte takes in the kernel's ring carries, 1 where
+        they hold packed bytes (4: one byte an i32 word), 0 where the
+        fused kernel is off (ops/fused_split.carry_bytes).
+        ``record_write``: 1 where the ``record_write`` kernel writes the
+        per-row columns into the records (ops/record_write.py, wherever
+        the fused kernel runs), 0 where XLA's lane-slice update does."""
+        from ..ops.fused_split import carry_bytes, hist_levels
         gp = self.grower_params
-        self._hist_counters = {"hist_levels": hist_levels(
-            self._compact["layout"].num_features, gp.num_bins,
-            gp.hist_layout) if fused else 0,
+        layout = self._compact["layout"] if fused else None
+        self._hist_counters = {
+            "hist_levels": hist_levels(layout.num_features, gp.num_bins,
+                                       gp.hist_layout) if fused else 0,
+            "carry_bytes": carry_bytes(gp.fused_block, layout.num_cols)
+            if fused else 0,
             "record_write": int(fused)}
 
     def _build_step_fn(self):
@@ -2593,7 +2599,8 @@ class GBDT:
         # renews its leaves: fixed when the step is built
         counters.update(getattr(self, "_quant_counters", {}))
         # how many levels the fused kernel's one-hot has (0: kernel off),
-        # and whether record_write writes the step's per-row columns
+        # the bytes its ring carries take a row byte, and whether
+        # record_write writes the step's per-row columns
         counters.update(getattr(self, "_hist_counters", {}))
         flight.note("iteration", iteration=self.iter_,
                     seconds=round(seconds, 6), t1=time.perf_counter(),

@@ -60,7 +60,8 @@ into int32 histograms; 0: the dequantising f32 shim), ``quant_bins``,
 ``quant_renew`` (1: leaves renewed from the true gradients); and
 ``hist_levels``, the levels of the one-hot the step's fused kernel
 contracts (2: bin = 64 hi + lo; 1: the whole stride; 0: the kernel is off);
-and ``record_write`` (1: the ``record_write`` kernel writes the step's
+``carry_bytes``, the bytes of VMEM a carried row's byte takes in its ring
+carries (1: packed bytes; 0: the kernel is off); and ``record_write`` (1: the ``record_write`` kernel writes the step's
 per-row columns into the records; 0: XLA's lane-slice update does).
 
 The update's seconds by phase ride the same event. Every span that

@@ -17,16 +17,30 @@ whole walk:
   * the parent leaf's contiguous segment streams HBM -> VMEM once, with
     double-buffered DMA;
   * each block stably partitions via ONE dest-indexed one-hot MXU matmul
-    into the two streams' carries. A carry is a RING of one block,
-    [bs, C], whose rows at and above its count are zero; a selected row
-    lands at dest = (count + rank) mod bs, the right stream's ring bs
-    slots below the left's, so the one-hot is [2 bs, bs] and the carry
-    append costs nothing extra. A block that does not fill a ring adds
-    into it. One that does flushes `where(slot < count, carry, block's
-    rows)`, the carry's rows and then the block's first bs - count, and
-    the rows that wrapped, already in place below the old count, are the
+    into the two streams' carries. A carry is a RING of one block, bs
+    rows of C bytes, whose rows at and above its count are zero; a
+    selected row lands at dest = (count + rank) mod bs, the right
+    stream's ring bs slots below the left's, so the one-hot is [2 bs, bs]
+    and the carry append costs nothing extra. A block that does not fill
+    a ring ORs into it. One that does flushes the carry's rows and then
+    the block's first bs - count, and the rows that wrapped, already in
+    place below the old count, are the
     new carry: nothing shifts. (Through PR 30 a carry was 2 bs tall and
     shifted down a block on every flush, and the one-hot was [4 bs, bs].)
+  * a carry holds PACKED BYTES: [bs / 4, C] i32 words, four rows'
+    (byte - 128) bytes a word, as ``pltpu.bitcast`` lays out an int8
+    [bs, C] block. The permutation's product is cast to int8 and bitcast
+    to words once, as it leaves the MXU. Each slot receives at most one
+    row and every slot at or above a count is zero, so the append is an
+    OR, a flush is ``carry | (block & ~m)`` with the new carry ``block &
+    m`` (``m`` the byte mask of the slots below the count: the slots
+    between the wrapped rows and the count are zero, so one mask serves
+    both), and the staged bytes are the words with their top bits
+    flipped. The mask is arithmetic on the words (``below``), a few
+    registers and no [bs, C] compare. Held one byte an i32 word, a
+    carry cost an append 48 adds on spilled registers and a flush two
+    selects and an i32 -> u8 pack of 48 registers at higgs's 128-byte
+    rows (PERF.md section 6 has both listings).
   * what a row decides (its routing bin, its side, its rank, its slot)
     is computed with the block's rows along LANES, [32, bs]: the matmul
     that picks the routing column out of the block also transposes it,
@@ -97,9 +111,10 @@ of the form `32*t + k*BS`, which the compiler can prove aligned.
 Numerics: row bytes move through the permutation matmul as (byte - 128) int8
 values at 2x the bf16 MXU rate (one-hot contraction, i32 accumulate — exact).
 byte <-> byte - 128 is the top bit flipped, done on the packed bytes on the
-way in and on the way out; a carry slot that no row has reached holds 0 and
-would flush as 128, and every such slot is one a blend replaces or that
-lands in dead bytes. Histogram channels use the same hi/lo-bf16
+way in and on the way out (the carries' words keep the product's (byte -
+128) bytes, so their flush is the same flip); a carry slot that no row has
+reached holds 0 and would flush as 128, and every such slot is one a blend
+replaces or that lands in dead bytes. Histogram channels use the same hi/lo-bf16
 split as ops/pallas_histogram.py: counts exact, grad/hess ~2^-17 relative.
 
 The pending ring (round 6's batched-M pipeline; measured on the chip in
@@ -150,16 +165,20 @@ _A = 32  # row alignment every DMA offset is provably divisible by
 # The kernel's fixed streaming buffers scale with block_size * num_cols:
 # the double-buffered input block, the two streams' double-buffered stages
 # and the read-modify-write block as bytes (7 bs*C, 8 in the copy-back
-# variant), and the two ring carries as 32-bit words (8 bs*C; 16 before
-# PR 31). 49152 is the empirical bs*C product the round-3 kernel tolerated
-# on v5e with the taller carries, 23 bytes a cell; the cap stays where the
-# cells' blocks were measured, with one exception. The histogram half holds
+# variant), and the two ring carries as packed bytes (2 bs*C; 8 bs*C as
+# a 32-bit word a byte, as the cap still counts them). 49152 is the
+# empirical bs*C product the round-3 kernel tolerated on v5e with the
+# taller carries, 23 bytes a cell; the cap and the 15 bytes a cell below
+# count the carries as words, so every shape keeps the block it was
+# measured at (the 6 bs*C the packed carries freed are for a change of
+# block to spend, and to measure). The cap stays where the cells' blocks
+# were measured, with one exception. The histogram half holds
 # a block's rows along LANES (PR 33): a block that leaves a lane tile part
 # empty (192 rows: 256-byte records at the cap) sends the whole one-hot
 # down the compiler's select-and-pack path instead of the masked weight
 # load, 60 cycles a row against 36 at 220 features. Such a block takes
 # the next multiple of 128 where the stream's buffers, 15 bytes a cell
-# now, stay under what the cap held when it was measured
+# as counted, stay under what the cap held when it was measured
 # (fused_block_cap). The pending ring (hist_accum) ADDS
 # mbatch-proportional residency: the staged transposed blocks, the
 # channel slots, and the per-feature-group one-hot of a contraction — so
@@ -318,6 +337,21 @@ def hist_levels(f: int, b: int, hist_layout: str = "lane") -> int:
     return 2 if _hist_flush_shape(f, b, hist_layout)[0] > 1 else 1
 
 
+def _carry_shape(block_size: int, num_cols: int) -> Tuple[int, int]:
+    """A ring carry's VMEM scratch: [bs / 4, C] i32 words, four rows'
+    bytes a word (module docstring)."""
+    return block_size // 4, num_cols
+
+
+def carry_bytes(block_size: int, num_cols: int) -> int:
+    """Bytes of VMEM that one byte of a carried row takes in the fused
+    kernel's ring carries, read off the carry's scratch shape: 1 where the
+    carries hold packed bytes (the ``carry_bytes`` counter of an
+    ``iteration`` event; one byte an i32 word would read 4)."""
+    rows, cols = _carry_shape(block_size, num_cols)
+    return 4 * rows * cols // (block_size * num_cols)
+
+
 def _hist_rows(layout: RowLayout) -> int:
     """Byte columns of a row record the histogram reads (the bins and the
     gradient, hessian and count words), rounded up to whole int8 tiles:
@@ -417,8 +451,7 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     rcarry[:, :] = jnp.zeros_like(rcarry)
     auxbuf[...] = jnp.zeros_like(auxbuf)
 
-    slot_i = lax.broadcasted_iota(i32, (bs, 1), 0)
-    iota = slot_i[:, 0]
+    iota = lax.broadcasted_iota(i32, (bs, 1), 0)[:, 0]
     io2 = lax.broadcasted_iota(i32, (bs, bs), 0)
     jo2 = lax.broadcasted_iota(i32, (bs, bs), 1)
     # strict upper triangular: ranks via MXU (int8 runs at 2x bf16 rate)
@@ -430,18 +463,62 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
     row_t = lax.broadcasted_iota(i32, (ROWS, bs), 0)
     iota2 = lax.broadcasted_iota(i32, (2 * bs, bs), 0)
 
+    # the top bit of each byte of a word
+    TOP = jnp.int32(-0x7F7F7F80)
+
     def flip_offset(bytes8, out_t):
         """byte <-> (byte - 128) on [BS, C] packed bytes: the top bit
         flipped, whichever way."""
-        return pltpu.bitcast(
-            pltpu.bitcast(bytes8, i32) ^ jnp.int32(-0x7F7F7F80), out_t)
+        return pltpu.bitcast(pltpu.bitcast(bytes8, i32) ^ TOP, out_t)
 
-    def carry_block_u8(c):
-        """A [BS, C] block in carry form (byte - 128, i32) as its bytes.
-        A slot that received no row reads 128 and not 0: every such slot is
-        one a caller blends away or that lands in dead bytes (the right
-        stream's psi head slots, a tail's rows past its count)."""
-        return flip_offset(c.astype(jnp.int8), jnp.uint8)
+    # The u8 buffers seen as words, four consecutive rows a word, as
+    # pltpu.bitcast packs them and as their (8,128)(4,1) tiles hold them
+    # (measured on the chip: PERF.md section 6). A staged block is stored as
+    # words, 12 registers at higgs's rows; a u8 value is first unpacked
+    # and packed to the buffer's 8-row tiles and stored a quarter
+    # register at a time, 48 stores.
+    inbuf_w, auxbuf_w = inbuf.bitcast(i32), auxbuf.bitcast(i32)
+
+    def put_words(ref, slot, words):
+        """Store [BS / 4, C] words into slot ``slot`` of a u8 buffer.
+        Pallas's interpreter writes through no bitcast view of a ref; its
+        value bitcast packs the same bytes in the same order."""
+        if interpret:
+            ref[slot] = pltpu.bitcast(words, ref.dtype)
+        else:
+            ref.bitcast(i32)[slot] = words
+
+    # The byte mask of the slots below a count n, on the carry's words. A
+    # word's four slots lie in one tile of 32 slots, and a register of
+    # words (8 rows) is one such tile: the tiles below n >> 5 are all
+    # below, those above it none, and tile n >> 5 holds the r = n & 31
+    # slots below n at the offsets o < r. Within a tile each byte's slot
+    # offset o is made through the same bitcast as the data, held as
+    # 0x7F - o in its byte (one register); with r in every byte, a byte's
+    # 0x7F - o + r has its top bit set exactly where o < r (no byte
+    # carries into the next: 0x60 + 0 to 0x7F + 31), and that bit times
+    # 0xFF is the byte's mask. The partial tile is one register of work,
+    # each other tile two compares and two selects against immediates.
+    # Built on one lane tile and repeated along the lanes of a wider row.
+    BW = _carry_shape(bs, C)[0]
+    slot_off = lax.broadcasted_iota(i32, (_A, _LANES), 0)
+    off_tile = pltpu.bitcast((0x7F - slot_off).astype(jnp.int8), i32)
+
+    def below(n):
+        """[BS / 4, C] words: 0xFF in each byte whose slot is below ``n``
+        (a scalar, 0 <= n <= BS), 0 elsewhere."""
+        top = ((n & (_A - 1)) * 0x01010101 + off_tile) & TOP
+        part = lax.shift_right_logical(top, 7) * 0xFF
+        tile = jnp.full(part.shape, n >> 5, i32)
+        m = jnp.concatenate(
+            [jnp.where(tile > k, -1, jnp.where(tile == k, part, 0))
+             for k in range(BW // 8)], axis=0)
+        return m if C == _LANES else jnp.concatenate([m] * (C // _LANES),
+                                                     axis=1)
+
+    def blend_words(m, a, b):
+        """Bytes of ``a`` where the mask ``m`` is set, of ``b`` elsewhere."""
+        return b ^ ((a ^ b) & m)
 
     def start_read(i, slot):
         """Issue the parent-segment block read from its residency array."""
@@ -733,13 +810,14 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
             hist_flush(pending)
             smem[_PEND] = pushes - pending
 
-    def stage_flush(stream, data_u8, hbm_base, do_hist, h0, n_valid=bs,
+    def stage_flush(stream, data_w, hbm_base, do_hist, h0, n_valid=bs,
                     head_rows=None):
-        """Write one full block via the stream's staging ring; maybe hist
-        (of the block's rows [h0, n_valid)). ``head_rows`` (dual residency,
-        the right stream's first block): so many leading rows belong to a
-        segment that may be live in the destination array, and are read
-        from there and written back as they were."""
+        """Write one full block, ``data_w`` its bytes as [BS / 4, C]
+        words, via the stream's staging ring; maybe hist (of the block's
+        rows [h0, n_valid)). ``head_rows`` (dual residency, the right
+        stream's first block): so many leading rows belong to a segment
+        that may be live in the destination array, and are read from there
+        and written back as they were."""
         stage, sem, cslot = ((lstage, sem_l, _LF) if stream == 0
                              else (rstage, sem_r, _RF))
         # left stream writes the parent's residency array, right the other
@@ -753,14 +831,14 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                 stage.at[slot], work_out.at[pl.ds(0, bs), :],
                 sem.at[slot]).wait()
 
-        stage[slot] = data_u8
+        put_words(stage, slot, data_w)
         if head_rows is not None:
             @pl.when(head_rows > 0)
             def _():
                 rmw_read(hbm_base)
-                stage[slot] = jnp.where(
-                    slot_i < head_rows, auxbuf[:, :].astype(i32),
-                    stage[slot].astype(i32)).astype(jnp.uint8)
+                put_words(stage, slot, blend_words(
+                    below(head_rows), auxbuf_w[:, :],
+                    stage.bitcast(i32)[slot]))
         hbm_base = clamp_base(hbm_base)
 
         @pl.when(to_work)
@@ -882,47 +960,56 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                 oh.astype(jnp.int8), blk8,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=i32)                     # [2BS, C]
-            comp_l = comp[:bs]
-            comp_r = comp[bs:]
+            # the carries' packed form, made once as the product leaves
+            # the MXU: the left ring's words, then the right's
+            compw = pltpu.bitcast(comp.astype(jnp.int8), i32)  # [2BS/4, C]
+            comp_l = compw[:BW]
+            comp_r = compw[BW:]
             new_l = lcnt + nl_b
             new_r = rcnt + nr_b
 
-            def ring_flush(carry, comp_s, cnt, new):
+            def ring_flush(carry, comp_s, cnt):
                 """The block a full ring flushes, in carry form: the carry's
                 rows below ``cnt``, then the block's first bs - cnt. The
                 block's later rows wrapped to slots below ``cnt``, already
-                in place: they are the new carry."""
-                full = jnp.where(slot_i < cnt, carry[:, :], comp_s)
-                carry[:, :] = jnp.where(slot_i < new - bs, comp_s,
-                                        jnp.zeros_like(comp_s))
+                in place: they are the new carry. The carry is zero from
+                ``cnt`` up and the block between its wrapped rows and
+                ``cnt``, so one mask splits both. Flipped to bytes
+                (``^ TOP``), a slot that received no row reads 128 and not
+                0: every such slot is one a caller blends away or that
+                lands in dead bytes (the right stream's psi head slots, a
+                tail's rows past its count)."""
+                keep = comp_s & below(cnt)
+                full = carry[:, :] | (comp_s ^ keep)
+                carry[:, :] = keep
                 return full
 
             @pl.when(new_l < bs)
             def _():
                 # the slots written were zero (rows at and above cnt are)
-                lcarry[:, :] = lcarry[:, :] + comp_l
+                lcarry[:, :] = lcarry[:, :] | comp_l
 
             @pl.when(new_l >= bs)
             def _():
                 lf = smem[_LF]
                 h0 = jnp.where(lf == 0, phi, 0)
-                full = ring_flush(lcarry, comp_l, lcnt, new_l)
-                stage_flush(0, carry_block_u8(full), base + lf * bs,
+                full = ring_flush(lcarry, comp_l, lcnt)
+                stage_flush(0, full ^ TOP, base + lf * bs,
                             smaller_left == 1, h0)
             smem[_LCNT] = new_l - bs * (new_l >= bs).astype(i32)
 
             @pl.when(new_r < bs)
             def _():
-                rcarry[:, :] = rcarry[:, :] + comp_r
+                rcarry[:, :] = rcarry[:, :] | comp_r
 
             @pl.when(new_r >= bs)
             def _():
                 rf = smem[_RF]
                 h0 = jnp.where(rf == 0, psi, 0)
-                full = ring_flush(rcarry, comp_r, rcnt, new_r)
+                full = ring_flush(rcarry, comp_r, rcnt)
                 # the psi pre-rows: an RMW blend under dual residency; in
                 # copy-back mode they land in dead scratch bytes
-                stage_flush(1, carry_block_u8(full), rbase + rf * bs,
+                stage_flush(1, full ^ TOP, rbase + rf * bs,
                             smaller_left == 0, h0,
                             head_rows=h0 if dual else None)
             smem[_RCNT] = new_r - bs * (new_r >= bs).astype(i32)
@@ -959,9 +1046,8 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
                         scr_out.at[pl.ds(start_read_at, bs), :],
                         inbuf.at[0], sem_in.at[0]).start()
             wait_read(0)
-            blend = jnp.where(
-                slot_i < lcnt, carry_block_u8(lcarry[:, :]).astype(i32),
-                inbuf[0].astype(i32)).astype(jnp.uint8)
+            blend = blend_words(below(lcnt), lcarry[:, :] ^ TOP,
+                                inbuf_w[0])
             h0 = jnp.where(lf == 0, phi, 0)
             stage_flush(0, blend, base + lf * bs, smaller_left == 1, h0,
                         lcnt)
@@ -970,14 +1056,13 @@ def _fused_kernel(sp_ref, bits_ref, work_in, scr_in, work_out, scr_out,
         def _():
             rf = smem[_RF]
             h0 = jnp.where(rf == 0, psi, 0)
-            data = carry_block_u8(rcarry[:, :])
+            data = rcarry[:, :] ^ TOP
             if dual:
                 # RMW blend against the destination array: the psi head rows
                 # (rf == 0) and everything beyond rcnt may be live neighbours
                 rmw_read(rbase + rf * bs)
-                valid = jnp.logical_and(slot_i >= h0, slot_i < rcnt)
-                data = jnp.where(valid, data.astype(i32),
-                                 auxbuf[:, :].astype(i32)).astype(jnp.uint8)
+                data = blend_words(below(rcnt) & ~below(h0), data,
+                                   auxbuf_w[:, :])
             # (copy-back mode: full-block write, overrun lands in dead
             # scratch bytes)
             stage_flush(1, data, rbase + rf * bs, smaller_left == 0, h0,
@@ -1221,8 +1306,8 @@ def fused_split(
                 (pltpu.SemaphoreType.DMA if dual
                  else pltpu.SemaphoreType.DMA((2,))),       # sem_aux
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # inbuf
-                pltpu.VMEM((bs, C), jnp.int32),     # lcarry
-                pltpu.VMEM((bs, C), jnp.int32),     # rcarry
+                pltpu.VMEM(_carry_shape(bs, C), jnp.int32),  # lcarry
+                pltpu.VMEM(_carry_shape(bs, C), jnp.int32),  # rcarry
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # lstage
                 pltpu.VMEM((2, bs, C), jnp.uint8),  # rstage
                 (pltpu.VMEM((bs, C), jnp.uint8) if dual

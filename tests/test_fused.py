@@ -23,15 +23,21 @@ from lightgbm_tpu.ops.fused_split import fused_split
 i32 = jnp.int32
 
 
-def _make_work(rng, n, f, b, extra=1, route=None):
+def _make_work(rng, n, f, b, extra=1, route=None, packed4=False,
+               quant=False):
     """``route``: (feature, fn(column) -> None) rewrites one feature's bins
-    in place before the rows are packed."""
-    layout = RowLayout(num_features=f, num_extra=extra)
+    in place before the rows are packed. ``quant``: the gradients and
+    hessians are the small integer codes of quantized training."""
+    layout = RowLayout(num_features=f, num_extra=extra, packed4=packed4)
     binned = rng.randint(0, b, size=(n, f)).astype(np.uint8)
     if route is not None:
         route[1](binned[:, route[0]])
-    g = rng.randn(n).astype(np.float32)
-    h = np.abs(rng.randn(n)).astype(np.float32)
+    if quant:
+        g = rng.randint(-63, 64, n).astype(np.float32)
+        h = rng.randint(0, 64, n).astype(np.float32)
+    else:
+        g = rng.randn(n).astype(np.float32)
+        h = np.abs(rng.randn(n)).astype(np.float32)
     cnt = (rng.rand(n) > 0.25).astype(np.float32)
     extras = rng.randn(extra, n).astype(np.float32)
     work = jax.jit(pack_rows, static_argnames=("layout", "pad_rows"))(
@@ -42,7 +48,7 @@ def _make_work(rng, n, f, b, extra=1, route=None):
 
 def _run_fused(work0, layout, b, mode, start, count, n_left, feat, bin_,
                default_left=0, nan_bin=0, is_cat=0, bits=None, bs=128,
-               dual=True):
+               dual=True, quant=False):
     bits = (jnp.zeros((8,), jnp.uint32) if bits is None
             else jnp.asarray(bits, jnp.uint32))
     return fused_split(
@@ -52,7 +58,7 @@ def _run_fused(work0, layout, b, mode, start, count, n_left, feat, bin_,
         jnp.asarray(feat, i32), jnp.asarray(bin_, i32),
         jnp.asarray(default_left, i32), jnp.asarray(nan_bin, i32),
         jnp.asarray(is_cat, i32), bits, layout, b, bs, 8, interpret=True,
-        dual=dual)
+        dual=dual, quant=quant)
 
 
 def _merged(wf, sf, start, count, n_left, dual=True):
@@ -75,7 +81,8 @@ def _run_ref(work0, b, layout, start, count, n_left, feat, bin_,
         jnp.asarray(start, i32), jnp.asarray(count, i32),
         jnp.asarray(n_left, i32), jnp.asarray(feat, i32),
         jnp.asarray(bin_, i32), jnp.asarray(default_left),
-        jnp.asarray(nan_bin, i32), jnp.asarray(is_cat), bits, 128)
+        jnp.asarray(nan_bin, i32), jnp.asarray(is_cat), bits, 128,
+        packed4=layout.packed4)
     n_right = count - n_left
     s_small = start if n_left <= n_right else start + n_left
     m_small = min(n_left, n_right)
@@ -89,16 +96,20 @@ LEFT, RIGHT = 0, 255    # bins on either side of a 256-bin case's threshold
 
 
 def _ring_case(rng, case, start, count, n=3000):
-    """(layout, work0, bins, threshold bin, fused block, n_left) of a named
-    partition case.
+    """(layout, work0, bins, threshold bin, fused block, n_left, quant) of
+    a named partition case.
 
     Each stream's carry is a ring of one block: a row lands at
     (cnt + rank) mod block, and a block that fills the ring flushes the
     carry's rows, then its own first rows, and keeps the rows that wrapped.
     The cases place blocks of the walk (block k holds the rows from
     32 * (start // 32) + k * block on) where the ring's arithmetic has an
-    edge. ``psi`` is the right ring's first count, (start + n_left) % 32."""
+    edge. ``psi`` is the right ring's first count, (start + n_left) % 32.
+    A carry holds its rows as packed bytes, four slots a 32-bit word:
+    where a count is no multiple of 4 the masks of a flush or a tail split
+    a word."""
     f, b, bin_, bs, col = 5, 256, 100, 128, None
+    packed4 = quant = False
 
     def block0_right(c, psi):
         """Block 0's rows all go right, into a ring that starts at psi
@@ -108,6 +119,14 @@ def _ring_case(rng, case, start, count, n=3000):
         c[spare] = RIGHT
         n_left = int((c[start:start + count] <= bin_).sum())
         c[spare[:(psi - n_left - start) % 32]] = LEFT
+
+    def odd_blocks(c):
+        """Every block of the walk sends its first ``odd`` rows left and
+        the rest right: the rings' counts run through every residue mod 4
+        at their flushes and at the tails."""
+        pos = (np.arange(n) - start // 32 * 32) % bs
+        seg = slice(start, start + count)
+        c[seg] = np.where(pos[seg] < odd, LEFT, RIGHT)
 
     if case == "median_bs32":
         # half a block to each stream: one of the two rings wraps in
@@ -119,6 +138,21 @@ def _ring_case(rng, case, start, count, n=3000):
         # 112 + 12 + 4 bytes fill the 128 lanes: the last lane carries a
         # row's own byte through the permutation and the carries
         f, b, bin_ = 112, 64, 31
+    elif case == "wide_rows":
+        # 118 + 12 + 4 bytes: 256-byte records, two lane tiles a carry row
+        f, b, bin_ = 118, 64, 31
+    elif case == "quant":
+        # the int8 kernel: integer gradient codes, int32 histograms
+        quant = True
+    elif case == "packed4":
+        # two features a byte: the routing bin is a nibble
+        f, b, bin_, packed4 = 5, 16, 7, True
+    elif case in ("odd_counts", "odd_counts_bs32"):
+        # 65 of 128 (17 of 32) rows a block go left
+        if case == "odd_counts_bs32":
+            bs = 32
+        odd = bs // 2 + 1
+        col = odd_blocks
     elif case == "all_left_blocks":
         # block 0 fills an empty ring exactly (n == bs at cnt == 0, no
         # wrap); block 3 sends a whole block through a ring part full
@@ -130,29 +164,50 @@ def _ring_case(rng, case, start, count, n=3000):
         def col(c):
             c[start + 3 * bs:start + 4 * bs] = RIGHT
             block0_right(c, 0)
-    elif case == "head_wrap_block0":
+    elif case.startswith("head_wrap_block0"):
         # phi > 0 head rows ride the left ring while block 0's rows all go
         # right into a ring that starts at psi > 0: it wraps in block 0
+        # (``_phiK``: the case at phi = K, set by the caller's start)
         def col(c):
             block0_right(c, 17)
     else:
         assert case == "mixed"
-    layout, work0 = _make_work(rng, n, f, b, route=col and (FEAT, col))
-    n_left = int((work0[start:start + count, FEAT] <= bin_).sum())
+    layout, work0 = _make_work(rng, n, f, b, route=col and (FEAT, col),
+                               packed4=packed4, quant=quant)
+    n_left = int((_route_bins(layout, work0)[start:start + count]
+                  <= bin_).sum())
     phi, psi = start % 32, (start + n_left) % 32
     if case == "no_spare_lane":
         assert layout.num_real_cols == layout.num_cols
+    if case == "wide_rows":
+        assert layout.num_cols == 256
+    if case.startswith("odd_counts"):
+        assert odd % 4
     if case == "all_right_blocks":
         assert phi == psi == 0
-    if case == "head_wrap_block0":
+    if case.startswith("head_wrap_block0"):
         assert phi > 0 and psi + (bs - phi) > bs
-    return layout, work0, b, bin_, bs, n_left
+    return layout, work0, b, bin_, bs, n_left, quant
+
+
+def _route_bins(layout, work0):
+    """The routing feature's bins of every row (a nibble under packed4)."""
+    if layout.packed4:
+        return (work0[:, FEAT // 2] >> (4 * (FEAT % 2))) & 0xF
+    return work0[:, FEAT]
 
 
 RING_CASES = [(0, 3000, "all_left_blocks"), (0, 3000, "all_right_blocks"),
               (0, 3000, "median_bs32"), (37, 2219, "median_bs32"),
               (37, 2219, "median_bs128"), (37, 2219, "head_wrap_block0"),
-              (0, 3000, "no_spare_lane"), (37, 2219, "no_spare_lane")]
+              (0, 3000, "no_spare_lane"), (37, 2219, "no_spare_lane"),
+              # packed carries: counts that split a word, phi of 1 to 3,
+              # two lane tiles a row, the int8 kernel, nibble-packed bins
+              (37, 2219, "odd_counts"), (0, 3000, "odd_counts_bs32"),
+              (37, 2219, "odd_counts_bs32"),
+              (33, 2219, "head_wrap_block0"), (66, 2219, "head_wrap_block0"),
+              (99, 2219, "head_wrap_block0"), (37, 2219, "wide_rows"),
+              (37, 2219, "quant"), (37, 2219, "packed4")]
 
 
 class TestFusedSplit:
@@ -163,17 +218,15 @@ class TestFusedSplit:
          (500, 1, "mixed"), (200, 0, "mixed")] + RING_CASES)
     def test_partition_and_hist_parity(self, rng, start, count, case, dual):
         n, feat = 3000, FEAT
-        layout, work0, b, bin_, bs, n_left = _ring_case(rng, case, start,
-                                                        count)
+        layout, work0, b, bin_, bs, n_left, quant = _ring_case(
+            rng, case, start, count)
         wf, sf, hf = _run_fused(work0, layout, b, 0, start, count, n_left,
-                                feat, bin_, dual=dual, bs=bs)
+                                feat, bin_, dual=dual, bs=bs, quant=quant)
         wr, href = _run_ref(work0, b, layout, start, count, n_left, feat,
                             bin_)
         wm = _merged(wf, sf, start, count, n_left, dual)
         np.testing.assert_array_equal(wm[:n], wr[:n])
-        hf = np.asarray(hf)
-        np.testing.assert_array_equal(hf[:, :, 2:], href[:, :, 2:])
-        np.testing.assert_allclose(hf[:, :, :2], href[:, :, :2], atol=2e-2)
+        _assert_hist(hf, href, quant)
 
     def test_nan_default_left(self, rng):
         n, f, b = 2000, 4, 64
@@ -222,14 +275,20 @@ class TestFusedSplit:
     @pytest.mark.parametrize("dual", [True, False])
     @pytest.mark.parametrize("case", ["mixed", "median_bs32",
                                       "all_right_blocks", "head_wrap_block0",
-                                      "no_spare_lane"])
+                                      "no_spare_lane", "odd_counts",
+                                      "odd_counts_bs32", "head_wrap_block0_phi1",
+                                      "head_wrap_block0_phi2",
+                                      "head_wrap_block0_phi3", "wide_rows",
+                                      "quant", "packed4"])
     def test_untouched_outside_segment(self, rng, case, dual):
         n, feat = 2000, FEAT
         start, count = (608, 700) if case == "all_right_blocks" else (613, 700)
-        layout, work0, b, bin_, bs, n_left = _ring_case(rng, case, start,
-                                                        count, n=n)
+        if case.startswith("head_wrap_block0_phi"):
+            start = 608 + int(case[-1])
+        layout, work0, b, bin_, bs, n_left, quant = _ring_case(
+            rng, case, start, count, n=n)
         wf, sf, _ = _run_fused(work0, layout, b, 0, start, count, n_left,
-                               feat, bin_, dual=dual, bs=bs)
+                               feat, bin_, dual=dual, bs=bs, quant=quant)
         wf, sf = np.asarray(wf), np.asarray(sf)
         np.testing.assert_array_equal(wf[:start], work0[:start])
         np.testing.assert_array_equal(wf[start + count:n],

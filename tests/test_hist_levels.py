@@ -2,7 +2,10 @@
 one-hot of the fused kernel's histogram flush has in the step that ran (2:
 bin = 64 hi + lo, more than 64 bins a feature; 1: the whole stride; 0: the
 fused kernel is off), and the benchmark's ``kernel.hist_levels`` reads it
-from the flight ring through the ``update_loop`` reducer that is there."""
+from the flight ring through the ``update_loop`` reducer that is there. So
+does ``carry_bytes``, the bytes of VMEM a carried row's byte takes in the
+kernel's ring carries (1: packed bytes; 0: the kernel is off), which
+``kernel.carry_bytes`` reads."""
 import json
 
 import numpy as np
@@ -10,7 +13,8 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.obs import flight
-from lightgbm_tpu.ops.fused_split import hist_levels
+from lightgbm_tpu.ops.fused_split import (_carry_shape, carry_bytes,
+                                          hist_levels)
 
 from benchmarks import run as bench_run
 from utils import OwnThreadRing
@@ -96,5 +100,59 @@ def test_the_benchmark_lists_the_metric_for_all_five_cells():
         spec = json.load(f)
     assert spec["reducer"] == "update_loop"
     assert spec["args"] == {"what": "hist_levels"}
+    for key in ("name", "unit", "better", "layer", "source", "moves"):
+        assert spec[key] == entry[key], key
+
+
+# ------------------------------------------- carry_bytes rides along too
+CARRY_METRIC = "kernel.carry_bytes"
+
+
+@pytest.mark.parametrize("block,cols", [(384, 128), (256, 256), (32, 128),
+                                        (128, 384)])
+def test_carry_bytes_is_read_off_the_carry_scratch(block, cols):
+    # the ring carries hold packed bytes: four rows a 32-bit word
+    assert _carry_shape(block, cols) == (block // 4, cols)
+    assert carry_bytes(block, cols) == 1
+
+
+@pytest.mark.parametrize("more,want", [
+    ({}, 1),
+    ({"use_quantized_grad": True}, 1),
+    ({"tpu_fused": "off"}, 0),
+    ({"tpu_grower": "masked"}, 0),
+])
+def test_carry_bytes_rides_every_iteration_event(monkeypatch, more, want):
+    got = ticks(monkeypatch, 255, **more)
+    assert [e["carry_bytes"] for e in got] == [want, want]
+
+
+@pytest.mark.parametrize("value", [1, 4, 0])
+def test_the_carry_metric_reads_the_counter_through_the_harness(value):
+    got = bench_run.per_layer_metrics([CARRY_METRIC],
+                                      hand_made_run(carry_bytes=value))
+    assert got[CARRY_METRIC]["value"] == value
+    assert got[CARRY_METRIC]["unit"] == "count"
+
+
+def test_a_program_without_carry_bytes_leaves_the_metric_out():
+    # a program from before the counter: its side reads nothing
+    assert bench_run.per_layer_metrics([CARRY_METRIC],
+                                       hand_made_run(hist_levels=2)) == {}
+
+
+def test_the_benchmark_lists_the_carry_metric_for_all_five_cells():
+    with open(bench_run.ROOT + "/BENCHMARK.json") as f:
+        bench = json.load(f)
+    entry, = [m for m in bench["per_layer"] if m["name"] == CARRY_METRIC]
+    assert entry == {
+        "name": CARRY_METRIC, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "train_s_per_iter", "workloads": CELLS}
+    with open(bench_run.ROOT + "/benchmarks/metrics/" + CARRY_METRIC
+              + ".json") as f:
+        spec = json.load(f)
+    assert spec["reducer"] == "update_loop"
+    assert spec["args"] == {"what": "carry_bytes"}
     for key in ("name", "unit", "better", "layer", "source", "moves"):
         assert spec[key] == entry[key], key
